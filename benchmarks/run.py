@@ -112,6 +112,8 @@ BENCHES = {
 
 
 def main() -> None:
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
     names = sys.argv[1:] or list(BENCHES)
     print("name,us_per_call,derived")
     for n in names:

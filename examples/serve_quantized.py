@@ -3,8 +3,8 @@
   PYTHONPATH=src python examples/serve_quantized.py
 
 Compares dense-bf16 serving vs fp8 DAQ-quantized serving on the same
-requests: same model code, QuantizedTensor leaves (quant_runtime/qlinear);
-on TPU the fused dequant-matmul kernel takes over via USE_KERNELS.  Both
+requests: same model code, QuantizedTensor leaves (quant_runtime/qlinear),
+which XLA dequantizes and multiplies on every backend.  Both
 runs go through ``repro.engine.Engine`` — slot scheduling lives on device
 and the host syncs once per ``k_steps`` decode steps.
 """

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+import jax
 import jax.numpy as jnp
 
 
@@ -63,5 +64,11 @@ def dequantize(q: jnp.ndarray, scale: jnp.ndarray, fmt: Format,
 
 
 def qdq(w: jnp.ndarray, scale: jnp.ndarray, fmt: Format) -> jnp.ndarray:
-    """Quantize-dequantize operator :math:`Q_s(W)` (paper Eq. 4), fp32 out."""
-    return dequantize(quantize(w, scale, fmt), scale, fmt)
+    """Quantize-dequantize operator :math:`Q_s(W)` (paper Eq. 4), fp32 out.
+
+    The barrier makes XLA materialise the low-precision codes.  Without it
+    the TPU compiler fuses ``convert(convert(x, fp8), f32)`` into one
+    kernel and, allowed excess precision, skips the rounding: Q_s becomes
+    the identity and every DAQ candidate scores as lossless."""
+    q = jax.lax.optimization_barrier(quantize(w, scale, fmt))
+    return dequantize(q, scale, fmt)

@@ -15,6 +15,9 @@ import jax.numpy as jnp
 
 EPS = 1e-12
 
+# Keys of the partial-sum form (see ``partial_sums``).
+PARTIAL_KEYS = ("sq_err", "n_sign_match", "dot", "dp_sq", "dq_sq", "count")
+
 
 # ---------------------------------------------------------------------------
 # Whole-tensor metrics (paper Eqs. 6, 8, 9)
@@ -41,21 +44,6 @@ def cosine(dp: jnp.ndarray, dq: jnp.ndarray) -> jnp.ndarray:
     num = jnp.sum(dp * dq)
     den = jnp.sqrt(jnp.sum(dp * dp)) * jnp.sqrt(jnp.sum(dq * dq))
     return num / jnp.maximum(den, EPS)
-
-
-def delta_l2(dp: jnp.ndarray, dq: jnp.ndarray) -> jnp.ndarray:
-    """|| dq - dp ||_2 — the 'Delta-W L2' column of the paper's tables."""
-    d = (dq - dp).astype(jnp.float32)
-    return jnp.sqrt(jnp.sum(d * d))
-
-
-def all_metrics(dp: jnp.ndarray, dq: jnp.ndarray) -> dict[str, jnp.ndarray]:
-    return {
-        "mse": mse(dp, dq),
-        "sign_rate": sign_rate(dp, dq),
-        "cosine": cosine(dp, dq),
-        "delta_l2": delta_l2(dp, dq),
-    }
 
 
 def objective(name: str, dp: jnp.ndarray, dq: jnp.ndarray,
@@ -115,7 +103,7 @@ def objective_from_partials(name: str, p: dict[str, jnp.ndarray],
 def combine_partials(parts: list[dict[str, jnp.ndarray]]) -> dict[str, jnp.ndarray]:
     """Sum partial sums across tensors (for model-level aggregate metrics)."""
     out: dict[str, jnp.ndarray] = {}
-    for key in ("sq_err", "n_sign_match", "dot", "dp_sq", "dq_sq", "count"):
+    for key in PARTIAL_KEYS:
         out[key] = sum(jnp.sum(p[key]) for p in parts)
     return out
 

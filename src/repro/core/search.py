@@ -31,11 +31,12 @@ from repro.core.granularity import (absmax_scale, apply_qdq, pad_to_blocks,
 
 @dataclass
 class SearchResult:
-    """Result of quantizing one weight tensor."""
+    """Result of quantizing one weight tensor.  The dequantized weights are
+    not kept: ``QuantizedTensor(w_q, scale, ...).dequantize()`` reproduces
+    Q_s(W_post) bit-exactly when a caller wants them."""
     alpha: jnp.ndarray          # chosen multiplier(s): scalar or per-block
     scale: jnp.ndarray          # final scale(s) = alpha * s0
     w_q: jnp.ndarray            # storage representation (fp8/int8), layout of W
-    w_dq: jnp.ndarray           # dequantized weights Q_s(W_post), fp32
     chosen: dict                # metrics + partial sums at chosen alpha
     default: dict               # metrics + partial sums at alpha=1 (AbsMax)
     eq_scale: jnp.ndarray | None = None  # per-in-channel equalization vector
@@ -44,10 +45,15 @@ class SearchResult:
 
 jax.tree_util.register_dataclass(
     SearchResult,
-    data_fields=["alpha", "scale", "w_q", "w_dq", "chosen", "default",
-                 "eq_scale"],
+    data_fields=["alpha", "scale", "w_q", "chosen", "default", "eq_scale"],
     meta_fields=[],
 )
+
+# Candidates are scored over row blocks of at most this many fp32 bytes, so
+# scoring one candidate on a [151552, 4096] embedding holds a few blocks'
+# temporaries instead of several fp32 copies of the whole leaf.  2**23
+# elements also keeps each block's sign-match count an exact fp32 integer.
+ROW_BLOCK_BYTES = 32 << 20
 
 
 def _candidate_grid(qcfg: QuantConfig) -> jnp.ndarray:
@@ -56,11 +62,57 @@ def _candidate_grid(qcfg: QuantConfig) -> jnp.ndarray:
     return jnp.linspace(qcfg.alpha_min, qcfg.alpha_max, n)
 
 
-def _eval_alpha(alpha, w_post, dp, w_base, s0, qcfg: QuantConfig):
+def _row_block(shape: tuple[int, int], qcfg: QuantConfig) -> int:
+    """Rows per scoring block: the largest divisor of I whose fp32 block
+    fits ``ROW_BLOCK_BYTES``, in whole quant blocks for block granularity
+    (whose scale rows must line up; a ragged I is scored in one block)."""
+    I, O = shape
+    unit = 1
+    if qcfg.granularity == "block":
+        unit = qcfg.block_size if I % qcfg.block_size == 0 else I
+    n_units = I // unit
+    fit = max(1, ROW_BLOCK_BYTES // (unit * O * 4))
+    d = max(k for k in range(1, min(fit, n_units) + 1) if n_units % k == 0)
+    return d * unit
+
+
+def leaf_partials(w_post, w_base, scale, qcfg: QuantConfig) -> dict:
+    """Whole-leaf :func:`repro.core.metrics.partial_sums` of ``dp = W_post -
+    W_base`` and ``dq = Q_scale(W_post) - W_base``, summed over row blocks
+    (equal to the one-shot sums up to summation order)."""
     fmt = get_format(qcfg.fmt)
-    wq = apply_qdq(w_post, alpha * s0, qcfg.granularity, fmt, qcfg.block_size)
-    dq = wq - w_base
-    return M.objective(qcfg.metric, dp, dq, qcfg.hybrid_lambda)
+    I = w_post.shape[0]
+    R = _row_block(w_post.shape, qcfg)
+
+    def one(wp, wb, s):
+        wp = wp.astype(jnp.float32)
+        wb = wb.astype(jnp.float32)
+        wq = apply_qdq(wp, s, qcfg.granularity, fmt, qcfg.block_size)
+        return M.partial_sums(wp - wb, wq - wb, (0, 1))
+
+    if R == I:
+        return one(w_post, w_base, scale)
+    rows_per_scale = R // qcfg.block_size
+
+    def body(acc, i):
+        rows = lambda w: jax.lax.dynamic_slice_in_dim(w, i * R, R, 0)
+        s = (jax.lax.dynamic_slice_in_dim(scale, i * rows_per_scale,
+                                          rows_per_scale, 0)
+             if qcfg.granularity == "block" else scale)
+        part = one(rows(w_post), rows(w_base), s)
+        return jax.tree.map(jnp.add, acc, part), None
+
+    zero = {k: jnp.float32(0) for k in M.PARTIAL_KEYS}
+    acc, _ = jax.lax.scan(body, zero, jnp.arange(I // R))
+    return acc
+
+
+def with_metrics(partials: dict) -> dict:
+    """Partial sums plus the whole-tensor metrics they determine: the
+    common currency of ``SearchResult.chosen`` / ``.default`` across all
+    registered methods (``repro.quantize`` sums the partials over leaves
+    into exact global model metrics)."""
+    return {**partials, **M.metrics_from_partials(partials)}
 
 
 @partial(jax.jit, static_argnames=("qcfg",))
@@ -71,6 +123,8 @@ def search_scale(w_post: jnp.ndarray, w_base: jnp.ndarray,
     Dispatches to the per-block variant when ``qcfg.per_block_alpha`` and to
     the fused one-HBM-pass Pallas sweep when ``qcfg.use_fused_kernel``
     (block fp8 only; same argmax by construction — tests assert equality).
+    Candidates are scored from row-blocked partial sums, so the working set
+    is a few row blocks, not fp32 copies of the whole leaf.
     """
     if qcfg.per_block_alpha:
         return _search_per_block(w_post, w_base, qcfg)
@@ -79,12 +133,11 @@ def search_scale(w_post: jnp.ndarray, w_base: jnp.ndarray,
         return _search_fused(w_post, w_base, qcfg)
 
     fmt = get_format(qcfg.fmt)
-    w_post = w_post.astype(jnp.float32)
-    w_base = w_base.astype(jnp.float32)
-    dp = w_post - w_base
     s0 = absmax_scale(w_post, qcfg.granularity, fmt, qcfg.block_size)
 
-    eval_fn = lambda a: _eval_alpha(a, w_post, dp, w_base, s0, qcfg)
+    def eval_fn(a):
+        p = leaf_partials(w_post, w_base, a * s0, qcfg)
+        return M.objective_from_partials(qcfg.metric, p, qcfg.hybrid_lambda)
 
     # --- init: alpha = 1 (Alg. 1 lines 4-6) ---
     best_alpha = jnp.float32(1.0)
@@ -107,33 +160,17 @@ def search_scale(w_post: jnp.ndarray, w_base: jnp.ndarray,
     f_idx = jnp.argmax(fine_m)
     take_f = fine_m[f_idx] > best_m
     best_alpha = jnp.where(take_f, fine[f_idx], best_alpha)
-    best_m = jnp.maximum(fine_m[f_idx], best_m)
 
-    return _finalize(w_post, w_base, dp, best_alpha, s0, qcfg)
-
-
-def metrics_and_partials(dp, dq):
-    """Whole-tensor metrics + full-reduction partial sums for (dp, dq).
-
-    The common currency of ``SearchResult.chosen`` / ``.default`` across all
-    registered quantization methods — ``repro.quantize`` aggregates the
-    partial sums into exact global model metrics.
-    """
-    axes = tuple(range(dp.ndim))
-    out = dict(M.all_metrics(dp, dq))
-    out.update(M.partial_sums(dp, dq, axes))
-    return out
+    return _finalize(w_post, w_base, best_alpha, s0, qcfg)
 
 
-def _finalize(w_post, w_base, dp, alpha, s0, qcfg: QuantConfig) -> SearchResult:
+def _finalize(w_post, w_base, alpha, s0, qcfg: QuantConfig) -> SearchResult:
     fmt = get_format(qcfg.fmt)
     scale = alpha * s0
-    w_dq = apply_qdq(w_post, scale, qcfg.granularity, fmt, qcfg.block_size)
     w_q = quantize_store(w_post, scale, qcfg.granularity, fmt, qcfg.block_size)
-    chosen = metrics_and_partials(dp, w_dq - w_base)
-    w_dq0 = apply_qdq(w_post, s0, qcfg.granularity, fmt, qcfg.block_size)
-    default = metrics_and_partials(dp, w_dq0 - w_base)
-    return SearchResult(alpha=alpha, scale=scale, w_q=w_q, w_dq=w_dq,
+    chosen = with_metrics(leaf_partials(w_post, w_base, scale, qcfg))
+    default = with_metrics(leaf_partials(w_post, w_base, s0, qcfg))
+    return SearchResult(alpha=alpha, scale=scale, w_q=w_q,
                         chosen=chosen, default=default)
 
 
@@ -147,9 +184,6 @@ def _search_fused(w_post, w_base, qcfg: QuantConfig) -> SearchResult:
     ~8x less HBM traffic than re-reading W per candidate (see §Perf)."""
     from repro.kernels.scale_search import ops as K
 
-    w_post = w_post.astype(jnp.float32)
-    w_base = w_base.astype(jnp.float32)
-    dp = w_post - w_base
     s0 = absmax_scale(w_post, "block", get_format(qcfg.fmt), qcfg.block_size)
 
     def stage_best(alphas):
@@ -170,7 +204,7 @@ def _search_fused(w_post, w_base, qcfg: QuantConfig) -> SearchResult:
     fine = jnp.concatenate([best_alpha[None],
                             jnp.linspace(lo, hi, qcfg.n_fine)])
     best_alpha, _ = stage_best(fine)
-    return _finalize(w_post, w_base, dp, best_alpha, s0, qcfg)
+    return _finalize(w_post, w_base, best_alpha, s0, qcfg)
 
 
 # ---------------------------------------------------------------------------
@@ -216,4 +250,4 @@ def _search_per_block(w_post, w_base, qcfg: QuantConfig) -> SearchResult:
         objs = jax.lax.map(per_cand, grid)
         alpha = grid[jnp.argmax(objs)]
 
-    return _finalize(w_post, w_base, dp, alpha, s0, qcfg)
+    return _finalize(w_post, w_base, alpha, s0, qcfg)

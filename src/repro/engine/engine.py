@@ -340,14 +340,8 @@ class Engine:
         steady-state serve loop holds this at 1 per entry; growth across
         dispatches means an avoidable recompile (shape drift or weak-type
         literals in the argument tree)."""
-        out = {}
-        for name, e in self._entries.items():
-            fn = e["fn"]
-            try:
-                out[name] = int(fn._cache_size())
-            except Exception:  # jax without the AOT cache-size probe
-                out[name] = -1
-        return out
+        return {name: int(e["fn"]._cache_size())
+                for name, e in self._entries.items()}
 
     # -- sharded placement --------------------------------------------------
 

@@ -1,4 +1,27 @@
-# Pallas TPU kernels (validated on CPU via interpret=True):
-#   scale_search -- fused DAQ candidate sweep (the paper's Alg. 1 hot-spot)
-#   fp8_matmul   -- fused block-dequant matmul (fp8 serving)
-#   fp8_quant    -- one-pass block absmax + E4M3 cast
+"""Pallas TPU kernels, each a kernel / ops / ref triad:
+
+  scale_search -- fused DAQ candidate sweep (the paper's Alg. 1 hot-spot)
+  fp8_matmul   -- fused block-dequant matmul
+  fp8_quant    -- one-pass block absmax + E4M3 cast
+
+Serving does not call them: ``quant_runtime.qlinear`` runs quantized weights
+as an XLA dequantize + matmul.  ``tests/test_tpu_compile.py`` compiles each
+kernel for a v5e chip at glm4-9b widths.
+"""
+from __future__ import annotations
+
+import jax
+from jax.experimental import pallas as pl
+
+
+def pallas_call(kernel, **kw):
+    """``pl.pallas_call`` that runs in Pallas interpret mode on the CPU
+    backend (the test suite) and compiled everywhere else.
+
+    The choice is made per lowering platform (``lax.platform_dependent``),
+    so a program lowered for a TPU never carries the interpreter, even when
+    the process's default backend is the CPU."""
+    compiled = pl.pallas_call(kernel, **kw)
+    interpreted = pl.pallas_call(kernel, interpret=True, **kw)
+    return lambda *args: jax.lax.platform_dependent(
+        *args, cpu=interpreted, default=compiled)

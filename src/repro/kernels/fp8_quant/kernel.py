@@ -3,8 +3,10 @@
 Computes, per 128x128 block, the AbsMax scale s0 = max|W|/448 and the
 saturating E4M3 cast — one HBM read of W, one fp8 write + scale write,
 instead of the two-pass (absmax pass, then quantize pass) jnp formulation.
-Used by the serving path when re-quantizing updated adapters and by the
-alpha != 1 DAQ finalization (scale = alpha * s0 folded in via ``alpha``).
+``alpha`` folds a DAQ multiplier into the scale (scale = alpha * s0).
+
+The scale grid is an SMEM output written one scalar per grid step, and
+``alpha`` is an SMEM scalar.
 """
 from __future__ import annotations
 
@@ -13,40 +15,42 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import pallas_call
 
 
 def _quant_kernel(w_ref, alpha_ref, q_ref, s_ref, *, qmax: float):
+    i, j = pl.program_id(0), pl.program_id(1)
     w = w_ref[...].astype(jnp.float32)
     amax = jnp.max(jnp.abs(w))
     s0 = jnp.maximum(amax, 1e-12) / qmax
     scale = alpha_ref[0] * s0
     q = jnp.clip(w / scale, -qmax, qmax).astype(jnp.float8_e4m3fn)
     q_ref[...] = q
-    s_ref[0, 0] = scale
+    s_ref[i, j] = scale
 
 
 def quantize_fp8_pallas(w: jnp.ndarray, alpha: jnp.ndarray, *,
-                        block: int = 128, qmax: float = 448.0,
-                        interpret: bool = True):
+                        block: int = 128, qmax: float = 448.0):
     """w [I, O] (block multiples); alpha scalar [1].  Returns
     (q [I, O] fp8, scales [I/b, O/b] fp32)."""
     I, O = w.shape
     nbi, nbo = I // block, O // block
     kernel = functools.partial(_quant_kernel, qmax=qmax)
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=(nbi, nbo),
         in_specs=[
             pl.BlockSpec((block, block), lambda i, j: (i, j)),
-            pl.BlockSpec((1,), lambda i, j: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((block, block), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((I, O), jnp.float8_e4m3fn),
             jax.ShapeDtypeStruct((nbi, nbo), jnp.float32),
         ],
-        interpret=interpret,
     )(w, alpha)

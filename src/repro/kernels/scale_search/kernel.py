@@ -8,19 +8,15 @@ accumulating the five DAQ partial sums per (candidate, block):
 
   [sq_err, n_sign_match, dot(dp,dq), |dp|^2, |dq|^2]  (+3 pad lanes)
 
-Predicted effect (napkin): the search becomes 1 HBM pass instead of ~16 —
-an ~8x reduction of the search's memory roofline term per stage; measured
-in benchmarks/bench_search.py and EXPERIMENTS.md §Perf.
-
 Tiling: grid over (I/bs, O/bs) blocks; the candidate loop is unrolled over
-the VMEM-resident tile (n_cand * 2 tile-sized fp32 temporaries stay in
-registers/VMEM: 16 candidates x 2 x 64 KiB = 2 MiB << 128 MiB v5e VMEM...
-at bs=128 a tile is 128*128*4 B = 64 KiB; wp/wb + accumulators fit easily).
-The fp8 quantize-dequantize runs on the VPU (convert + clip); the dot
-products run as elementwise multiplies + reductions.
+the VMEM-resident tile (at bs=128 a tile is 64 KiB of fp32, so wp/wb and a
+candidate's temporaries fit easily).  The fp8 quantize-dequantize runs on
+the VPU (convert + clip); the dot products run as elementwise multiplies +
+reductions.  The block scales s0 and the candidate alphas are SMEM scalars.
+Each block writes one ``[n_cand, 8]`` tile of stats (one row per candidate,
+built with lane selects), so the output block spans whole array dims.
 
-Outputs: partials [n_cand, I/bs, O/bs, 8] fp32 (last dim padded to 8 for
-lane friendliness; slots 5..7 are zero).
+Outputs: partials [n_cand, I/bs, O/bs, 8] fp32 (slots 5..7 are zero).
 """
 from __future__ import annotations
 
@@ -29,6 +25,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import pallas_call
 
 N_STATS = 8  # 5 used + 3 pad
 
@@ -42,32 +41,34 @@ def _qdq_e4m3(w, scale, qmax: float):
 
 def _sweep_kernel(wp_ref, wb_ref, s0_ref, alphas_ref, out_ref, *,
                   n_cand: int, qmax: float):
+    i, j = pl.program_id(0), pl.program_id(1)
     wp = wp_ref[...].astype(jnp.float32)
     wb = wb_ref[...].astype(jnp.float32)
-    s0 = s0_ref[0, 0]
+    s0 = s0_ref[i, j]
     dp = wp - wb
     sign_dp = jnp.sign(dp)
-    dp_sq = jnp.sum(dp * dp)
+    total = lambda x: jnp.sum(x, keepdims=True)          # [1, 1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, N_STATS), 1)
+    dp_sq = total(dp * dp)
     for c in range(n_cand):  # unrolled: tile stays VMEM-resident
-        alpha = alphas_ref[c]
-        wq = _qdq_e4m3(wp, alpha * s0, qmax)
+        wq = _qdq_e4m3(wp, alphas_ref[c] * s0, qmax)
         dq = wq - wb
         diff = dq - dp
-        stats = jnp.stack([
-            jnp.sum(diff * diff),                                # sq_err
-            jnp.sum((sign_dp == jnp.sign(dq)).astype(jnp.float32)),
-            jnp.sum(dp * dq),                                    # dot
-            dp_sq,
-            jnp.sum(dq * dq),
-            jnp.float32(0.0), jnp.float32(0.0), jnp.float32(0.0),
-        ])
-        out_ref[c, 0, 0, :] = stats
+        stats = (total(diff * diff),                             # sq_err
+                 total((sign_dp == jnp.sign(dq)).astype(jnp.float32)),
+                 total(dp * dq),                                 # dot
+                 dp_sq,
+                 total(dq * dq))
+        row = jnp.zeros((1, N_STATS), jnp.float32)
+        for s, v in enumerate(stats):
+            row = jnp.where(lane == s, v, row)
+        out_ref[c:c + 1, :] = row
 
 
 def sweep_partials_pallas(wp: jnp.ndarray, wb: jnp.ndarray,
                           s0: jnp.ndarray, alphas: jnp.ndarray, *,
-                          block_size: int = 128, qmax: float = 448.0,
-                          interpret: bool = True) -> jnp.ndarray:
+                          block_size: int = 128,
+                          qmax: float = 448.0) -> jnp.ndarray:
     """wp/wb [I, O] (pre-padded to block multiples), s0 [I/bs, O/bs],
     alphas [n_cand].  Returns partials [n_cand, I/bs, O/bs, 8] fp32."""
     I, O = wp.shape
@@ -76,18 +77,18 @@ def sweep_partials_pallas(wp: jnp.ndarray, wb: jnp.ndarray,
     n_cand = alphas.shape[0]
 
     kernel = functools.partial(_sweep_kernel, n_cand=n_cand, qmax=qmax)
-    return pl.pallas_call(
+    out = pallas_call(
         kernel,
         grid=(nbi, nbo),
         in_specs=[
             pl.BlockSpec((bs, bs), lambda i, j: (i, j)),
             pl.BlockSpec((bs, bs), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-            pl.BlockSpec((n_cand,), lambda i, j: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((n_cand, 1, 1, N_STATS),
-                               lambda i, j: (0, i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_cand, nbi, nbo, N_STATS),
+        out_specs=pl.BlockSpec((None, None, n_cand, N_STATS),
+                               lambda i, j: (i, j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nbi, nbo, n_cand, N_STATS),
                                        jnp.float32),
-        interpret=interpret,
     )(wp, wb, s0, alphas)
+    return jnp.moveaxis(out, 2, 0)

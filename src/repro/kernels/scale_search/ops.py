@@ -1,9 +1,9 @@
 """Jitted wrapper: DAQ coarse/fine candidate sweep over one weight tensor.
 
 ``sweep(wp, wb, alphas, qcfg-ish args)`` pads to the block grid, runs the
-fused kernel (interpret=True on CPU — the TPU path flips the flag), and
-reduces the per-block partials to the per-candidate / per-block objective
-values the search needs.  Slot layout matches core.metrics.partial_sums.
+fused kernel (or its jnp oracle with ``use_kernel=False``), and reduces the
+per-block partials to the per-candidate / per-block objective values the
+search needs.  Slot layout matches core.metrics.partial_sums.
 """
 from __future__ import annotations
 
@@ -19,11 +19,10 @@ from repro.kernels.scale_search.ref import sweep_partials_ref
 EPS = 1e-12
 
 
-@partial(jax.jit, static_argnames=("block_size", "qmax", "use_kernel",
-                                   "interpret"))
+@partial(jax.jit, static_argnames=("block_size", "qmax", "use_kernel"))
 def sweep(wp: jnp.ndarray, wb: jnp.ndarray, alphas: jnp.ndarray, *,
           block_size: int = 128, qmax: float = 448.0,
-          use_kernel: bool = True, interpret: bool = True) -> dict:
+          use_kernel: bool = True) -> dict:
     """Returns dict of [n_cand] tensor-level partials + [n_cand, nbi, nbo]
     block-level partials for per-block alpha selection."""
     wp32 = wp.astype(jnp.float32)
@@ -34,15 +33,9 @@ def sweep(wp: jnp.ndarray, wb: jnp.ndarray, alphas: jnp.ndarray, *,
     amax = jnp.max(jnp.abs(wp_p.reshape(nbi, block_size, nbo, block_size)),
                    axis=(1, 3))
     s0 = jnp.maximum(amax, EPS) / qmax
-    fn = sweep_partials_pallas if use_kernel else \
-        lambda *a, **k: sweep_partials_ref(*a, **{kk: vv for kk, vv in
-                                                  k.items()
-                                                  if kk != "interpret"})
+    fn = sweep_partials_pallas if use_kernel else sweep_partials_ref
     parts = fn(wp_p, wb_p, s0, alphas.astype(jnp.float32),
-               block_size=block_size, qmax=qmax, interpret=interpret) \
-        if use_kernel else sweep_partials_ref(
-            wp_p, wb_p, s0, alphas.astype(jnp.float32),
-            block_size=block_size, qmax=qmax)
+               block_size=block_size, qmax=qmax)
 
     # [n_cand, nbi, nbo, 8] -> block + tensor reductions
     block = {

@@ -1,16 +1,17 @@
 """Pure-jnp oracle for the scale-search sweep kernel."""
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 
+from repro.core.formats import FP8_E4M3, qdq
 from repro.kernels.scale_search.kernel import N_STATS
 
 
 def _qdq_e4m3(w, scale, qmax: float = 448.0):
-    scaled = w / scale
-    clipped = jnp.clip(scaled, -qmax, qmax)
-    return clipped.astype(jnp.float8_e4m3fn).astype(jnp.float32) * scale
+    return qdq(w, scale, dataclasses.replace(FP8_E4M3, qmax=qmax))
 
 
 def sweep_partials_ref(wp: jnp.ndarray, wb: jnp.ndarray, s0: jnp.ndarray,

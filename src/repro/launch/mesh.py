@@ -14,41 +14,22 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-
-
-def _auto(n: int) -> dict:
-    """axis_types kwargs when this jax exposes them (explicit-sharding era);
-    older jax (< 0.5) predates AxisType and defaults every axis to Auto."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
-
-
-def use_mesh(mesh):
-    """Context manager activating ``mesh`` for jitted code, tolerant of the
-    API churn across jax versions: ``jax.set_mesh`` (explicit-sharding era),
-    ``jax.sharding.use_mesh`` (transition releases), or the Mesh's own
-    context manager (jax <= 0.4.x)."""
-    setter = (getattr(jax, "set_mesh", None)
-              or getattr(jax.sharding, "use_mesh", None))
-    if setter is not None:
-        return setter(mesh)
-    return mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_auto(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(*, model: int = 1):
-    """A mesh over whatever devices exist (tests, CPU examples)."""
+    """A mesh over whatever devices exist (tests, CPU examples, one host's
+    chips)."""
     n = len(jax.devices())
     model = min(model, n)
     return jax.make_mesh((n // model, model), ("data", "model"),
-                         **_auto(2))
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def mesh_info(mesh) -> dict:

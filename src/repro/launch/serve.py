@@ -10,14 +10,15 @@ only parses flags, builds (and optionally quantizes) the model, and calls
 * **Quantized weights** — pass ``--daq`` to serve fp8 weights quantized
   through ``repro.quantize`` (method selectable via ``--method``): the
   parameter tree's matmul leaves become QuantizedTensor nodes and the same
-  model code serves them (quant_runtime/qlinear.py); on TPU the fused
-  dequant-matmul Pallas kernel takes over (kernels/fp8_matmul).
+  model code serves them (quant_runtime/qlinear.py) with an XLA dequantize
+  + matmul.  The base tree is dropped as soon as quantizing is done.
   Delta-aware methods want a real base model — point ``--base-ckpt`` at a
   checkpoint directory (e.g. ``experiments/study/base``); without it a
   jittered copy stands in (with a loud warning — demo only).
 * **Sharded serving** — ``--mesh N`` builds a host mesh with model-parallel
-  size N (``launch/mesh.make_host_mesh``) and places params + cache with
-  the ``launch/sharding`` specs; quantized ``wq/data`` / ``wq/scale``
+  size N (``launch/mesh.make_host_mesh``), initialises params straight into
+  their ``launch/sharding`` specs (no device ever holds the whole tree) and
+  places the cache the same way; quantized ``wq/data`` / ``wq/scale``
   leaves inherit the dense weight's layout.
 * **Speculative decoding** — ``--spec-draft METHOD --n-spec N`` (with
   ``--paged``) quantizes the weights with METHOD and serves them as the
@@ -57,7 +58,9 @@ import jax.numpy as jnp
 from repro.configs import QuantConfig, get_arch, reduced as reduce_cfg
 from repro.data import LanguageSpec, sample_batch
 from repro.engine import Engine, SamplingParams
+from repro.launch.sharding import init_params
 from repro.models import build_model
+from repro.runtime import enable_compile_cache
 
 
 def serve(model, params, requests: list[jnp.ndarray], *, batch: int,
@@ -204,41 +207,42 @@ def main() -> None:
     if cfg.family in ("vlm", "encdec"):
         raise SystemExit("serve CLI drives LM-style archs; "
                          "vlm/encdec need modality inputs (see examples/)")
+    print(f"[serve] compile cache: {enable_compile_cache()}")
+    mesh = None
+    if args.mesh:
+        from repro.launch.mesh import make_host_mesh, mesh_info
+        mesh = make_host_mesh(model=args.mesh)
+        print(f"[serve] host mesh: {mesh_info(mesh)}")
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    params = init_params(model, jax.random.PRNGKey(0), mesh)
 
     spec = LanguageSpec(vocab=cfg.vocab_size)
+    # the base tree is an argument expression, never bound to a name: it is
+    # freed as soon as quantize returns, before serving starts
     if args.daq:
         from repro.quantize import quantize
         qcfg = QuantConfig(method=args.method or "daq", metric=args.metric,
                            granularity="channel")
-        base = _load_base_params(args.base_ckpt, params)
         # model=/spec= feed the calibrate hook of calibration-based
         # methods (smoothquant/awq); data-free methods ignore them
-        params, report = quantize(params, base, qcfg, mode="storage",
-                                  out_dtype="bfloat16", model=model,
-                                  spec=spec)
+        params, report = quantize(params,
+                                  _load_base_params(args.base_ckpt, params),
+                                  qcfg, mode="storage", out_dtype="bfloat16",
+                                  model=model, spec=spec)
         print(report.summary())
     draft_params = None
     if args.spec_draft:
         from repro.quantize import quantize
         qcfg = QuantConfig(method=args.spec_draft, metric=args.metric,
                            granularity="channel")
-        base = _load_base_params(args.base_ckpt, params)
-        draft_params, report = quantize(params, base, qcfg, mode="storage",
-                                        out_dtype="bfloat16", model=model,
-                                        spec=spec)
+        draft_params, report = quantize(
+            params, _load_base_params(args.base_ckpt, params), qcfg,
+            mode="storage", out_dtype="bfloat16", model=model, spec=spec)
         print(f"[serve] speculative draft ({args.spec_draft}):")
         print(report.summary())
     prompts = [sample_batch(jax.random.PRNGKey(i), spec, 1,
                             args.prompt_len)[0] for i in range(args.requests)]
     cache_len = args.prompt_len + args.gen + 8
-
-    mesh = None
-    if args.mesh:
-        from repro.launch.mesh import make_host_mesh, mesh_info
-        mesh = make_host_mesh(model=args.mesh)
-        print(f"[serve] host mesh: {mesh_info(mesh)}")
     if args.temperature <= 0 and args.top_k == 0 and args.top_p >= 1.0:
         sp = SamplingParams()                        # greedy
     else:  # any flag alone enables sampling (temperature defaults to 1)
@@ -304,7 +308,7 @@ def main() -> None:
     # length bucket.  Anything above that is an avoidable recompile — the
     # signature contracts live in `python -m repro.staticcheck`.
     counts = eng.compile_counts()
-    total = sum(c for c in counts.values() if c > 0)
+    total = sum(counts.values())
     print(f"compiles: {total} total ("
           + ", ".join(f"{k.lstrip('_')}={v}"
                       for k, v in sorted(counts.items())) + ")")

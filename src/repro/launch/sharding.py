@@ -157,6 +157,17 @@ def params_shardings(params_shape: Any, cfg: ModelConfig, mesh) -> Any:
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
+def init_params(model, key, mesh=None) -> Any:
+    """``model.init(key)``; with a mesh, built straight into
+    :func:`params_shardings` (a jit with ``out_shardings``), so no device
+    ever holds the whole tree."""
+    if mesh is None:
+        return model.init(key)
+    shard = params_shardings(jax.eval_shape(model.init, key), model.cfg,
+                             mesh)
+    return jax.jit(model.init, out_shardings=shard)(key)
+
+
 # ---------------------------------------------------------------------------
 # Optimizer-state specs (mirror the param layout)
 # ---------------------------------------------------------------------------

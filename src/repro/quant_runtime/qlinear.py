@@ -4,9 +4,10 @@ Every matmul in the model zoo routes through :func:`matmul` so that a weight
 leaf may transparently be either a dense array or a
 :class:`~repro.quant_runtime.qparams.QuantizedTensor`.
 
-On TPU the 2-D fp8 case uses the fused dequant-matmul Pallas kernel
-(`repro.kernels.fp8_matmul`); elsewhere (CPU dry-run / interpret) it
-dequantizes and lets XLA fuse the multiply into the matmul epilogue.
+A quantized weight is dequantized and multiplied by XLA, on every backend.
+The fused block-dequant Pallas kernel (`repro.kernels.fp8_matmul`) is wired
+in behind ``USE_KERNELS`` for 2-D block-granularity fp8 weights only, and
+that switch is off.
 """
 from __future__ import annotations
 
@@ -15,7 +16,8 @@ import jax.numpy as jnp
 
 from repro.quant_runtime.qparams import QuantizedTensor
 
-# Toggled by launch configs; kernels need a real TPU (or interpret mode).
+# Routes 2-D block-granularity fp8 weights through the fused Pallas kernel.
+# Off: serving runs the XLA dequantize + matmul below.
 USE_KERNELS = False
 
 # Calibration hook: when set to a list, every matmul appends
@@ -57,10 +59,11 @@ def matmul(x: jnp.ndarray, w, *, precision=None) -> jnp.ndarray:
         RECORD.append((tuple(w_res.shape), weight_fingerprint(w_res),
                        jnp.max(jnp.abs(x.reshape(-1, x.shape[-1])), axis=0)))
     if isinstance(w, QuantizedTensor):
-        # fused kernel dequantizes q*scale only; equalized tensors need the
-        # extra /eq_scale epilogue, so they take the XLA dequantize path
-        if USE_KERNELS and w.ndim == 2 and w.fmt.startswith("fp8") \
-                and w.eq_scale is None:
+        # the fused kernel reads one scale per 128x128 block and dequantizes
+        # q*scale only: channel/tensor scales and equalized tensors (extra
+        # /eq_scale epilogue) take the XLA dequantize path
+        if USE_KERNELS and w.ndim == 2 and w.granularity == "block" \
+                and w.fmt.startswith("fp8") and w.eq_scale is None:
             from repro.kernels import fp8_matmul  # lazy: pallas import cost
             return fp8_matmul.ops.matmul_fp8(x, w)
         w = w.dequantize()

@@ -2,7 +2,7 @@
 
 Model code calls ``repro.quant_runtime.qlinear.matmul(x, w)`` for every
 linear; when ``w`` is a ``QuantizedTensor`` the weight is dequantized on the
-fly (or fed to the fused Pallas dequant-matmul kernel on TPU).  Because the
+fly and XLA runs the matmul.  Because the
 node is a registered pytree, quantized parameter trees flow through
 ``jax.jit``, ``jax.eval_shape``, shardings and checkpointing unchanged.
 """

@@ -32,8 +32,6 @@ from repro.core.search import SearchResult
 from repro.quant_runtime.qparams import QuantizedTensor
 from repro.quantize.registry import get_method
 
-_PARTIAL_KEYS = ("sq_err", "n_sign_match", "dot", "dp_sq", "dq_sq", "count")
-
 
 # ---------------------------------------------------------------------------
 # Per-leaf context + method protocol
@@ -172,8 +170,8 @@ def quantize(params_post: Any, params_base: Any = None,
     if len(post_leaves) != len(base_leaves):
         raise ValueError("post/base parameter trees differ in structure")
 
-    agg_c = {k: 0.0 for k in _PARTIAL_KEYS}
-    agg_d = {k: 0.0 for k in _PARTIAL_KEYS}
+    agg_c = {k: 0.0 for k in M.PARTIAL_KEYS}
+    agg_d = {k: 0.0 for k in M.PARTIAL_KEYS}
 
     out_leaves = []
     for (path, w_post), w_base in zip(post_leaves, base_leaves):
@@ -185,7 +183,7 @@ def quantize(params_post: Any, params_base: Any = None,
         res = quantizer.prepare(LeafContext(leaf_name, w_post, w_base, qcfg))
         report.n_quantized += 1
         report.original_bytes += w_post.size * w_post.dtype.itemsize
-        for k in _PARTIAL_KEYS:
+        for k in M.PARTIAL_KEYS:
             agg_c[k] += _scalar_sum(res.chosen[k])
             agg_d[k] += _scalar_sum(res.default[k])
         report.per_leaf[leaf_name] = {
@@ -196,21 +194,13 @@ def quantize(params_post: Any, params_base: Any = None,
                         ("mse", "sign_rate", "cosine", "delta_l2")},
             "shape": tuple(w_post.shape),
         }
-        if mode == "storage":
-            qt = QuantizedTensor(data=res.w_q, scale=res.scale, fmt=qcfg.fmt,
-                                 granularity=qcfg.granularity,
-                                 block_size=qcfg.block_size,
-                                 out_dtype=out_dtype, eq_scale=res.eq_scale)
-            report.quantized_bytes += qt.nbytes()
-            out_leaves.append(qt)
-        else:
-            from repro.core.formats import get_format
-            nbytes = (w_post.size * get_format(qcfg.fmt).bits // 8
-                      + res.scale.size * 4)
-            if res.eq_scale is not None:
-                nbytes += res.eq_scale.size * 4
-            report.quantized_bytes += nbytes
-            out_leaves.append(res.w_dq.astype(jnp.dtype(out_dtype)))
+        qt = QuantizedTensor(data=res.w_q, scale=res.scale, fmt=qcfg.fmt,
+                             granularity=qcfg.granularity,
+                             block_size=qcfg.block_size,
+                             out_dtype=out_dtype, eq_scale=res.eq_scale)
+        report.quantized_bytes += qt.nbytes()
+        # dequant mode: Q_s(W_post) rebuilt from storage, bit-exact
+        out_leaves.append(qt if mode == "storage" else qt.dequantize())
 
     agg_cj = {k: jnp.asarray(v) for k, v in agg_c.items()}
     agg_dj = {k: jnp.asarray(v) for k, v in agg_d.items()}

@@ -1,12 +1,14 @@
 """Registered data-free methods: DAQ (paper Alg. 1) and the AbsMax baseline.
 
 The per-leaf search lives in :mod:`repro.core.search`; stacked-layer leaves
-``[L, I, O]`` are handled by vmapping the per-matrix search over the leading
-axes — one alpha per layer, exactly Alg. 1's per-layer loop.
+``[L, I, O]`` run the per-matrix search in a ``lax.map`` over the leading
+axes — one alpha per layer, exactly Alg. 1's per-layer loop, with one
+layer's working set live at a time.
 """
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 
 import jax
 
@@ -25,11 +27,16 @@ class DAQQuantizer(Quantizer):
     """
 
     def prepare(self, ctx: LeafContext) -> SearchResult:
-        qcfg = ctx.qcfg
-        fn = lambda p, b: search_scale(p, b, qcfg)
-        for _ in range(ctx.w_post.ndim - 2):
-            fn = jax.vmap(fn)
-        return fn(ctx.w_post, ctx.w_base)
+        return search_stacked(ctx.w_post, ctx.w_base, ctx.qcfg)
+
+
+@partial(jax.jit, static_argnames=("qcfg",))
+def search_stacked(w_post, w_base, qcfg: QuantConfig) -> SearchResult:
+    """:func:`search_scale` over every 2-D matrix of a ``[..., I, O]`` leaf."""
+    fn = lambda p, b: search_scale(p, b, qcfg)
+    for _ in range(w_post.ndim - 2):
+        fn = lambda p, b, f=fn: jax.lax.map(lambda pb: f(*pb), (p, b))
+    return fn(w_post, w_base)
 
 
 @register("daq-per-block")

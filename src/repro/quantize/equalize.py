@@ -19,9 +19,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import QuantConfig
+from repro.core import metrics as M
 from repro.core.formats import Format, get_format
 from repro.core.granularity import absmax_scale, apply_qdq, quantize_store
-from repro.core.search import SearchResult, metrics_and_partials
+from repro.core.search import SearchResult, leaf_partials, with_metrics
 from repro.quantize.api import LeafContext, Quantizer
 from repro.quantize.registry import register
 
@@ -123,10 +124,11 @@ class _EqualizeQuantizer(Quantizer):
         # default baseline: plain AbsMax at the same granularity, no
         # equalization — mirrors SearchResult.default for the DAQ methods
         s0 = absmax_scale(wp32, qcfg.granularity, fmt, qcfg.block_size)
-        w_dq0 = apply_qdq(wp32, s0, qcfg.granularity, fmt, qcfg.block_size)
-        return SearchResult(alpha=s, scale=scale, w_q=w_q, w_dq=w_dq,
-                            chosen=metrics_and_partials(dp, w_dq - wb32),
-                            default=metrics_and_partials(dp, w_dq0 - wb32),
+        chosen = M.partial_sums(dp, w_dq - wb32, (0, 1))
+        return SearchResult(alpha=s, scale=scale, w_q=w_q,
+                            chosen=with_metrics(chosen),
+                            default=with_metrics(
+                                leaf_partials(wp32, wb32, s0, qcfg)),
                             eq_scale=s)
 
     def prepare(self, ctx: LeafContext) -> SearchResult:

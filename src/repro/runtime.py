@@ -1,12 +1,18 @@
 """Process-wide runtime/perf flags (no arch semantics — see QuantConfig /
-ModelConfig for those).
+ModelConfig for those), the ambient-mesh sharding helpers, and the
+persistent compilation cache.
 
-These are read at *trace time*; callers that flip them must rebuild/re-lower
-(build_model returns fresh closures, so a fresh Model + jit is enough).
-They exist so the perf-iteration loop (EXPERIMENTS.md §Perf) can toggle
-structural choices without threading knobs through every layer signature.
+The flags are read at *trace time*; callers that flip them must
+rebuild/re-lower (build_model returns fresh closures, so a fresh Model + jit
+is enough).  They let structural choices be toggled without threading knobs
+through every layer signature.
 """
 from __future__ import annotations
+
+import os
+from pathlib import Path
+
+import jax
 
 flags: dict = {
     # Megatron-style sequence parallelism: residual stream is sharded over
@@ -27,7 +33,7 @@ flags: dict = {
     # KV-cache storage dtype: "bfloat16" | "float8_e4m3fn".  fp8 halves the
     # cache-read traffic that dominates long-context decode (k/v values are
     # O(1-10) so the unscaled E4M3 range is safe; ~6% relative noise on
-    # attention scores — beyond-paper optimization, see EXPERIMENTS.md §Perf)
+    # attention scores — beyond-paper optimization)
     "kv_cache_dtype": "bfloat16",
     # MoE expert-weight layout (launch/sharding.py):
     #   "ep_model"          E over `model`, D over `data` (FSDP) — weights
@@ -42,18 +48,37 @@ flags: dict = {
 }
 
 
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here.  Otherwise the cache lives at a fixed path,
+    ``<repo>/.jax_cache``: the path is part of the cache key, so a moving
+    directory would never hit."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(Path(__file__).resolve().parents[2] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def _ambient_mesh():
+    """The mesh set with ``jax.set_mesh`` around the current trace, or None
+    (plain single-device runs, and Engine serving, which shards through
+    its arguments' placements instead)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
+
+
 def _mesh_axes():
-    import jax
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or not mesh.axis_names:
-            return None, None, 0
-        names = mesh.axis_names
-        dp = tuple(a for a in ("pod", "data") if a in names) or None
-        msz = mesh.shape["model"] if "model" in names else 0
-        return names, dp, msz
-    except Exception:
+    mesh = _ambient_mesh()
+    if mesh is None:
         return None, None, 0
+    names = mesh.axis_names
+    dp = tuple(a for a in ("pod", "data") if a in names) or None
+    msz = mesh.shape["model"] if "model" in names else 0
+    return names, dp, msz
 
 
 def attn_shard_specs(kv_heads: int, q_groups: int):
@@ -101,33 +126,24 @@ def kv_repeat_factor(kv_heads: int, q_groups: int,
 
 
 def constrain(x, spec):
-    import jax
-    if spec is None or x is None:
+    """Sharding constraint under the ambient mesh (no-op without one)."""
+    if spec is None or x is None or _ambient_mesh() is None:
         return x
-    try:
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, spec)
 
 
 def residual_constraint(x):
     """Apply the configured residual-stream sharding constraint (no-op when
     there is no ambient mesh, e.g. plain CPU tests)."""
-    import jax
     from jax.sharding import PartitionSpec as P
 
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or not mesh.axis_names:
-            return x
-        names = mesh.axis_names
-        dp = tuple(a for a in ("pod", "data") if a in names) or None
-        seq = "model" if (flags["seq_shard"] and "model" in names) else None
-        if not flags["batch_constraint"]:
-            dp = None
-        if dp is None and seq is None:
-            return x
-        spec = P(dp, seq, *([None] * (x.ndim - 2)))
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:
+    names, dp, _ = _mesh_axes()
+    if names is None:
         return x
+    seq = "model" if (flags["seq_shard"] and "model" in names) else None
+    if not flags["batch_constraint"]:
+        dp = None
+    if dp is None and seq is None:
+        return x
+    return jax.lax.with_sharding_constraint(
+        x, P(dp, seq, *([None] * (x.ndim - 2))))

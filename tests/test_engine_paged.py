@@ -240,6 +240,7 @@ def test_paged_stress_randomized(seed):
     k_steps = int(rng.randint(1, 4))
     cache_len = max(lens) + gen + int(rng.randint(0, 6))
     prompts = _prompts(spec, lens, seed=seed % 997)
+    lens = [int(p.shape[0]) for p in prompts]   # sample_batch adds a token
 
     legacy = serve_host_loop(model, params, prompts, batch=slots,
                              gen_tokens=gen, cache_len=cache_len)

@@ -275,6 +275,7 @@ def test_spec_stress_randomized(seed):
     cache_len = max(lens) + gen + int(rng.randint(0, 6))
     dtree = draft if seed % 2 == 0 else bad
     prompts = _prompts(spec, lens, seed=seed % 997)
+    lens = [int(p.shape[0]) for p in prompts]   # sample_batch adds a token
 
     base = Engine(model, params, slots=slots, cache_len=cache_len,
                   k_steps=k_steps, paged=True, block_size=8
@@ -320,6 +321,7 @@ def test_spec_composed_stress_randomized(seed):
     cache_len = max(lens) + gen + int(rng.randint(0, 6))
     dtree = draft if seed % 2 == 0 else bad
     prompts = _shared_prompts(spec, lens, share, seed=seed % 997)
+    lens = [int(p.shape[0]) for p in prompts]   # sample_batch adds a token
 
     base = Engine(model, params, slots=slots, cache_len=cache_len,
                   k_steps=k_steps, paged=True, block_size=8,

@@ -1,4 +1,5 @@
-"""Pallas kernels vs pure-jnp oracles (interpret mode), shape/dtype sweeps."""
+"""Pallas kernels vs pure-jnp oracles (interpret mode on the CPU backend),
+shape/dtype sweeps."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -27,8 +28,7 @@ def test_scale_search_kernel(shape, bs, dtype):
     nbi, nbo = I // bs, O // bs
     amax = jnp.max(jnp.abs(wp32.reshape(nbi, bs, nbo, bs)), axis=(1, 3))
     s0 = jnp.maximum(amax, 1e-12) / 448.0
-    pk = sweep_partials_pallas(wp32, wb32, s0, alphas, block_size=bs,
-                               interpret=True)
+    pk = sweep_partials_pallas(wp32, wb32, s0, alphas, block_size=bs)
     pr = sweep_partials_ref(wp32, wb32, s0, alphas, block_size=bs)
     # Tolerances: the sign-match stat is an integer count; fp32
     # associativity / division-order can flip exact-tie elements (bf16
@@ -50,7 +50,7 @@ def test_fp8_matmul_kernel(M, K, N, xdtype):
     x = jax.random.normal(key, (M, K)).astype(xdtype)
     w = jax.random.normal(jax.random.PRNGKey(1), (K, N)) * 0.1
     q, s = quantize_fp8(w)
-    yk = matmul_fp8_pallas(x, q, s, bm=min(128, M), block=128, interpret=True)
+    yk = matmul_fp8_pallas(x, q, s, bm=min(128, M), block=128)
     yr = matmul_fp8_ref(x, q, s, block=128)
     np.testing.assert_allclose(np.asarray(yk), np.asarray(yr),
                                rtol=1e-5, atol=1e-4)
@@ -60,7 +60,7 @@ def test_fp8_matmul_kernel(M, K, N, xdtype):
                                      ((256, 128), 64), ((64, 192), 64)])
 def test_fp8_quant_kernel(shape, b):
     w = jax.random.normal(jax.random.PRNGKey(0), shape) * 0.3
-    qk, sk = quantize_fp8_pallas(w, jnp.ones(1), block=b, interpret=True)
+    qk, sk = quantize_fp8_pallas(w, jnp.ones(1), block=b)
     qr, sr = quantize_fp8_ref(w, jnp.ones(1), block=b)
     np.testing.assert_allclose(np.asarray(sk), np.asarray(sr), rtol=1e-6)
     a = np.asarray(qk, np.float32)

@@ -94,6 +94,7 @@ def test_daq_walk_matches_handrolled_reference():
     quantize_tree is now a shim over the code under test, so shim-parity
     alone can't catch porting bugs in the walk."""
     from repro.core import metrics as M
+    from repro.core.granularity import dequantize_stored
     from repro.core.policy import path_str, should_quantize
     from repro.core.search import search_scale
 
@@ -116,9 +117,17 @@ def test_daq_walk_matches_handrolled_reference():
         n_q += 1
         if wp.ndim == 2:
             res = search_scale(wp, wb, q)
-        else:
-            res = jax.vmap(lambda p, b: search_scale(p, b, q))(wp, wb)
-        exp_leaves[name] = res.w_dq.astype(jnp.float32)
+            w_dq = dequantize_stored(res.w_q, res.scale, q.granularity,
+                                     get_format(q.fmt), q.block_size,
+                                     jnp.float32)
+        else:  # Alg. 1's per-layer loop
+            res = jax.lax.map(lambda pb: search_scale(*pb, q), (wp, wb))
+            w_dq = jnp.stack([
+                dequantize_stored(res.w_q[t], res.scale[t], q.granularity,
+                                  get_format(q.fmt), q.block_size,
+                                  jnp.float32)
+                for t in range(wp.shape[0])])
+        exp_leaves[name] = w_dq
         for k in keys:
             agg_c[k] += float(jnp.sum(res.chosen[k]))
             agg_d[k] += float(jnp.sum(res.default[k]))

@@ -10,8 +10,14 @@ from hypothesis import given, settings, strategies as st
 from repro.configs import QuantConfig
 from repro.core import metrics as M
 from repro.core.formats import get_format
-from repro.core.granularity import absmax_scale, apply_qdq
+from repro.core.granularity import absmax_scale, apply_qdq, dequantize_stored
 from repro.core.search import search_scale
+
+
+def _dq(res, q):
+    """Q_s(W_post) of a search result, rebuilt from its storage form."""
+    return dequantize_stored(res.w_q, res.scale, q.granularity,
+                             get_format(q.fmt), q.block_size, jnp.float32)
 
 
 def _pair(seed, shape=(96, 64), delta=0.003):
@@ -34,7 +40,7 @@ def test_never_worse_than_absmax(metric, gran):
     dp = wp - wb
     dq0 = apply_qdq(wp, s0, gran, fmt, 32) - wb
     m_abs = float(M.objective(metric, dp, dq0))
-    m_chosen = float(M.objective(metric, dp, res.w_dq - wb))
+    m_chosen = float(M.objective(metric, dp, _dq(res, q) - wb))
     assert m_chosen >= m_abs - 1e-6
 
 
@@ -57,7 +63,8 @@ def test_fused_kernel_matches_naive(metric):
     r1 = search_scale(wp, wb, q1)
     r2 = search_scale(wp, wb, q2)
     assert abs(float(r1.alpha) - float(r2.alpha)) < 1e-6
-    np.testing.assert_allclose(np.asarray(r1.w_dq), np.asarray(r2.w_dq))
+    np.testing.assert_array_equal(np.asarray(r1.w_q, np.float32),
+                                  np.asarray(r2.w_q, np.float32))
 
 
 @pytest.mark.parametrize("metric", ["mse", "sign"])
@@ -70,8 +77,8 @@ def test_per_block_at_least_as_good(metric):
     r_s = search_scale(wp, wb, q_shared)
     r_b = search_scale(wp, wb, q_block)
     dp = wp - wb
-    m_s = float(M.objective(metric, dp, r_s.w_dq - wb))
-    m_b = float(M.objective(metric, dp, r_b.w_dq - wb))
+    m_s = float(M.objective(metric, dp, _dq(r_s, q_shared) - wb))
+    m_b = float(M.objective(metric, dp, _dq(r_b, q_block) - wb))
     assert m_b >= m_s - 1e-6
 
 
@@ -90,3 +97,28 @@ def test_zero_delta_perfect_metrics():
     q = QuantConfig(metric="sign", granularity="channel")
     res = search_scale(wb, wb, q)
     assert np.isfinite(float(res.chosen["cosine"]))
+
+
+@pytest.mark.parametrize("gran", ["tensor", "channel", "block"])
+def test_row_blocked_scoring_matches_one_shot(gran, monkeypatch):
+    """Large leaves are scored over row blocks: the partial sums add up to
+    the one-block sums (up to summation order; sign counts exactly), so the
+    chosen alpha does not move."""
+    import repro.core.search as S
+    wp, wb = _pair(9, shape=(256, 96))
+    q = QuantConfig(metric="sign", granularity=gran, block_size=32)
+    fresh = lambda: jax.jit(S.search_scale.__wrapped__,
+                            static_argnames=("qcfg",))
+    assert S._row_block(wp.shape, q) == 256
+    one = fresh()(wp, wb, q)
+    monkeypatch.setattr(S, "ROW_BLOCK_BYTES", 32 * 96 * 4)
+    assert S._row_block(wp.shape, q) == 32        # 8 blocks of 32 rows
+    blocked = fresh()(wp, wb, q)
+    assert float(blocked.alpha) == float(one.alpha)
+    np.testing.assert_array_equal(np.asarray(blocked.w_q, np.float32),
+                                  np.asarray(one.w_q, np.float32))
+    for part in ("chosen", "default"):
+        a, b = getattr(blocked, part), getattr(one, part)
+        assert float(a["n_sign_match"]) == float(b["n_sign_match"])
+        for k in ("sq_err", "dot", "dp_sq", "dq_sq", "count"):
+            np.testing.assert_allclose(float(a[k]), float(b[k]), rtol=1e-5)

@@ -72,6 +72,29 @@ def test_eq7_mse_search_is_base_agnostic():
     assert abs(a1 - a2) < 1e-6
 
 
+def test_compile_cache_prefers_env(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and the code sets nothing."""
+    from repro.runtime import enable_compile_cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_repo(monkeypatch):
+    """Without the variable the cache goes to the fixed <repo>/.jax_cache."""
+    from repro.runtime import enable_compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = enable_compile_cache()
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:  # no compile ran in between, so nothing was written there
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
 def test_mini_dryrun_subprocess():
     """The production dry-run machinery on an 8-device fake mesh."""
     code = """
@@ -81,7 +104,6 @@ import sys; sys.path.insert(0, "src")
 import jax
 from repro.configs import TrainConfig, get_arch, reduced, ShapeConfig
 from repro.launch import sharding as SH
-from repro.launch.mesh import _auto, use_mesh
 from repro.launch.steps import make_train_step
 from repro.launch.specs import train_batch_specs, state_specs
 from repro.models import build_model
@@ -89,7 +111,8 @@ from repro.models import build_model
 cfg = reduced(get_arch("glm4-9b"))
 model = build_model(cfg)
 tc = TrainConfig()
-mesh = jax.make_mesh((4, 2), ("data", "model"), **_auto(2))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 state = state_specs(model, tc)
 shape = ShapeConfig("mini", 64, 8, "train")
 batch = train_batch_specs(cfg, shape)
@@ -98,7 +121,7 @@ st_sh = {"params": SH.params_shardings(state["params"], cfg, mesh),
                                        mesh)}
 b_sh = SH.batch_shardings(batch, mesh)
 step = make_train_step(model, tc)
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     compiled = jax.jit(step, in_shardings=(st_sh, b_sh),
                        out_shardings=(st_sh, None),
                        donate_argnums=0).lower(state, batch).compile()

@@ -1,0 +1,104 @@
+"""Compile for a described TPU v5e (no chip attached) at glm4-9b widths.
+
+The TPU compiler refuses what interpret mode accepts: misaligned block
+shapes, too much fast memory, programs that do not fit the device.  These
+tests compile the Pallas kernels and the DAQ per-leaf program for one v5e
+chip, so such faults show up here instead of on the chip.
+
+The topology is described inside a module-scoped fixture only: describing it
+loads the TPU library, which one process at a time may hold.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import QuantConfig
+from repro.quantize.api import LeafContext
+from repro.quantize.daq import DAQQuantizer
+
+D_MODEL, D_FF, VOCAB = 4096, 13696, 151552   # glm4-9b published widths
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache off around these
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _kernel_case(name):
+    from repro.kernels.fp8_matmul.ops import matmul_fp8_2d
+    from repro.kernels.fp8_quant.ops import quantize_fp8
+    from repro.kernels.scale_search.ops import sweep
+    K, N, bs = D_MODEL, D_FF, 128
+    if name == "fp8_matmul":
+        return matmul_fp8_2d, [((8, K), jnp.bfloat16),
+                               ((K, N), jnp.float8_e4m3fn),
+                               ((K // bs, N // bs), jnp.float32)]
+    if name == "fp8_quant":
+        return quantize_fp8, [((K, N), jnp.bfloat16), ((), jnp.float32)]
+    return (lambda wp, wb, a: sweep(wp, wb, a)["tensor"],
+            [((K, N), jnp.bfloat16), ((K, N), jnp.bfloat16),
+             ((16,), jnp.float32)])
+
+
+@pytest.mark.parametrize("name", ["fp8_matmul", "fp8_quant", "scale_search"])
+def test_kernel_compiles_for_v5e(one_chip, name):
+    """Each kernel lowers through Mosaic (not the interpreter) and compiles
+    at glm4-9b's MLP width."""
+    fn, specs = _kernel_case(name)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in specs]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("shape", [(VOCAB, D_MODEL), (4, D_MODEL, D_FF)],
+                         ids=["embed", "stacked_mlp"])
+def test_daq_prepare_fits_v5e(one_chip, shape):
+    """DAQ's per-leaf program holds its bf16 inputs, its fp8 output and a
+    bounded working set: candidates are scored over row blocks, stacked
+    layers one at a time, and no fp32 dequantized copy is built.  Before
+    that repair the embed leaf compiled to 2.48 GB arguments + 4.97 GB
+    temporaries + 3.1 GB output."""
+    q = QuantConfig(method="daq", metric="sign", fmt="fp8_e4m3",
+                    granularity="channel")
+    leaf = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    prepare = lambda wp, wb: DAQQuantizer().prepare(
+        LeafContext("embed/embed", wp, wb, q))
+    m = jax.jit(prepare).lower(leaf, leaf).compile().memory_analysis()
+    args = m.argument_size_in_bytes
+    # inputs + fp8 output (a quarter of the inputs) + 256 MiB working set
+    budget = args + args // 4 + (256 << 20)
+    used = args + m.temp_size_in_bytes + m.output_size_in_bytes
+    assert used <= budget, (args, m.temp_size_in_bytes,
+                            m.output_size_in_bytes)
+
+
+def test_fp8_round_trip_survives_v5e_fusion(one_chip):
+    """Q_s(W) must round to fp8 on the chip.  XLA's TPU fusions may keep
+    an intermediate in higher precision, so an fp8 code that is converted
+    straight back inside one fusion is never rounded (DAQ then scores every
+    candidate as lossless).  Every fp8 code in the compiled DAQ scoring has
+    to leave its fusion as a materialised fp8 result."""
+    import re
+    from repro.core.search import search_scale
+    q = QuantConfig(metric="sign", granularity="channel")
+    leaf = jax.ShapeDtypeStruct((D_MODEL, D_FF), jnp.bfloat16,
+                                sharding=one_chip)
+    hlo = search_scale.lower(leaf, leaf, q).compile().as_text()
+    fused = re.findall(r"\n(%fused_computation\S* .*?\n})", hlo, re.S)
+    converts = [line for comp in fused for line in comp.split("\n")
+                if re.search(r"= f8e4m3fn\[[^\]]*\]\S* convert\(", line)]
+    assert converts, "no fp8 rounding left in the compiled search"
+    inner = [c for c in converts if not c.lstrip().startswith("ROOT")]
+    assert not inner, inner[:2]
