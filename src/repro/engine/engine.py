@@ -98,6 +98,7 @@ from repro.engine.scheduler import init_slot_state, make_decode_dispatch
 from repro.models.lm import Model
 from repro.telemetry.counters import (COUNTER_KEYS, bump, counter_totals,
                                       init_counters)
+from repro.telemetry.trace import clock_us, span
 
 _BKEYS = P.BSTATE_KEYS
 
@@ -342,6 +343,31 @@ class Engine:
         literals in the argument tree)."""
         return {name: int(e["fn"]._cache_size())
                 for name, e in self._entries.items()}
+
+    def hlo_texts(self) -> dict[str, str]:
+        """``{module name: compiled HLO text}`` of each dispatch entry that
+        has run.  Each is lowered against ``self.params`` and the cache and
+        slot state that the prefix-cached path keeps between serves, so it
+        is the executable the serve loop ran (with a persistent compile
+        cache, a cache hit); ``repro.telemetry.scopes.op_scopes`` reads the
+        named scopes of its device ops from it.  Needs a prefix-cached
+        engine that has served."""
+        if getattr(self, "_pcache", None) is None:
+            raise ValueError("hlo_texts needs a prefix_cache engine that "
+                             "has served: it lowers against the kept cache")
+        key = jax.random.PRNGKey(0)
+        out = {}
+        for name, e in self._entries.items():
+            if not name.startswith("_dispatch") or not e["fn"]._cache_size():
+                continue
+            if "spec" in name:
+                args = (self.params, self._draft_params, self._pstate,
+                        self._pcache, jnp.int32(0), key)
+            else:
+                args = (self.params, self._pstate, self._pcache, key)
+            text = e["fn"].lower(*args).compile().as_text()
+            out[text.split(",", 1)[0].split()[-1]] = text
+        return out
 
     # -- sharded placement --------------------------------------------------
 
@@ -683,20 +709,27 @@ class Engine:
             m.histogram("request.prefix_hit_frac", lo=1e-3,
                         hi=1.0).observe(prefix_hit_frac)
 
-    def _trace_dispatch(self, t0_us, totals, depth=None, drafted=0,
-                        accepted=0):
-        """One dispatch's trace events: a duration on the dispatch/spec
-        track plus counter-track samples from the device counter tree."""
+    def _count_dispatch(self, stats, totals, prev, spec_ctl):
+        """Fold one synced dispatch into ``stats``: its speculative deltas
+        (``totals - prev`` of the device counters) move the depth policy;
+        with a tracer, counter-track samples of the counter tree."""
+        K = self.cfg.k_steps
         tr = self.tracer
+        if self.cfg.n_spec:
+            d_dr = totals["drafted"] - prev["drafted"]
+            d_ac = totals["accepted"] - prev["accepted"]
+            stats["draft_tokens"] += d_dr
+            stats["draft_accepted"] += d_ac
+            stats["spec_rounds"] += K
+            if tr:
+                tr.counter("spec", {"depth": spec_ctl.depth,
+                                    "drafted": d_dr, "accepted": d_ac})
+            stats["spec_depth"] = spec_ctl.update(d_dr, d_ac)
+        stats["host_syncs"] += 1
+        stats["dispatches"] += 1
+        stats["decode_steps"] += K
         if tr is None:
             return
-        if depth is None:
-            tr.complete("dispatch", "decode", t0_us,
-                        {"k_steps": self.cfg.k_steps})
-        else:
-            tr.complete("spec", "rounds", t0_us,
-                        {"k_steps": self.cfg.k_steps, "depth": depth,
-                         "drafted": drafted, "accepted": accepted})
         tr.counter("tokens", {"emitted": totals["tokens"]})
         if self.cfg.paged:
             live = (getattr(self, "_ctr_live0", 0)
@@ -743,7 +776,7 @@ class Engine:
         generated tokens.  Returns outputs in request order (and a stats
         dict when ``return_stats``)."""
         cfg, model = self.cfg, self.model
-        B, K = cfg.slots, cfg.k_steps
+        B = cfg.slots
         requests = [jnp.asarray(r, jnp.int32).reshape(-1) for r in requests]
         stats = {"host_syncs": 0, "dispatches": 0, "prefill_calls": 0,
                  "decode_steps": 0, "tokens": 0, "prefill_tokens": 0,
@@ -817,83 +850,69 @@ class Engine:
                     take_slots = free[:take]
                     rids = [queue.popleft() for _ in range(take)]
                 if rids:
-                    key, sub = jax.random.split(key)
-                    t0_us = tr.now_us() if tr else 0.0
-                    cache, state, first, ncalls = self._admit(
-                        cache, state, take_slots,
-                        [requests[r] for r in rids], gen_tokens, sub)
-                    ta = time.perf_counter()
-                    if tr:
-                        tr.complete("admission", f"admit x{len(rids)}",
-                                    t0_us, {"requests": len(rids),
-                                            "prefill_calls": ncalls})
-                    stats["prefill_calls"] += ncalls
-                    stats["host_syncs"] += ncalls
-                    stats["tokens"] += len(rids)
-                    stats["prefill_tokens"] += sum(
-                        int(requests[r].shape[0]) for r in rids)
-                    for s, r, t in zip(take_slots, rids, first):
-                        outputs[r] = [t]
-                        slot_rid[s], slot_rem[s] = r, gen_tokens - 1
-                        # first token comes from the prefill logits, so
-                        # admission time IS first-token time here
-                        req_admit[r] = ta
-                    for s in take_slots:   # gen_tokens == 1 finishes now
-                        if slot_rem[s] <= 0:
-                            r = slot_rid[s]
-                            self._request_done(
-                                int(requests[r].shape[0]), gen_tokens,
-                                t_enq, req_admit[r], req_admit[r])
-                            slot_rid[s] = -1
-                            slot_rsv[s] = 0
+                    n_prompt = sum(int(requests[r].shape[0]) for r in rids)
+                    with span("engine", "admit", tr, requests=len(rids),
+                              prompt_len=n_prompt, prefix_hit=0):
+                        key, sub = jax.random.split(key)
+                        cache, state, first, ncalls = self._admit(
+                            cache, state, take_slots,
+                            [requests[r] for r in rids], gen_tokens, sub)
+                        ta = time.perf_counter()
+                        stats["prefill_calls"] += ncalls
+                        stats["host_syncs"] += ncalls
+                        stats["tokens"] += len(rids)
+                        stats["prefill_tokens"] += n_prompt
+                        for s, r, t in zip(take_slots, rids, first):
+                            outputs[r] = [t]
+                            slot_rid[s], slot_rem[s] = r, gen_tokens - 1
+                            # first token comes from the prefill logits, so
+                            # admission time IS first-token time here
+                            req_admit[r] = ta
+                        for s in take_slots:   # gen_tokens == 1 ends now
+                            if slot_rem[s] <= 0:
+                                r = slot_rid[s]
+                                self._request_done(
+                                    int(requests[r].shape[0]), gen_tokens,
+                                    t_enq, req_admit[r], req_admit[r])
+                                slot_rid[s] = -1
+                                slot_rsv[s] = 0
             if not any(r >= 0 for r in slot_rid):
                 continue
 
-            key, sub = jax.random.split(key)
-            t0_us = tr.now_us() if tr else 0.0
-            if cfg.n_spec:
-                state, cache, toks, emitted = self._dispatch_spec(
-                    self.params, self._draft_params, state, cache,
-                    jnp.int32(spec_ctl.depth), sub)
-            else:
-                state, cache, toks, emitted = self._dispatch(
-                    self.params, state, cache, sub)
+            with span("engine", "dispatch", tr,
+                      dispatch="spec" if cfg.n_spec else "decode"):
+                key, sub = jax.random.split(key)
+                if cfg.n_spec:
+                    state, cache, toks, emitted = self._dispatch_spec(
+                        self.params, self._draft_params, state, cache,
+                        jnp.int32(spec_ctl.depth), sub)
+                else:
+                    state, cache, toks, emitted = self._dispatch(
+                        self.params, state, cache, sub)
             # the counter tree rides the returned state: same sync, no cost
-            toks_h, em_h, ctr_host = jax.device_get(
-                (toks, emitted, state["ctr"]))
-            totals = counter_totals(ctr_host)
-            if cfg.n_spec:
-                d_dr = totals["drafted"] - ctr_prev["drafted"]
-                d_ac = totals["accepted"] - ctr_prev["accepted"]
-                stats["draft_tokens"] += d_dr
-                stats["draft_accepted"] += d_ac
-                stats["spec_rounds"] += K
-                depth_used = spec_ctl.depth
-                stats["spec_depth"] = spec_ctl.update(d_dr, d_ac)
-                self._trace_dispatch(t0_us, totals, depth=depth_used,
-                                     drafted=d_dr, accepted=d_ac)
-            else:
-                self._trace_dispatch(t0_us, totals)
-            ctr_prev = totals
-            stats["host_syncs"] += 1
-            stats["dispatches"] += 1
-            stats["decode_steps"] += K
-            if cfg.paged and cfg.check_invariants:
-                self._assert_invariants(cache, state)
-            for s in range(B):
-                r = slot_rid[s]
-                if r < 0:
-                    continue
-                row = [int(t) for t in toks_h[s][em_h[s]]]
-                outputs[r].extend(row)
-                stats["tokens"] += len(row)
-                slot_rem[s] -= len(row)
-                if slot_rem[s] <= 0:
-                    slot_rid[s] = -1
-                    slot_rsv[s] = 0  # device freed the blocks mid-scan
-                    self._request_done(int(requests[r].shape[0]),
-                                       gen_tokens, t_enq, req_admit[r],
-                                       req_admit[r])
+            with span("engine", "sync", tr):
+                toks_h, em_h, ctr_host = jax.device_get(
+                    (toks, emitted, state["ctr"]))
+            with span("engine", "host", tr):
+                totals = counter_totals(ctr_host)
+                self._count_dispatch(stats, totals, ctr_prev, spec_ctl)
+                ctr_prev = totals
+                if cfg.paged and cfg.check_invariants:
+                    self._assert_invariants(cache, state)
+                for s in range(B):
+                    r = slot_rid[s]
+                    if r < 0:
+                        continue
+                    row = [int(t) for t in toks_h[s][em_h[s]]]
+                    outputs[r].extend(row)
+                    stats["tokens"] += len(row)
+                    slot_rem[s] -= len(row)
+                    if slot_rem[s] <= 0:
+                        slot_rid[s] = -1
+                        slot_rsv[s] = 0  # device freed the blocks mid-scan
+                        self._request_done(int(requests[r].shape[0]),
+                                           gen_tokens, t_enq, req_admit[r],
+                                           req_admit[r])
 
         self._finalize_serve(stats, ctr_host)
         outs = [outputs[i] for i in sorted(outputs)]
@@ -972,10 +991,9 @@ class Engine:
         def try_evict(want: int) -> int:
             ids = self._index.evict(want) if self._can_match else []
             if ids:
-                drop_holds(ids)
+                with span("engine", "evict", tr, blocks=len(ids)):
+                    drop_holds(ids)
                 stats["prefix_evictions"] += len(ids)
-                if tr:
-                    tr.instant("eviction", "evict", {"blocks": len(ids)})
             return len(ids)
 
         while queue or any(r >= 0 for r in slot_rid):
@@ -1050,127 +1068,115 @@ class Engine:
                     break
                 s = free.pop(0)
                 queue.popleft()
-                t0_us = tr.now_us() if tr else 0.0
-                shared_arr = np.full((self._mb,), -1, np.int32)
-                shared_arr[:len(shared)] = shared
-                cache, state, new_ids = self._admit_chunk(
-                    cache, state, jnp.int32(s),
-                    jnp.asarray(np.pad(toks_np, (0, pcap - L)), jnp.int32),
-                    jnp.int32(L), jnp.asarray(shared_arr),
-                    jnp.int32(n_shared), jnp.int32(n_new),
-                    jnp.int32(n_ret), jnp.int32(pf_start),
-                    jnp.int32(matched_tokens), jnp.int32(gen_tokens - 1))
-                slot_rid[s], slot_rem[s] = rid, gen_tokens
-                slot_rsv[s] = slot_need
-                slot_pf[s] = L - pf_start
-                slot_keys[s] = keys
-                outputs[rid] = []
-                req_admit[rid] = time.perf_counter()
-                req_pf[rid] = pf_start / L if L else 0.0
-                if tr:
-                    tr.complete("admission", f"req{rid}", t0_us,
-                                {"prompt_len": L, "prefix_hit": pf_start,
-                                 "shared_blocks": n_shared})
-                    slot_t0us[s] = tr.now_us()
-                stats["prefill_tokens"] += L - pf_start
-                stats["prefix_hits"] += pf_start   # tokens NOT recomputed
-                stats["prefill_calls"] += 1
-                if n_ret:
-                    ids = [int(i) for i in
-                           jax.device_get(new_ids)[:n_ret]]
-                    stats["host_syncs"] += 1
-                    self._hold_blocks.update(ids)
-                    slot_pend[s] = (toks_np, matched_full, ids)
-                if cfg.check_invariants:
-                    self._assert_invariants(cache, state)
+                with span("engine", "admit", tr, prompt_len=L,
+                          prefix_hit=pf_start):
+                    shared_arr = np.full((self._mb,), -1, np.int32)
+                    shared_arr[:len(shared)] = shared
+                    cache, state, new_ids = self._admit_chunk(
+                        cache, state, jnp.int32(s),
+                        jnp.asarray(np.pad(toks_np, (0, pcap - L)),
+                                    jnp.int32),
+                        jnp.int32(L), jnp.asarray(shared_arr),
+                        jnp.int32(n_shared), jnp.int32(n_new),
+                        jnp.int32(n_ret), jnp.int32(pf_start),
+                        jnp.int32(matched_tokens), jnp.int32(gen_tokens - 1))
+                    slot_rid[s], slot_rem[s] = rid, gen_tokens
+                    slot_rsv[s] = slot_need
+                    slot_pf[s] = L - pf_start
+                    slot_keys[s] = keys
+                    outputs[rid] = []
+                    req_admit[rid] = time.perf_counter()
+                    req_pf[rid] = pf_start / L if L else 0.0
+                    stats["prefill_tokens"] += L - pf_start
+                    stats["prefix_hits"] += pf_start  # tokens NOT recomputed
+                    stats["prefill_calls"] += 1
+                    if n_ret:
+                        ids = [int(i) for i in
+                               jax.device_get(new_ids)[:n_ret]]
+                        stats["host_syncs"] += 1
+                        self._hold_blocks.update(ids)
+                        slot_pend[s] = (toks_np, matched_full, ids)
+                    if cfg.check_invariants:
+                        self._assert_invariants(cache, state)
+                slot_t0us[s] = clock_us()
             if not any(r >= 0 for r in slot_rid):
                 assert not queue, "admission stalled with an idle pool"
                 continue
 
-            key, sub = jax.random.split(key)
             prefilling = any(p > 0 for p in slot_pf)
-            t0_us = tr.now_us() if tr else 0.0
-            if cfg.n_spec:
-                dispatch = (self._dispatch_spec_chunk if prefilling
-                            else self._dispatch_spec)
-                state, cache, toks, emitted = dispatch(
-                    self.params, self._draft_params, state, cache,
-                    jnp.int32(spec_ctl.depth), sub)
-            else:
-                dispatch = (self._dispatch_chunk if prefilling
-                            else self._dispatch)
-                state, cache, toks, emitted = dispatch(
-                    self.params, state, cache, sub)
+            kind = ("spec_" if cfg.n_spec else "") + \
+                ("chunk" if prefilling else "decode")
+            with span("engine", "dispatch", tr, dispatch=kind):
+                key, sub = jax.random.split(key)
+                if cfg.n_spec:
+                    dispatch = (self._dispatch_spec_chunk if prefilling
+                                else self._dispatch_spec)
+                    state, cache, toks, emitted = dispatch(
+                        self.params, self._draft_params, state, cache,
+                        jnp.int32(spec_ctl.depth), sub)
+                else:
+                    dispatch = (self._dispatch_chunk if prefilling
+                                else self._dispatch)
+                    state, cache, toks, emitted = dispatch(
+                        self.params, state, cache, sub)
             # the counter tree rides the returned state: same sync, no cost
-            toks_h, em_h, ctr_host = jax.device_get(
-                (toks, emitted, state["ctr"]))
-            totals = counter_totals(ctr_host)
-            if cfg.n_spec:
-                d_dr = totals["drafted"] - ctr_prev["drafted"]
-                d_ac = totals["accepted"] - ctr_prev["accepted"]
-                stats["draft_tokens"] += d_dr
-                stats["draft_accepted"] += d_ac
-                stats["spec_rounds"] += K
-                depth_used = spec_ctl.depth
-                stats["spec_depth"] = spec_ctl.update(d_dr, d_ac)
-                self._trace_dispatch(t0_us, totals, depth=depth_used,
-                                     drafted=d_dr, accepted=d_ac)
-            else:
-                self._trace_dispatch(t0_us, totals)
-            ctr_prev = totals
-            stats["host_syncs"] += 1
-            stats["dispatches"] += 1
-            stats["decode_steps"] += K
-            for s in range(B):
-                if slot_rid[s] < 0 or slot_pf[s] <= 0:
-                    continue
-                slot_pf[s] = max(0, slot_pf[s] - K * C)
-                if slot_pf[s] > 0:
-                    continue
-                if tr:
-                    tr.complete("prefill-chunk", f"req{slot_rid[s]}",
-                                slot_t0us[s])
-                if slot_pend[s] is not None:
-                    # the slot's new full prompt blocks now hold real KV:
-                    # publish them to the prefix index (duplicates lose
-                    # their pre-retained hold and die with the slot)
-                    toks_np, first_block, ids = slot_pend[s]
-                    slot_pend[s] = None
-                    dups = self._index.register(toks_np, ids, first_block)
-                    if dups:
-                        drop_holds(dups)
-                        slot_rsv[s] += len(dups)
-                    dup_set = set(dups)
-                    nkeys = self._index.keys_for(toks_np,
-                                                 first_block + len(ids))
-                    reg_keys = [k for k, bid in
-                                zip(nkeys[first_block:], ids)
-                                if bid not in dup_set]
-                    self._index.pin(reg_keys)
-                    slot_keys[s] = slot_keys[s] + reg_keys
-            if cfg.check_invariants:
-                self._assert_invariants(cache, state)
-            for s in range(B):
-                r = slot_rid[s]
-                if r < 0:
-                    continue
-                row = [int(t) for t in toks_h[s][em_h[s]]]
-                if row and r not in req_first:
-                    req_first[r] = time.perf_counter()
-                outputs[r].extend(row)
-                stats["tokens"] += len(row)
-                slot_rem[s] -= len(row)
-                if slot_rem[s] <= 0:
-                    assert slot_pend[s] is None, \
-                        "slot finished before its prompt finished prefilling"
-                    slot_rid[s] = -1
-                    slot_rsv[s] = 0
-                    self._index.unpin(slot_keys[s])
-                    slot_keys[s] = []
-                    self._request_done(
-                        int(requests[r].shape[0]), gen_tokens, t_enq,
-                        req_admit[r], req_first.get(r, req_admit[r]),
-                        prefix_hit_frac=req_pf.get(r))
+            with span("engine", "sync", tr):
+                toks_h, em_h, ctr_host = jax.device_get(
+                    (toks, emitted, state["ctr"]))
+            with span("engine", "host", tr):
+                totals = counter_totals(ctr_host)
+                self._count_dispatch(stats, totals, ctr_prev, spec_ctl)
+                ctr_prev = totals
+                for s in range(B):
+                    if slot_rid[s] < 0 or slot_pf[s] <= 0:
+                        continue
+                    slot_pf[s] = max(0, slot_pf[s] - K * C)
+                    if slot_pf[s] > 0:
+                        continue
+                    if tr:
+                        tr.complete("prefill-chunk", f"req{slot_rid[s]}",
+                                    slot_t0us[s])
+                    if slot_pend[s] is not None:
+                        # the slot's new full prompt blocks now hold real KV:
+                        # publish them to the prefix index (duplicates lose
+                        # their pre-retained hold and die with the slot)
+                        toks_np, first_block, ids = slot_pend[s]
+                        slot_pend[s] = None
+                        dups = self._index.register(toks_np, ids, first_block)
+                        if dups:
+                            drop_holds(dups)
+                            slot_rsv[s] += len(dups)
+                        dup_set = set(dups)
+                        nkeys = self._index.keys_for(toks_np,
+                                                     first_block + len(ids))
+                        reg_keys = [k for k, bid in
+                                    zip(nkeys[first_block:], ids)
+                                    if bid not in dup_set]
+                        self._index.pin(reg_keys)
+                        slot_keys[s] = slot_keys[s] + reg_keys
+                if cfg.check_invariants:
+                    self._assert_invariants(cache, state)
+                for s in range(B):
+                    r = slot_rid[s]
+                    if r < 0:
+                        continue
+                    row = [int(t) for t in toks_h[s][em_h[s]]]
+                    if row and r not in req_first:
+                        req_first[r] = time.perf_counter()
+                    outputs[r].extend(row)
+                    stats["tokens"] += len(row)
+                    slot_rem[s] -= len(row)
+                    if slot_rem[s] <= 0:
+                        assert slot_pend[s] is None, \
+                            "slot finished before its prompt was prefilled"
+                        slot_rid[s] = -1
+                        slot_rsv[s] = 0
+                        self._index.unpin(slot_keys[s])
+                        slot_keys[s] = []
+                        self._request_done(
+                            int(requests[r].shape[0]), gen_tokens, t_enq,
+                            req_admit[r], req_first.get(r, req_admit[r]),
+                            prefix_hit_frac=req_pf.get(r))
 
         self._finalize_serve(stats, ctr_host)
         if persist:

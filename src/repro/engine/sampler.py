@@ -75,6 +75,7 @@ def probs(logits: jnp.ndarray, sp: SamplingParams) -> jnp.ndarray:
     return jax.nn.softmax(warp_logits(logits, sp), axis=-1)
 
 
+@jax.named_scope("sample")
 def sample(logits: jnp.ndarray, key, sp: SamplingParams) -> jnp.ndarray:
     """logits [..., V] -> token ids [...] (int32).  jit- and scan-safe."""
     if sp.greedy:

@@ -62,6 +62,7 @@ def init_slot_state(n_slots: int, prompt_cap: int = 0) -> dict:
     return st
 
 
+@jax.named_scope("keep_rows")
 def _keep_rows(new_cache: dict, old_cache: dict, keep) -> dict:
     """Merge two paged caches per slot: rows of slots in ``keep`` come from
     ``new_cache``, others are restored from ``old_cache``.  Pool leaves
@@ -87,6 +88,7 @@ def _keep_rows(new_cache: dict, old_cache: dict, keep) -> dict:
     return merged
 
 
+@jax.named_scope("prefill_chunk")
 def chunk_prefill_substep(model: Model, sp: SamplingParams, chunk: int,
                           params, st: dict, cache: dict, first_key):
     """One in-scan chunked-prefill piece — the prefill *phase* of a scan
@@ -133,6 +135,13 @@ def chunk_prefill_substep(model: Model, sp: SamplingParams, chunk: int,
           "pf_pos": st["pf_pos"] + valid,
           "ctr": ctr}
     return st, cache, first, completed
+
+
+def named_dispatch(fn, name: str):
+    """``fn`` under ``name``: jit names the module ``jit_<name>``, so each
+    dispatch variant's device ops read ``jit_<name>:<op>`` in a trace."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 def make_decode_dispatch(model: Model, sp: SamplingParams, k_steps: int,
@@ -234,7 +243,8 @@ def make_decode_dispatch(model: Model, sp: SamplingParams, k_steps: int,
             body, (state, cache), keys)
         return state, cache, toks.T, emitted.T
 
-    return dispatch
+    return named_dispatch(dispatch, "chunk_dispatch" if chunk
+                          else "decode_dispatch")
 
 
 def make_decode_step(model: Model, sp: SamplingParams | None = None):

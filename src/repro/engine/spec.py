@@ -107,7 +107,7 @@ import jax.numpy as jnp
 
 from repro.engine.paged import BSTATE_KEYS, alloc_span, release_slots
 from repro.engine.sampler import SamplingParams, probs, sample
-from repro.engine.scheduler import chunk_prefill_substep
+from repro.engine.scheduler import chunk_prefill_substep, named_dispatch
 from repro.models.lm import Model, cow_copy_blocks
 from repro.telemetry.counters import bump
 
@@ -410,4 +410,5 @@ def make_spec_dispatch(model: Model, sp: SamplingParams, k_steps: int,
         em = em.transpose(1, 0, 2).reshape(B, k_steps * S1)
         return state, cache, toks, em
 
-    return dispatch
+    return named_dispatch(dispatch, "spec_chunk_dispatch" if chunk
+                          else "spec_dispatch")

@@ -144,6 +144,7 @@ def chunked_attention(q, k, v, *, causal: bool, window: int = 0,
 # Decode attention (one new token against a cache)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("attention")
 def decode_attention(q, k_cache, v_cache, lengths, *, window: int = 0,
                      softcap: float = 0.0) -> jnp.ndarray:
     """q [B,1,H,hd]; caches [B,S,Kv,hd]; lengths [B] = #valid entries
@@ -187,6 +188,7 @@ def write_paged_kv_span(pk, pv, k_new, v_new, wblk, woff):
     return pk, pv
 
 
+@jax.named_scope("attention")
 def paged_prefill_attention(q, pk, pv, k_new, v_new, tbl, start, valid, *,
                             sliding_window=0, softcap=0.0) -> jnp.ndarray:
     """Prefill-chunk attention against the paged cache.
@@ -237,6 +239,7 @@ def paged_prefill_attention(q, pk, pv, k_new, v_new, tbl, start, valid, *,
                              kv_offsets=kv_off, kv_lengths=n_valid)
 
 
+@jax.named_scope("attention")
 def paged_verify_attention(q, pk, pv, k_new, v_new, tbl, start, valid, *,
                            sliding_window=0, softcap=0.0) -> jnp.ndarray:
     """Multi-token *verify* attention against the paged cache: row ``j`` of
@@ -322,6 +325,7 @@ def paged_verify_attention(q, pk, pv, k_new, v_new, tbl, start, valid, *,
     return out.reshape(B, C, H, hd).astype(q.dtype)
 
 
+@jax.named_scope("attention")
 def paged_decode_attention(q, pk, pv, tbl, lengths, *, sliding_window=0,
                            softcap=0.0) -> jnp.ndarray:
     """Decode attention against paged K/V pools.

@@ -52,6 +52,7 @@ def resolve(w):
     return w
 
 
+@jax.named_scope("qlinear")
 def matmul(x: jnp.ndarray, w, *, precision=None) -> jnp.ndarray:
     """x @ w with w possibly quantized. x: [..., in], w: [in, out]."""
     if RECORD is not None and not isinstance(x, jax.core.Tracer):
@@ -70,6 +71,7 @@ def matmul(x: jnp.ndarray, w, *, precision=None) -> jnp.ndarray:
     return jnp.matmul(x, w.astype(x.dtype), precision=precision)
 
 
+@jax.named_scope("qlinear")
 def matmul_t(x: jnp.ndarray, w) -> jnp.ndarray:
     """``x @ w.T`` with ``w`` possibly quantized — the tied-embedding LM
     head (``x [..., D] @ table[V, D].T``), which is the hot op of a
@@ -102,6 +104,7 @@ def matmul_t(x: jnp.ndarray, w) -> jnp.ndarray:
     return jnp.matmul(x, w.dequantize().T.astype(x.dtype))
 
 
+@jax.named_scope("qlinear")
 def take(embedding, ids):
     """Embedding lookup with optional quantized table.
 

@@ -31,6 +31,7 @@ from repro.core.policy import path_str, should_quantize
 from repro.core.search import SearchResult
 from repro.quant_runtime.qparams import QuantizedTensor
 from repro.quantize.registry import get_method
+from repro.telemetry.trace import span
 
 
 # ---------------------------------------------------------------------------
@@ -88,6 +89,7 @@ class QuantReport:
     quantized_bytes: int = 0
     original_bytes: int = 0
     method: str = ""
+    host_reads: int = 0          # blocking device -> host reads made
 
     def summary(self) -> str:
         g, d = self.global_chosen, self.global_default
@@ -108,13 +110,23 @@ class QuantReport:
 # Entry point
 # ---------------------------------------------------------------------------
 
-def _scalar_sum(x) -> float:
-    return float(jnp.sum(x))
+_LEAF_METRICS = ("mse", "sign_rate", "cosine", "delta_l2")
 
 
-def _mean_metric(d: dict, m: str) -> float:
+def _read(report: QuantReport, x, get=float):
+    """One blocking device -> host read, ``get(x)``, counted in
+    ``report.host_reads``."""
+    report.host_reads += 1
+    return get(x)
+
+
+def _scalar_sum(report: QuantReport, x) -> float:
+    return _read(report, jnp.sum(x))
+
+
+def _mean_metric(report: QuantReport, d: dict, m: str) -> float:
     """Per-leaf metric: mean over stacked layers when the leaf was vmapped."""
-    return float(jnp.mean(d[m]))
+    return _read(report, jnp.mean(d[m]))
 
 
 def quantize(params_post: Any, params_base: Any = None,
@@ -180,32 +192,36 @@ def quantize(params_post: Any, params_base: Any = None,
             report.n_skipped += 1
             out_leaves.append(w_post)
             continue
-        res = quantizer.prepare(LeafContext(leaf_name, w_post, w_base, qcfg))
-        report.n_quantized += 1
-        report.original_bytes += w_post.size * w_post.dtype.itemsize
-        for k in M.PARTIAL_KEYS:
-            agg_c[k] += _scalar_sum(res.chosen[k])
-            agg_d[k] += _scalar_sum(res.default[k])
-        report.per_leaf[leaf_name] = {
-            "alpha": jax.device_get(res.alpha),
-            "chosen": {m: _mean_metric(res.chosen, m) for m in
-                       ("mse", "sign_rate", "cosine", "delta_l2")},
-            "default": {m: _mean_metric(res.default, m) for m in
-                        ("mse", "sign_rate", "cosine", "delta_l2")},
-            "shape": tuple(w_post.shape),
-        }
-        qt = QuantizedTensor(data=res.w_q, scale=res.scale, fmt=qcfg.fmt,
-                             granularity=qcfg.granularity,
-                             block_size=qcfg.block_size,
-                             out_dtype=out_dtype, eq_scale=res.eq_scale)
-        report.quantized_bytes += qt.nbytes()
-        # dequant mode: Q_s(W_post) rebuilt from storage, bit-exact
-        out_leaves.append(qt if mode == "storage" else qt.dequantize())
+        with span("quantize", "leaf", leaf=leaf_name):
+            with span("quantize", "search"):
+                res = quantizer.prepare(LeafContext(leaf_name, w_post,
+                                                    w_base, qcfg))
+            report.n_quantized += 1
+            report.original_bytes += w_post.size * w_post.dtype.itemsize
+            with span("quantize", "report"):
+                for k in M.PARTIAL_KEYS:
+                    agg_c[k] += _scalar_sum(report, res.chosen[k])
+                    agg_d[k] += _scalar_sum(report, res.default[k])
+                report.per_leaf[leaf_name] = {
+                    "alpha": _read(report, res.alpha, jax.device_get),
+                    "chosen": {m: _mean_metric(report, res.chosen, m)
+                               for m in _LEAF_METRICS},
+                    "default": {m: _mean_metric(report, res.default, m)
+                                for m in _LEAF_METRICS},
+                    "shape": tuple(w_post.shape),
+                }
+            qt = QuantizedTensor(data=res.w_q, scale=res.scale, fmt=qcfg.fmt,
+                                 granularity=qcfg.granularity,
+                                 block_size=qcfg.block_size,
+                                 out_dtype=out_dtype, eq_scale=res.eq_scale)
+            report.quantized_bytes += qt.nbytes()
+            # dequant mode: Q_s(W_post) rebuilt from storage, bit-exact
+            out_leaves.append(qt if mode == "storage" else qt.dequantize())
 
-    agg_cj = {k: jnp.asarray(v) for k, v in agg_c.items()}
-    agg_dj = {k: jnp.asarray(v) for k, v in agg_d.items()}
-    report.global_chosen = {k: float(v) for k, v in
-                            M.metrics_from_partials(agg_cj).items()}
-    report.global_default = {k: float(v) for k, v in
-                             M.metrics_from_partials(agg_dj).items()}
+    with span("quantize", "global"):
+        for agg, dst in ((agg_c, report.global_chosen),
+                         (agg_d, report.global_default)):
+            parts = {k: jnp.asarray(v) for k, v in agg.items()}
+            for k, v in M.metrics_from_partials(parts).items():
+                dst[k] = _read(report, v)
     return jax.tree_util.tree_unflatten(treedef, out_leaves), report

@@ -1,45 +1,82 @@
-"""Chrome/Perfetto trace export for the serving engine.
+"""Program spans on the profiler's clock, and Chrome/Perfetto trace export.
 
-:class:`Tracer` records trace events host-side (timestamps from
-``time.perf_counter`` relative to tracer construction, in microseconds —
-the Chrome trace-event clock unit) and serializes them in the Chrome
-trace-event JSON-object format::
+:func:`span` is the one span primitive of the program.  It always opens a
+``jax.profiler.TraceAnnotation`` named ``"<track>.<name>"`` (a few
+microseconds when no profiler runs), so a ``jax.profiler`` trace shows the
+program's own phases: ``engine.admit``, ``engine.dispatch``,
+``engine.sync``, ``engine.host``, ``engine.evict`` in the serving engine;
+``quantize.leaf``, ``quantize.search``, ``quantize.report``,
+``quantize.global`` in ``repro.quantize.quantize``.  When a
+:class:`Tracer` is passed, the span is also recorded as a Chrome "X" event
+on track ``track``.
+
+:class:`Tracer` serializes its events in the Chrome trace-event JSON-object
+format::
 
     {"traceEvents": [...], "displayTimeUnit": "ms"}
 
 Open the file in Perfetto (https://ui.perfetto.dev) or
-``chrome://tracing``.  One *track* (a named tid under one engine pid) per
-subsystem — the engine uses ``admission``, ``dispatch``, ``spec``,
-``prefill-chunk`` and ``eviction`` — plus counter tracks ("C" events)
-sampled from the device counter tree after each dispatch.  Recording an
+``chrome://tracing``.  Its timestamps come from :func:`clock_us`, the clock
+the profiler stamps host events with (``CLOCK_REALTIME``), so the JSON and
+an ``.xplane.pb`` of the same run lie on one time axis: an xplane event's
+``start_ns`` is an offset from :func:`xplane_origin_us`.  Recording an
 event is an O(1) list append of values already on the host; the tracer
 never touches the device.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import time
 
+import jax
+
 TRACE_PID = 1   # one "process": the engine
+
+
+def clock_us() -> float:
+    """Microseconds on the profiler's host clock (``CLOCK_REALTIME``)."""
+    return time.time_ns() / 1e3
+
+
+def xplane_origin_us(profile_data) -> float:
+    """The instant, in :func:`clock_us` microseconds, that the event times
+    of a ``jax.profiler.ProfileData`` count from: its ``profile_start_time``
+    (``start_ns / 1e3`` plus this is an event's Chrome ``ts``)."""
+    for plane in profile_data.planes:
+        for key, value in plane.stats:
+            if key == "profile_start_time":
+                return value / 1e3
+    raise ValueError("the trace records no profile_start_time")
+
+
+@contextlib.contextmanager
+def span(track: str, name: str, tracer: "Tracer | None" = None, **args):
+    """A program span: a profiler annotation ``"<track>.<name>"`` carrying
+    ``args``, and, with a ``tracer``, a Chrome "X" event on ``track``."""
+    with jax.profiler.TraceAnnotation(f"{track}.{name}", **args):
+        if tracer is None:
+            yield
+            return
+        t0 = clock_us()
+        try:
+            yield
+        finally:
+            tracer.complete(track, name, t0, args)
 
 
 class Tracer:
     """Host-side Chrome trace-event recorder.
 
-    Events within a track are recorded in wall order with a monotonic
-    clock, so per-track ``ts`` is non-decreasing (a schema property the
-    tests pin).  Duration ("X") events take their start from
-    :meth:`now_us`, captured by the caller before the spanned work.
+    Events within a track are recorded in wall order, so per-track ``ts``
+    is non-decreasing (a schema property the tests pin).  Duration ("X")
+    events take their start from :func:`clock_us`, captured by the caller
+    (or by :func:`span`) before the spanned work.
     """
 
     def __init__(self):
-        self._t0 = time.perf_counter()
         self._tids: dict[str, int] = {}
         self.events: list[dict] = []
-
-    def now_us(self) -> float:
-        """Microseconds since tracer construction (trace clock)."""
-        return (time.perf_counter() - self._t0) * 1e6
 
     def _tid(self, track: str) -> int:
         tid = self._tids.get(track)
@@ -55,22 +92,10 @@ class Tracer:
     def complete(self, track: str, name: str, start_us: float,
                  args: dict | None = None) -> None:
         """A duration ("X") event spanning ``start_us`` .. now."""
-        now = self.now_us()
         ev = {
             "name": name, "ph": "X", "pid": TRACE_PID,
             "tid": self._tid(track), "ts": start_us,
-            "dur": max(now - start_us, 0.0),
-        }
-        if args:
-            ev["args"] = args
-        self.events.append(ev)
-
-    def instant(self, track: str, name: str,
-                args: dict | None = None) -> None:
-        """An instant ("i") event at the current time."""
-        ev = {
-            "name": name, "ph": "i", "pid": TRACE_PID,
-            "tid": self._tid(track), "ts": self.now_us(), "s": "t",
+            "dur": max(clock_us() - start_us, 0.0),
         }
         if args:
             ev["args"] = args
@@ -81,7 +106,7 @@ class Tracer:
         rendered by the viewer as a stacked area track."""
         self.events.append({
             "name": name, "ph": "C", "pid": TRACE_PID,
-            "ts": self.now_us(),
+            "ts": clock_us(),
             "args": {k: float(v) for k, v in values.items()},
         })
 

@@ -17,6 +17,7 @@ from repro.engine import Engine
 from repro.models import build_model
 from repro.telemetry import (COUNTER_KEYS, METRICS_SCHEMA, Histogram,
                              MetricsRegistry, Tracer)
+from repro.telemetry.trace import clock_us, span
 from repro.telemetry.metrics import log_bucket_edges
 
 KEY = jax.random.PRNGKey(0)
@@ -155,9 +156,6 @@ def _check_chrome_trace(doc):
         assert ev["ts"] >= 0
         if ev["ph"] == "X":
             assert ev["dur"] >= 0
-        if ev["ph"] == "i":
-            assert ev["s"] in ("t", "p", "g")
-        if ev["ph"] in ("X", "i"):
             per_track.setdefault(ev["tid"], []).append(ev["ts"])
     for tid, ts in per_track.items():
         assert ts == sorted(ts), f"track {tid} timestamps not monotonic"
@@ -165,22 +163,33 @@ def _check_chrome_trace(doc):
     return evs
 
 
+def _tid(evs, track):
+    return next(ev["tid"] for ev in evs if ev["ph"] == "M"
+                and ev["name"] == "thread_name"
+                and ev["args"]["name"] == track)
+
+
 def test_tracer_chrome_format(tmp_path):
     tr = Tracer()
-    t0 = tr.now_us()
-    tr.instant("admission", "req0", {"prompt_len": 16})
-    tr.complete("dispatch", "decode", t0, {"k_steps": 8})
+    t0 = clock_us()
+    with span("engine", "admit", tr, prompt_len=16):
+        pass
+    tr.complete("prefill-chunk", "req0", t0, {"k_steps": 8})
     tr.counter("tokens", {"emitted": 5})
-    tr.instant("admission", "req1")
-    tr.complete("dispatch", "decode", tr.now_us())
+    with span("engine", "admit", tr):
+        pass
+    with span("engine", "dispatch"):            # no tracer: no event
+        pass
+    tr.complete("prefill-chunk", "req1", clock_us())
     evs = _check_chrome_trace(tr.to_dict())
-    assert sum(ev["ph"] == "X" for ev in evs) == 2
-    assert sum(ev["ph"] == "i" for ev in evs) == 2
+    assert sum(ev["ph"] == "X" for ev in evs) == 4
     assert sum(ev["ph"] == "C" for ev in evs) == 1
-    # the two admission events share one track, dispatch another
+    # the two admission spans share one track, prefill chunks another
     tracks = {ev["args"]["name"] for ev in evs
               if ev["ph"] == "M" and ev["name"] == "thread_name"}
-    assert tracks == {"admission", "dispatch"}
+    assert tracks == {"engine", "prefill-chunk"}
+    admits = [ev for ev in evs if ev.get("name") == "admit"]
+    assert [ev.get("args") for ev in admits] == [{"prompt_len": 16}, None]
     path = tmp_path / "trace.json"
     tr.save(path)
     assert json.loads(path.read_text()) == tr.to_dict()
@@ -248,7 +257,10 @@ def test_serve_metrics_free_on_hot_path(tmp_path):
     evs = _check_chrome_trace(tr.to_dict())
     tracks = {ev["args"]["name"] for ev in evs
               if ev["ph"] == "M" and ev["name"] == "thread_name"}
-    assert {"admission", "dispatch", "prefill-chunk"} <= tracks
+    assert {"engine", "prefill-chunk"} <= tracks
+    spans = {ev["name"] for ev in evs if ev["ph"] == "X"
+             and ev["tid"] == _tid(evs, "engine")}
+    assert {"admit", "dispatch", "sync", "host"} <= spans
     assert any(ev["ph"] == "C" for ev in evs)
 
     # warm second serve: counters re-zero, conservation re-baselines on
