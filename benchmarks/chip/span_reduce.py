@@ -17,6 +17,8 @@ over devices.
 * :func:`device_scopes` — device self seconds per scope, from a
   ``{"<module>:<op>": scope path}`` map (``scopes.op_scopes`` of each
   compiled module): an op counts towards every scope on its path.
+
+``trace_reduce.reduce`` picks the same window with code of its own.
 """
 from __future__ import annotations
 
@@ -75,16 +77,24 @@ def idle_in(devices: dict, host: dict) -> dict:
             for n, ivs in sorted(spans.items())}
 
 
-def device_scopes(devices: dict, host: dict, scope_map: dict) -> dict:
-    """``{scope: device self seconds of the ops on whose path it lies}``
-    inside the window, averaged over devices."""
+def self_seconds(devices: dict, host: dict) -> dict:
+    """``{op: device self seconds}`` inside the window, averaged over
+    devices."""
     w = window(host)
     if not w or not devices:
         return {}
-    w0, w1, _ = w
     out = defaultdict(float)
     for evs in devices.values():
-        for op, t in self_times(evs, w0, w1).items():
-            for scope in filter(None, scope_map.get(op, "").split("/")):
-                out[scope] += t / len(devices) / 1e9
+        for op, t in self_times(evs, w[0], w[1]).items():
+            out[op] += t / len(devices) / 1e9
+    return dict(out)
+
+
+def device_scopes(devices: dict, host: dict, scope_map: dict) -> dict:
+    """``{scope: device self seconds of the ops on whose path it lies}``
+    inside the window, averaged over devices."""
+    out = defaultdict(float)
+    for op, t in self_seconds(devices, host).items():
+        for scope in filter(None, scope_map.get(op, "").split("/")):
+            out[scope] += t
     return dict(out)

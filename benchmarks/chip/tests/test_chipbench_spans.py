@@ -7,10 +7,14 @@ that trace the first ops start about a millisecond before the host span
 ``window`` that dispatched them: the device clock is that far off the
 host's, and the window clips them."""
 import json
+import os
+import subprocess
+import sys
 
 import pytest
 
 import chipbench_tiny
+from benchmarks.chip import peaks, timed
 from benchmarks.chip import span_reduce as S
 from benchmarks.chip import trace_reduce as T
 
@@ -56,6 +60,83 @@ def test_device_scopes_count_self_time_on_every_scope_of_the_path():
 def test_overlap_of_interval_lists():
     assert S.overlap([(0, 5), (8, 12)], [(3, 9), (11, 20)]) == 2 + 1 + 1
     assert S.overlap([], [(0, 1)]) == 0
+
+
+def _read(name, ctx):
+    return timed.load_file(chipbench_tiny.CHIP / "metrics" / f"{name}.py",
+                           "read")(ctx)
+
+
+def test_span_readers_read_the_reductions():
+    smap = {"m:a": "prefill_chunk/qlinear", "m:b": "attention"}
+    trace = T.reduce(DEVICES, HOST)
+    trace.update(idle_in=S.idle_in(DEVICES, HOST),
+                 device_scopes=S.device_scopes(DEVICES, HOST, smap))
+    ctx = {"trace": trace, "work": {"host_reads": 197}}
+    # busy 10 ns of a 40 ns window (averaged over d0 and idle d1)
+    assert trace["busy_s"] == pytest.approx(10e-9)
+    assert _read("host_idle.serve", ctx) == pytest.approx(
+        100 * 24.5 / 40)
+    assert _read("prefill_share.serve", ctx) == pytest.approx(50)
+    assert _read("qlinear_share.serve", ctx) == pytest.approx(50)
+    assert _read("attn_share.serve", ctx) == pytest.approx(50)
+    assert _read("host_reads.quant", ctx) == 197
+    assert _read("report_idle.quant", ctx) is None
+    host = {"main": [("window", 0, 40), ("quantize.report", 5, 25)]}
+    ctx["trace"] = dict(trace, idle_in=S.idle_in(DEVICES, host))
+    assert _read("report_idle.quant", ctx) == pytest.approx(100 * 15 / 40)
+    assert _read("host_idle.serve", ctx) is None
+
+
+@pytest.mark.parametrize("name", ["host_idle.serve", "prefill_share.serve",
+                                  "qlinear_share.serve", "attn_share.serve",
+                                  "report_idle.quant", "host_reads.quant"])
+def test_span_readers_find_nothing_in_a_program_without_spans(name):
+    """A trace reduced without spans or scopes, and work without the
+    counter, as from a program that has none: no reading, no error."""
+    ctx = {"trace": T.reduce(DEVICES, HOST), "work": {"min_bytes": 1}}
+    assert _read(name, ctx) is None
+    assert _read(name, {"trace": {}, "work": {}}) is None
+
+
+@pytest.mark.parametrize("mix", ["decode", "daq"])
+def test_read_cell_on_the_cpu(tmp_path, monkeypatch, mix):
+    """The tiny cells end to end: checked, counted and mapped.  A CPU
+    trace has no TPU plane, so the readers of the trace give nothing."""
+    from benchmarks.chip import check
+    from benchmarks.chip import model as M
+    from benchmarks.chip.spans import read_cell
+    from repro.core.metrics import PARTIAL_KEYS
+    import jax
+    root = chipbench_tiny.make_root(tmp_path)
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    monkeypatch.setitem(peaks.PEAKS, "cpu", peaks.PEAKS["TPU v5 lite"])
+    res = read_cell(root, bench, f"tiny.{mix}", 3, 0.1, jax.devices()[:1])
+    assert res["correct"]
+    assert not any(k in res["metrics"] for k in (
+        "host_idle.serve", "qlinear_share.serve", "report_idle.quant"))
+    if mix == "decode":
+        assert res["mapped_ops"] > 0
+        assert "occupancy.serve" in res["metrics"]
+        assert "host_reads.quant" not in res["metrics"]
+    else:
+        cfg = chipbench_tiny.TINY
+        specs, _ = M.layout(M.model_config(cfg, cfg["distinct_layers"]))
+        n = sum(check.eligible(p, s) for p, s, _, _ in specs)
+        per_leaf = 2 * len(PARTIAL_KEYS) + 1 + 2 * 4
+        assert res["metrics"]["host_reads.quant"] == n * per_leaf + 2 * 4
+        assert res["mapped_ops"] == 0
+
+
+def test_spans_refuses_without_a_tpu(tmp_path):
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+    r = subprocess.run([sys.executable, str(chipbench_tiny.CHIP / "spans.py"),
+                        "--workload", "glm4-9b.daq", "--seed", "1",
+                        "--seconds", "1"], env=env, capture_output=True,
+                       text=True, timeout=300)
+    assert r.returncode == 2
+    assert "needs 1 TPU chip" in r.stderr and not r.stdout
 
 
 def test_nothing_to_read_gives_nothing():

@@ -103,6 +103,14 @@ from repro.telemetry.trace import clock_us, span
 _BKEYS = P.BSTATE_KEYS
 
 
+def _abstract(x):
+    """A device array's shape, dtype and placement; anything else as is."""
+    if not isinstance(x, jax.Array):
+        return x
+    return jax.ShapeDtypeStruct(x.shape, x.dtype, weak_type=x.weak_type,
+                                sharding=x.sharding if x.committed else None)
+
+
 @dataclass(frozen=True)
 class EngineConfig:
     slots: int = 2          # size of the continuous-batching pool
@@ -248,6 +256,7 @@ class Engine:
         # authoritative registry: name -> jitted fn, donated argnums, and
         # where the cache tree sits in the signature / result.
         self._entries: dict[str, dict] = {}
+        self._first_args: dict[str, tuple] = {}
         self._dispatch = self._register(
             "_dispatch",
             make_decode_dispatch(model, sp, K, paged=cfg.paged,
@@ -327,7 +336,14 @@ class Engine:
             "static_argnums": tuple(static_argnums),
             "cache_arg": cache_arg, "cache_out": cache_out,
         }
-        return jitted
+
+        def call(*args):
+            # the signature of the first call, taken before donation frees
+            # the buffers: hlo_texts() lowers the same executable from it
+            if name not in self._first_args:
+                self._first_args[name] = jax.tree.map(_abstract, args)
+            return jitted(*args)
+        return call
 
     def entry_points(self) -> dict[str, dict]:
         """The live jitted entry points of this engine configuration (a
@@ -346,27 +362,16 @@ class Engine:
 
     def hlo_texts(self) -> dict[str, str]:
         """``{module name: compiled HLO text}`` of each dispatch entry that
-        has run.  Each is lowered against ``self.params`` and the cache and
-        slot state that the prefix-cached path keeps between serves, so it
-        is the executable the serve loop ran (with a persistent compile
-        cache, a cache hit); ``repro.telemetry.scopes.op_scopes`` reads the
-        named scopes of its device ops from it.  Needs a prefix-cached
-        engine that has served."""
-        if getattr(self, "_pcache", None) is None:
-            raise ValueError("hlo_texts needs a prefix_cache engine that "
-                             "has served: it lowers against the kept cache")
-        key = jax.random.PRNGKey(0)
+        has run, lowered from the shapes, dtypes and shardings of its first
+        call: the executable the serve loop ran (with a persistent compile
+        cache, a cache hit).  ``repro.telemetry.scopes.op_scopes`` reads the
+        named scopes of its device ops from it."""
         out = {}
-        for name, e in self._entries.items():
-            if not name.startswith("_dispatch") or not e["fn"]._cache_size():
-                continue
-            if "spec" in name:
-                args = (self.params, self._draft_params, self._pstate,
-                        self._pcache, jnp.int32(0), key)
-            else:
-                args = (self.params, self._pstate, self._pcache, key)
-            text = e["fn"].lower(*args).compile().as_text()
-            out[text.split(",", 1)[0].split()[-1]] = text
+        for name, args in self._first_args.items():
+            if name.startswith("_dispatch"):
+                text = self._entries[name]["fn"].lower(*args).compile() \
+                    .as_text()
+                out[text.split(",", 1)[0].split()[-1]] = text
         return out
 
     # -- sharded placement --------------------------------------------------

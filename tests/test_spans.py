@@ -143,11 +143,34 @@ def test_scope_path_reads_op_names():
 
 
 def test_hlo_texts_needs_a_served_prefix_engine(tiny):
+    """Only dispatches that have run have a text: none before a serve."""
     cfg, model, params, _ = tiny
     eng = Engine(model, params, slots=2, cache_len=48, k_steps=4,
                  paged=True, block_size=8, prefix_cache=True, chunk_size=8)
-    with pytest.raises(ValueError, match="prefix_cache"):
-        eng.hlo_texts()
+    assert eng.hlo_texts() == {}
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_hlo_texts_of_an_engine_without_prefix_cache(tiny, tmp_path, paged):
+    """An engine that keeps no cache between serves gives the executable
+    its decode dispatch ran, as the profiler names its ops."""
+    cfg, model, params, prompts = tiny
+    kw = dict(paged=True, block_size=8) if paged else {}
+    eng = Engine(model, params, slots=2, cache_len=48, k_steps=4, **kw)
+    outs, pd = _profile(tmp_path, lambda: eng.serve(prompts, gen_tokens=6))
+    counts = eng.compile_counts()
+    texts = eng.hlo_texts()
+    assert set(texts) == {"jit_decode_dispatch"}
+    assert eng.compile_counts() == counts
+    ran = {s["hlo_op"] for s in (dict(e.stats) for e in _events(pd))
+           if s.get("hlo_module") == "jit_decode_dispatch" and "hlo_op" in s}
+    from repro.analysis.hlo import HloModule
+    instrs = {i.name for c in HloModule(texts["jit_decode_dispatch"])
+              .computations.values() for i in c}
+    assert ran and ran <= instrs
+    assert any("qlinear" in p.split("/") for p in
+               op_scopes(texts["jit_decode_dispatch"]).values())
+    assert eng.serve(prompts, gen_tokens=6) == outs
 
 
 def _pair():
