@@ -956,6 +956,11 @@ class Engine:
             state = init_slot_state(B, prompt_cap=pcap)
             if self.mesh is not None:
                 cache = self._place_cache(cache)
+            # eviction first runs once the index has filled the pool, a
+            # serve or more later: compile it now, with the cache, so no
+            # later serve stalls on a compile (every id -1: no release)
+            cache, state = self._evict(
+                cache, state, jnp.full((self._num_blocks,), -1, jnp.int32))
         stats["cache_bytes"] = sum(
             l.size * l.dtype.itemsize for l in jax.tree.leaves(cache))
         stats["prefix_hits"] = 0

@@ -40,9 +40,10 @@ exclusively.  When a decode write lands in a block with ``ref > 1`` (a
 partially-filled prompt block shared through the prefix cache),
 ``alloc_step`` pops a fresh block, rewires the slot's table entry to it,
 decrements the shared block, and reports the old block as ``cow_src`` so
-the per-layer write copies its rows before appending.  Prefill-chunk writes
-never CoW: a recomputed row whose target is shared is simply dropped (the
-cached row already holds the identical value).
+the step copies its rows, in every layer, before the layer scan appends
+(``models.lm.cow_copy_blocks``).  Prefill-chunk writes never CoW: a
+recomputed row whose target is shared is simply dropped (the cached row
+already holds the identical value).
 
 Everything here is shape-static jit-safe jnp; per-layer wiring lives in
 ``models/lm.py`` (``init_paged_cache`` / ``decode_step_paged`` /
@@ -111,8 +112,8 @@ def alloc_step(bstate: dict, lengths: jnp.ndarray, block_size: int,
     With ``cow=True`` a write position landing in a block with ``ref > 1``
     (shared through the prefix cache) also pops a fresh block: the table
     entry is rewired to the copy, the shared block's refcount drops by one,
-    and the old id is reported as ``cow_src`` so the layer write can copy
-    the block's rows before appending.  ``cow_src == wblk`` marks "no copy"
+    and the old id is reported as ``cow_src`` so the step can copy the
+    block's rows before appending.  ``cow_src == wblk`` marks "no copy"
     (the copy is then the identity).
 
     Returns ``(bstate, wblk [B], woff [B], cow_src [B])`` — the per-slot
@@ -386,13 +387,16 @@ def span_targets(bstate: dict, start: jnp.ndarray, valid: jnp.ndarray,
 # Gather
 # ---------------------------------------------------------------------------
 
-def gather_blocks(pool: jnp.ndarray, tbl: jnp.ndarray) -> jnp.ndarray:
-    """pool [NB+1, bs, Kv, hd], tbl [B, MB] -> [B, MB*bs, Kv, hd].
+def gather_blocks(pool: jnp.ndarray, tbl: jnp.ndarray,
+                  layer) -> jnp.ndarray:
+    """Layer ``layer`` of the stacked pool [n, NB+1, bs, Kv, hd] through
+    the block table ``tbl`` [B, MB] -> [B, MB*bs, Kv, hd].
 
-    Unallocated entries (-1) wrap to the trash block; callers mask by
-    length, so its garbage never reaches the softmax.
+    The layer index is folded into the one gather, so the layer's pool is
+    never sliced out.  Unallocated entries (-1) wrap to the trash block;
+    callers mask by length, so its garbage never reaches the softmax.
     """
     B, MB = tbl.shape
-    bs = pool.shape[1]
-    g = pool[tbl]                               # [B, MB, bs, Kv, hd]
-    return g.reshape(B, MB * bs, *pool.shape[2:])
+    bs = pool.shape[2]
+    g = pool[layer, tbl]                        # [B, MB, bs, Kv, hd]
+    return g.reshape(B, MB * bs, *pool.shape[3:])

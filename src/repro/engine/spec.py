@@ -313,8 +313,7 @@ def make_spec_dispatch(model: Model, sp: SamplingParams, k_steps: int,
                     cap, ring, cow=cow)
                 cache = {**cache, **bstate}
                 if cow:
-                    cache = cow_copy_blocks(cache, cow_src, cow_dst,
-                                            jnp.any(cow_src != cow_dst))
+                    cache = cow_copy_blocks(cache, cow_src, cow_dst)
                     ctr = bump(ctr, cow_copies=jnp.sum(cow_src != cow_dst))
             # a slot whose shared block could not be CoWed sits the round
             # out entirely (no draft writes, no verify, no emission) and

@@ -168,29 +168,23 @@ def decode_attention(q, k_cache, v_cache, lengths, *, window: int = 0,
     return out.reshape(B, 1, H, hd).astype(q.dtype)
 
 
-def write_paged_kv(pk, pv, k_new, v_new, wblk, woff):
-    """Scatter one new kv [B,1,Kv,hd] into the block pools at each slot's
-    write target (block id ``wblk[b]``, in-block offset ``woff[b]`` —
-    computed once per step by ``engine.paged.alloc_step``; inactive slots
-    point at the trash block)."""
-    pk = pk.at[wblk, woff].set(k_new[:, 0].astype(pk.dtype))
-    pv = pv.at[wblk, woff].set(v_new[:, 0].astype(pv.dtype))
-    return pk, pv
-
-
-def write_paged_kv_span(pk, pv, k_new, v_new, wblk, woff):
-    """Scatter a prefill chunk's kv rows [B,C,Kv,hd] into the block pools
-    at per-row targets (``wblk``/``woff`` [B,C] from
-    ``engine.paged.span_targets``; pad rows, capacity overflows and
-    shared-block rows point at the trash block)."""
-    pk = pk.at[wblk, woff].set(k_new.astype(pk.dtype))
-    pv = pv.at[wblk, woff].set(v_new.astype(pv.dtype))
+def write_paged_kv(pk, pv, k_new, v_new, layer, wblk, woff):
+    """Scatter new kv rows into layer ``layer`` of the stacked block pools
+    ``[n, NB+1, bs, Kv, hd]``, in place: one decode row per slot (``k_new``
+    [B,Kv,hd], targets ``wblk``/``woff`` [B] from
+    ``engine.paged.alloc_step``) or a chunk's rows (``k_new`` [B,C,Kv,hd],
+    targets [B,C] from ``engine.paged.span_targets``).  Inactive slots, pad
+    rows, capacity overflows and shared-block rows point at the trash
+    block."""
+    pk = pk.at[layer, wblk, woff].set(k_new.astype(pk.dtype))
+    pv = pv.at[layer, wblk, woff].set(v_new.astype(pv.dtype))
     return pk, pv
 
 
 @jax.named_scope("attention")
-def paged_prefill_attention(q, pk, pv, k_new, v_new, tbl, start, valid, *,
-                            sliding_window=0, softcap=0.0) -> jnp.ndarray:
+def paged_prefill_attention(q, pk, pv, layer, k_new, v_new, tbl, start,
+                            valid, *, sliding_window=0,
+                            softcap=0.0) -> jnp.ndarray:
     """Prefill-chunk attention against the paged cache.
 
     q/k_new/v_new [B,C,{H|Kv},hd] are this chunk's rows at global positions
@@ -208,7 +202,7 @@ def paged_prefill_attention(q, pk, pv, k_new, v_new, tbl, start, valid, *,
     """
     from repro.engine.paged import gather_blocks
     B, C = k_new.shape[:2]
-    bs = pk.shape[1]
+    bs = pk.shape[2]
     MB = tbl.shape[1]
     cap = MB * bs
     ring = bool(sliding_window) and cap == sliding_window
@@ -219,11 +213,13 @@ def paged_prefill_attention(q, pk, pv, k_new, v_new, tbl, start, valid, *,
         pos = base[:, None] + jnp.arange(Wb)[None]          # [B, Wb]
         blk = jnp.take_along_axis(tbl, (pos % W) // bs, axis=1)
         off = (pos % W) % bs
-        gk, gv = pk[blk, off], pv[blk, off]                 # [B,Wb,Kv,hd]
+        gk = pk[layer, blk, off]                            # [B,Wb,Kv,hd]
+        gv = pv[layer, blk, off]
         kv_off = base
     else:
         pos = jnp.broadcast_to(jnp.arange(cap)[None], (B, cap))
-        gk, gv = gather_blocks(pk, tbl), gather_blocks(pv, tbl)
+        gk = gather_blocks(pk, tbl, layer)
+        gv = gather_blocks(pv, tbl, layer)
         kv_off = jnp.zeros((B,), jnp.int32)
     rel = pos - start[:, None]                              # [B, Wb|cap]
     fresh = (rel >= 0) & (rel < C)
@@ -240,8 +236,9 @@ def paged_prefill_attention(q, pk, pv, k_new, v_new, tbl, start, valid, *,
 
 
 @jax.named_scope("attention")
-def paged_verify_attention(q, pk, pv, k_new, v_new, tbl, start, valid, *,
-                           sliding_window=0, softcap=0.0) -> jnp.ndarray:
+def paged_verify_attention(q, pk, pv, layer, k_new, v_new, tbl, start,
+                           valid, *, sliding_window=0,
+                           softcap=0.0) -> jnp.ndarray:
     """Multi-token *verify* attention against the paged cache: row ``j`` of
     the chunk reproduces :func:`decode_attention` at position ``start + j``
     **operation for operation**.
@@ -261,7 +258,7 @@ def paged_verify_attention(q, pk, pv, k_new, v_new, tbl, start, valid, *,
     """
     from repro.engine.paged import gather_blocks
     B, C = k_new.shape[:2]
-    bs = pk.shape[1]
+    bs = pk.shape[2]
     MB = tbl.shape[1]
     cap = MB * bs
     H, hd = q.shape[2], q.shape[3]
@@ -276,7 +273,8 @@ def paged_verify_attention(q, pk, pv, k_new, v_new, tbl, start, valid, *,
         W = sliding_window
         slots = jnp.arange(W)[None, :]                      # [1, W]
         blk = tbl[:, (slots // bs)[0]]                      # [B, W]
-        gk, gv = pk[blk, (slots % bs)[0]], pv[blk, (slots % bs)[0]]
+        off = (slots % bs)[0]
+        gk, gv = pk[layer, blk, off], pv[layer, blk, off]
         i_s = (slots - start[:, None]) % W                  # writing row
         fresh = (i_s[:, None, :] <= rows) & (i_s[:, None, :] < C)
         idx = jnp.clip(i_s, 0, C - 1)
@@ -303,7 +301,8 @@ def paged_verify_attention(q, pk, pv, k_new, v_new, tbl, start, valid, *,
     # overlaid fresh, and the per-row length mask hides rows past it (the
     # exact mask decode_attention applies)
     pos = jnp.broadcast_to(jnp.arange(cap)[None], (B, cap))
-    gk, gv = gather_blocks(pk, tbl), gather_blocks(pv, tbl)
+    gk = gather_blocks(pk, tbl, layer)
+    gv = gather_blocks(pv, tbl, layer)
     rel = pos - start[:, None]
     fresh = (rel >= 0) & (rel < C)
     idx = jnp.clip(rel, 0, C - 1)[..., None, None]
@@ -326,18 +325,18 @@ def paged_verify_attention(q, pk, pv, k_new, v_new, tbl, start, valid, *,
 
 
 @jax.named_scope("attention")
-def paged_decode_attention(q, pk, pv, tbl, lengths, *, sliding_window=0,
-                           softcap=0.0) -> jnp.ndarray:
-    """Decode attention against paged K/V pools.
+def paged_decode_attention(q, pk, pv, layer, tbl, lengths, *,
+                           sliding_window=0, softcap=0.0) -> jnp.ndarray:
+    """Decode attention against layer ``layer`` of the stacked K/V pools.
 
-    q [B,1,H,hd]; pools [NB+1, bs, Kv, hd]; ``tbl`` [B, MB] block table.
+    q [B,1,H,hd]; pools [n, NB+1, bs, Kv, hd]; ``tbl`` [B, MB] block table.
     The gather reproduces the dense cache layout (linear positions, or ring
     positions when the table spans exactly the sliding window) so this is
     value-identical to :func:`decode_attention` on a contiguous cache.
     """
     from repro.engine.paged import gather_blocks
-    gk = gather_blocks(pk, tbl)
-    gv = gather_blocks(pv, tbl)
+    gk = gather_blocks(pk, tbl, layer)
+    gv = gather_blocks(pv, tbl, layer)
     cap = gk.shape[1]
     if sliding_window and cap == sliding_window:   # ring layout
         eff_len = jnp.minimum(lengths + 1, cap)

@@ -141,6 +141,11 @@ def apply_layer_train(p: dict, x, cfg: ModelConfig, spec: LayerSpec, *,
     return x, aux
 
 
+# leaves of an attention layer's paged cache that are block pools
+# ``[n_periods, num_blocks + 1, bs, Kv, hd]``; every other leaf is per slot
+POOL_KEYS = ("pk", "pv")
+
+
 def _kv_eff(cfg: ModelConfig) -> int:
     """Effective KV heads in decode caches (GQA repeat-sharding — see
     runtime.kv_repeat_factor): Kv*r so the cache head axis shards over
@@ -238,40 +243,43 @@ def _attn_decode(p, x, cache, lengths, cfg: ModelConfig):
     return y, new_cache
 
 
-def cow_copy_blocks(pcache: dict, src, dst, any_flag):
-    """Round-level copy-on-write for the paged pools: copy block ``src[b]``'s
-    rows into block ``dst[b]`` in every attention layer's ``pk``/``pv``
-    pool (``stack`` and ``prefix`` groups; pool leaves are
-    ``[n_periods, num_blocks + 1, bs, Kv, hd]``).
+def cow_copy_blocks(pcache: dict, src, dst):
+    """Copy-on-write for the paged pools: copy block ``src[b]`` into block
+    ``dst[b]`` in every attention layer's ``pk``/``pv`` pool (``stack`` and
+    ``prefix`` groups; pool leaves are ``[n_periods, num_blocks + 1, bs,
+    Kv, hd]``), all layers of a pool at once.
 
-    This materializes the private copy ``alloc_span(..., cow=True)``
-    rewired a slot's shared first span block to (engine/spec.py): the copy
-    must land *before* any draft or verify write of the round touches the
-    block, which is why it happens once per round here rather than inside
-    the per-layer write (the decode path's in-layer ``cow_src`` copy in
-    :func:`_attn_decode_paged` is the single-step analogue).  Slots without
-    a CoW carry ``src == dst`` (both the trash index), so their scatter is
-    a trash-block no-op; the whole copy is gated on ``any_flag`` because
-    at most one round per partial prefix hit ever CoWs.
+    This materializes the private copy that ``alloc_step(..., cow=True)``
+    (a decode step) or ``alloc_span(..., cow=True)`` (a speculative round,
+    engine/spec.py) rewired a slot's shared block to; it must land before
+    the step's or round's first write into the block, so callers run it
+    ahead of the layer scan.  Slots without a CoW carry ``src == dst`` (the
+    slot's own write block, or the trash block), whose copy would be the
+    identity, so only the copying slots are visited: a loop of in-place
+    block updates whose trip count is their number (none on most steps).
+    A ``lax.cond`` over the pools would copy them whole to pass them
+    through, and one scatter of every slot's block makes the TPU relayout
+    the pools around it.  The copying slots' ``src`` blocks are shared
+    (``ref > 1``) and their ``dst`` blocks fresh pops, so no copy reads
+    another's destination and the order is immaterial.
     """
-    def copy_group(group):
-        out = {}
-        for lk, lv in group.items():
-            nl = dict(lv)
-            for name in ("pk", "pv"):
-                if name in lv:
-                    nl[name] = lv[name].at[:, dst].set(lv[name][:, src])
-            out[lk] = nl
-        return out
+    cow = src != dst
+    order = jnp.argsort(~cow, stable=True)          # the copying slots first
+    src, dst = src[order], dst[order]
 
-    def do(c):
-        new = dict(c)
-        for grp in ("stack", "prefix"):
-            if grp in c:
-                new[grp] = copy_group(c[grp])
-        return new
+    def copy_one(i, groups):
+        def copy(leaf):
+            blk = jax.lax.dynamic_slice_in_dim(leaf, src[i], 1, axis=1)
+            return jax.lax.dynamic_update_slice_in_dim(leaf, blk, dst[i],
+                                                       axis=1)
+        return {g: {lk: {n: copy(l) if n in POOL_KEYS else l
+                         for n, l in lv.items()}
+                    for lk, lv in grp.items()}
+                for g, grp in groups.items()}
 
-    return jax.lax.cond(any_flag, do, lambda c: dict(c), pcache)
+    groups = {g: pcache[g] for g in ("stack", "prefix") if g in pcache}
+    return {**pcache,
+            **jax.lax.fori_loop(0, jnp.sum(cow), copy_one, groups)}
 
 
 def _attn_decode_paged(p, x, cache, pctx, cfg: ModelConfig):
@@ -281,14 +289,11 @@ def _attn_decode_paged(p, x, cache, pctx, cfg: ModelConfig):
     at the slot's write target (``pctx["wblk"]/["woff"]``, precomputed once
     per step — trash block for inactive slots), and attention runs over the
     block-table gather, which reproduces the dense cache layout (linear
-    positions, or ring positions for SWA) value-for-value.
-
-    When ``pctx["cow_src"]`` is present (refcounted prefix caching), the
-    write block is first overwritten with the rows of its copy-on-write
-    source — the identity when ``cow_src == wblk`` (no sharing), a real
-    block copy when ``alloc_step`` rewired the slot off a shared block."""
+    positions, or ring positions for SWA) value-for-value.  ``cache["pk"]``
+    / ``["pv"]`` are the whole stacked pools; the layer's rows are
+    addressed at ``pctx["layer"]`` (:func:`run_stack_paged`)."""
     B = x.shape[0]
-    lengths = pctx["lengths"]
+    lengths, layer = pctx["lengths"], pctx["layer"]
     q, k, v = A.qkv_proj(p, x, cfg)
     if cfg.rope_theta > 0:
         cos, sin = A.rope_cos_sin(lengths[:, None], cfg.resolved_head_dim,
@@ -299,19 +304,9 @@ def _attn_decode_paged(p, x, cache, pctx, cfg: ModelConfig):
     if r > 1:  # repeat-sharded cache (see _kv_eff)
         k = jnp.repeat(k, r, axis=2)
         v = jnp.repeat(v, r, axis=2)
-    pk, pv = cache["pk"], cache["pv"]
-    if "cow_src" in pctx:
-        # at most one slot CoWs per step and most steps none at all, so
-        # the block copy (a whole-block gather+scatter per layer) is
-        # gated on the step-wide predicate; skipping the identity copy
-        # (cow_src == wblk) is a bitwise no-op
-        def _copy(pools):
-            a, b = pools
-            return (a.at[pctx["wblk"]].set(a[pctx["cow_src"]]),
-                    b.at[pctx["wblk"]].set(b[pctx["cow_src"]]))
-        pk, pv = jax.lax.cond(pctx["cow_any"], _copy, lambda p: p, (pk, pv))
-    pk, pv = A.write_paged_kv(pk, pv, k, v, pctx["wblk"], pctx["woff"])
-    out = A.paged_decode_attention(q, pk, pv, pctx["tbl"], lengths,
+    pk, pv = A.write_paged_kv(cache["pk"], cache["pv"], k[:, 0], v[:, 0],
+                              layer, pctx["wblk"], pctx["woff"])
+    out = A.paged_decode_attention(q, pk, pv, layer, pctx["tbl"], lengths,
                                    sliding_window=cfg.sliding_window,
                                    softcap=cfg.attn_logit_softcap)
     from repro.quant_runtime import qlinear
@@ -335,13 +330,13 @@ def _attn_prefill_paged(p, x, cache, pctx, cfg: ModelConfig):
     if r > 1:  # repeat-sharded cache (see _kv_eff)
         k = jnp.repeat(k, r, axis=2)
         v = jnp.repeat(v, r, axis=2)
-    out = A.paged_prefill_attention(q, cache["pk"], cache["pv"], k, v,
-                                    pctx["tbl"], pctx["start"],
-                                    pctx["valid"],
+    out = A.paged_prefill_attention(q, cache["pk"], cache["pv"],
+                                    pctx["layer"], k, v, pctx["tbl"],
+                                    pctx["start"], pctx["valid"],
                                     sliding_window=cfg.sliding_window,
                                     softcap=cfg.attn_logit_softcap)
-    pk, pv = A.write_paged_kv_span(cache["pk"], cache["pv"], k, v,
-                                   pctx["wblk"], pctx["woff"])
+    pk, pv = A.write_paged_kv(cache["pk"], cache["pv"], k, v, pctx["layer"],
+                              pctx["wblk"], pctx["woff"])
     from repro.quant_runtime import qlinear
     y = qlinear.matmul(out.reshape(B, C, -1), p["wo"])
     return y, {**cache, "pk": pk, "pv": pv}
@@ -392,12 +387,13 @@ def _attn_verify_paged(p, x, cache, pctx, cfg: ModelConfig):
     if r > 1:  # repeat-sharded cache (see _kv_eff)
         k = jnp.repeat(k, r, axis=2)
         v = jnp.repeat(v, r, axis=2)
-    out = A.paged_verify_attention(q, cache["pk"], cache["pv"], k, v,
-                                   pctx["tbl"], pctx["start"], pctx["valid"],
+    out = A.paged_verify_attention(q, cache["pk"], cache["pv"],
+                                   pctx["layer"], k, v, pctx["tbl"],
+                                   pctx["start"], pctx["valid"],
                                    sliding_window=cfg.sliding_window,
                                    softcap=cfg.attn_logit_softcap)
-    pk, pv = A.write_paged_kv_span(cache["pk"], cache["pv"], k, v,
-                                   pctx["wblk"], pctx["woff"])
+    pk, pv = A.write_paged_kv(cache["pk"], cache["pv"], k, v, pctx["layer"],
+                              pctx["wblk"], pctx["woff"])
     from repro.quant_runtime import qlinear
     y = qlinear.matmul(out.reshape(B, C, -1), p["wo"])
     return y, {**cache, "pk": pk, "pv": pv}
@@ -615,51 +611,41 @@ def run_stack_decode(stack, cache, x, lengths, cfg, specs):
     return x, new_cache
 
 
-def run_stack_decode_paged(stack, cache, x, pctx, cfg, specs):
-    """Paged decode scan: the write targets / block table in ``pctx`` are
-    shared by every layer (all layers advance in lockstep), so they ride
-    the closure instead of the scanned xs."""
-    def body(h, xs):
-        lp, lc = xs
-        nc = {}
+def run_stack_paged(apply_layer, stack, cache, x, pctx, cfg, specs):
+    """Layer scan of a paged forward pass: ``apply_layer`` is
+    :func:`apply_layer_decode_paged`, :func:`apply_layer_prefill_paged` or
+    :func:`apply_layer_verify_paged`.
+
+    The stacked K/V pools (``pk``/``pv``, ``[n_periods, NB+1, bs, Kv, hd]``)
+    ride the scan's carry whole and the layer index is scanned: each layer
+    scatters its rows into ``pool[layer]`` in place and attention gathers
+    ``pool[layer][tbl]`` with the index folded into the gather, so no
+    layer's pool is sliced out, copied or written back, and the donated
+    pools alias through the dispatch.  Per-slot (SSM) state is scanned as
+    before.  The write targets / block table in ``pctx`` are shared by
+    every layer (all layers advance in lockstep) and ride the closure."""
+    pools = {lk: {n: v for n, v in lc.items() if n in POOL_KEYS}
+             for lk, lc in cache.items()}
+    state = {lk: {n: v for n, v in lc.items() if n not in POOL_KEYS}
+             for lk, lc in cache.items()}
+    n = jax.tree.leaves(stack)[0].shape[0]
+
+    def body(carry, xs):
+        h, pools = carry
+        lp, ls, layer = xs
+        lctx = {**pctx, "layer": layer}
+        new_pools, new_state = {}, {}
         for i, spec in enumerate(specs):
-            h, nci = apply_layer_decode_paged(lp[f"L{i}"], h, lc[f"L{i}"],
-                                              pctx, cfg, spec)
-            nc[f"L{i}"] = nci
-        return h, nc
-    x, new_cache = jax.lax.scan(body, x, (stack, cache))
-    return x, new_cache
+            lk = f"L{i}"
+            h, nc = apply_layer(lp[lk], h, {**ls[lk], **pools[lk]}, lctx,
+                                cfg, spec)
+            new_pools[lk] = {k: nc[k] for k in pools[lk]}
+            new_state[lk] = {k: nc[k] for k in ls[lk]}
+        return (h, new_pools), new_state
 
-
-def run_stack_prefill_paged(stack, cache, x, pctx, cfg, specs):
-    """Prefill-chunk scan over the period stack (chunked prefill rides the
-    decode dispatch, so this mirrors :func:`run_stack_decode_paged`)."""
-    def body(h, xs):
-        lp, lc = xs
-        nc = {}
-        for i, spec in enumerate(specs):
-            h, nci = apply_layer_prefill_paged(lp[f"L{i}"], h, lc[f"L{i}"],
-                                               pctx, cfg, spec)
-            nc[f"L{i}"] = nci
-        return h, nc
-    x, new_cache = jax.lax.scan(body, x, (stack, cache))
-    return x, new_cache
-
-
-def run_stack_verify_paged(stack, cache, x, pctx, cfg, specs):
-    """Speculative-verify scan over the period stack (mirrors
-    :func:`run_stack_decode_paged`: write targets / table ride the
-    closure since all layers advance in lockstep)."""
-    def body(h, xs):
-        lp, lc = xs
-        nc = {}
-        for i, spec in enumerate(specs):
-            h, nci = apply_layer_verify_paged(lp[f"L{i}"], h, lc[f"L{i}"],
-                                              pctx, cfg, spec)
-            nc[f"L{i}"] = nci
-        return h, nc
-    x, new_cache = jax.lax.scan(body, x, (stack, cache))
-    return x, new_cache
+    (x, pools), state = jax.lax.scan(
+        body, (x, pools), (stack, state, jnp.arange(n, dtype=jnp.int32)))
+    return x, {lk: {**state[lk], **pools[lk]} for lk in cache}
 
 
 def run_stack_prefill(stack, x, cfg, specs, *, memory=None, cache_len=0):
@@ -845,12 +831,26 @@ def build_model(cfg: ModelConfig) -> Model:
                                              batch, num_blocks, block_size)
         return c
 
+    def _run_stacks_paged(apply_layer, params, new_cache, x, pctx):
+        """The prefix group (if any), then the period stack, through
+        :func:`run_stack_paged`; each group's cache is replaced in
+        ``new_cache``.  Returns the hidden state."""
+        if n_prefix:
+            x, new_cache["prefix"] = run_stack_paged(
+                apply_layer, params["prefix"], new_cache["prefix"], x, pctx,
+                cfg, prefix_specs)
+        x, new_cache["stack"] = run_stack_paged(
+            apply_layer, params["stack"], new_cache["stack"], x, pctx, cfg,
+            specs)
+        return x
+
     def decode_step_paged(params, tokens, pcache, cow: bool = False):
         """tokens [B, 1] -> (logits [B, V], new paged cache).  Block
         allocation and write targets are computed once per step and shared
         by every attention layer (the stack advances in lockstep).  With
         ``cow=True`` (refcounted prefix caching) a write landing in a
-        shared block pops a private copy first — see engine/paged.py."""
+        shared block pops a private copy, copied for every layer before the
+        layer scan (:func:`cow_copy_blocks`) — see engine/paged.py."""
         from repro.engine.paged import BSTATE_KEYS, alloc_step
         x = embed_tokens(params["embed"], tokens)
         lengths = pcache["lengths"]
@@ -865,18 +865,13 @@ def build_model(cfg: ModelConfig) -> Model:
                                                      cap, ring, cow=cow)
             pctx = {"lengths": lengths, "tbl": bstate["tbl"],
                     "wblk": wblk, "woff": woff}
-            if cow:
-                pctx["cow_src"] = cow_src
-                pctx["cow_any"] = jnp.any(cow_src != wblk)
             new_cache.update(bstate)
+            if cow:  # the private copies land before the step's writes
+                new_cache = cow_copy_blocks(new_cache, cow_src, wblk)
         else:  # pure-SSM stack: contiguous state, no pools to manage
             pctx = {"lengths": lengths}
-        if n_prefix:
-            x, new_cache["prefix"] = run_stack_decode_paged(
-                params["prefix"], pcache["prefix"], x, pctx, cfg,
-                prefix_specs)
-        x, new_cache["stack"] = run_stack_decode_paged(
-            params["stack"], pcache["stack"], x, pctx, cfg, specs)
+        x = _run_stacks_paged(apply_layer_decode_paged, params, new_cache, x,
+                              pctx)
         x = apply_norm(params["final_norm"], x, cfg.norm_eps)
         logits = lm_logits(params["embed"], x)[:, 0]
         new_cache["lengths"] = lengths + 1
@@ -909,12 +904,8 @@ def build_model(cfg: ModelConfig) -> Model:
             wblk, woff = span_targets(bstate, start, valid, C, bs, cap,
                                       ring, shared_until)
             pctx.update(tbl=bstate["tbl"], wblk=wblk, woff=woff)
-        if n_prefix:
-            x, new_cache["prefix"] = run_stack_prefill_paged(
-                params["prefix"], pcache["prefix"], x, pctx, cfg,
-                prefix_specs)
-        x, new_cache["stack"] = run_stack_prefill_paged(
-            params["stack"], pcache["stack"], x, pctx, cfg, specs)
+        x = _run_stacks_paged(apply_layer_prefill_paged, params, new_cache, x,
+                              pctx)
         x = apply_norm(params["final_norm"], x, cfg.norm_eps)
         idx = jnp.clip(valid - 1, 0, C - 1)
         xg = x[jnp.arange(B), idx][:, None]
@@ -951,12 +942,8 @@ def build_model(cfg: ModelConfig) -> Model:
             wblk, woff = span_targets(bstate, start, valid, C, bs, cap,
                                       ring)
             pctx.update(tbl=bstate["tbl"], wblk=wblk, woff=woff)
-        if n_prefix:
-            x, new_cache["prefix"] = run_stack_verify_paged(
-                params["prefix"], pcache["prefix"], x, pctx, cfg,
-                prefix_specs)
-        x, new_cache["stack"] = run_stack_verify_paged(
-            params["stack"], pcache["stack"], x, pctx, cfg, specs)
+        x = _run_stacks_paged(apply_layer_verify_paged, params, new_cache, x,
+                              pctx)
         x = apply_norm(params["final_norm"], x, cfg.norm_eps)
         logits = lm_logits(params["embed"], x)
         new_cache["lengths"] = jnp.where(valid > 0, start + valid,

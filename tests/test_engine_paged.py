@@ -320,12 +320,15 @@ def test_alloc_admit_counts_and_trash_padding():
     assert set(tbl[1][tbl[1] >= 0].tolist()) == set(w[0, :2].tolist())
 
 
-def test_gather_blocks_reproduces_linear_layout():
+@pytest.mark.parametrize("layer", [0, 2])
+def test_gather_blocks_reproduces_linear_layout(layer):
+    """Blocks of the stacked pool's ``layer`` come out in table order."""
     NB, bs, Kv, hd = 5, 4, 2, 3
-    pool = jnp.arange((NB + 1) * bs * Kv * hd, dtype=jnp.float32).reshape(
-        NB + 1, bs, Kv, hd)
+    stack = jnp.arange(3 * (NB + 1) * bs * Kv * hd,
+                       dtype=jnp.float32).reshape(3, NB + 1, bs, Kv, hd)
+    pool = stack[layer]
     tbl = jnp.asarray([[2, 0, NEG], [4, NEG, NEG]], jnp.int32)
-    g = gather_blocks(pool, tbl)
+    g = gather_blocks(stack, tbl, jnp.int32(layer))
     assert g.shape == (2, 3 * bs, Kv, hd)
     np.testing.assert_array_equal(np.asarray(g[0, :bs]), np.asarray(pool[2]))
     np.testing.assert_array_equal(np.asarray(g[0, bs:2 * bs]),

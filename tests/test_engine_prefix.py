@@ -138,6 +138,67 @@ def test_alloc_step_cow_pops_private_copy():
     assert int(cow3[1]) == int(wblk3[1])
 
 
+def _same_bits(a, b):
+    a, b = np.asarray(a), np.asarray(b)
+    return a.shape == b.shape and a.dtype == b.dtype and (
+        a.tobytes() == b.tobytes())
+
+
+@pytest.mark.parametrize("shared", [False, True],
+                         ids=["no_cow", "one_slot_cows"])
+def test_decode_step_cow_copies_block_then_writes_row(shared):
+    """One ``decode_step_paged(cow=True)`` step equals, bit for bit, the
+    copy-on-write made explicit: copy every slot's write block from its
+    ``cow_src`` in every layer (the identity where they are equal, trash
+    onto trash for the idle slots), then run the step with nothing left to
+    copy.  Slot 0 writes row 6, inside its second block: a partial prompt
+    block that the prefix index also holds when ``shared``."""
+    from repro.engine.paged import BSTATE_KEYS
+    cfg, model, params, spec = _setup()
+    B, bs, NB = 4, 4, 12
+    pc = model.init_paged_cache(B, 16, block_size=bs, num_blocks=NB)
+    MB = pc["tbl"].shape[1]
+    leaves, tdef = jax.tree.flatten(pc["stack"])
+    pc["stack"] = jax.tree.unflatten(tdef, [
+        jax.random.normal(k, l.shape).astype(l.dtype)
+        for k, l in zip(jax.random.split(KEY, len(leaves)), leaves)])
+    bst = {k: pc[k] for k in BSTATE_KEYS}
+    none = jnp.full((MB,), NEG, jnp.int32)
+    bst, _ = admit_slot(bst, jnp.int32(0), none, jnp.int32(0), jnp.int32(2),
+                        jnp.int32(2 if shared else 0), MB)
+    bst, _ = admit_slot(bst, jnp.int32(1), none, jnp.int32(0), jnp.int32(2),
+                        jnp.int32(0), MB)
+    bst["slot_active"] = jnp.asarray([True, True, False, False])
+    pc = {**pc, **bst, "lengths": jnp.asarray([6, 5, 0, 0], jnp.int32)}
+    tokens = jnp.asarray([[3], [5], [7], [9]], jnp.int32)
+    step = jax.jit(model.decode_step_paged, static_argnames="cow")
+    logits, got = step(params, tokens, pc, cow=True)
+
+    b2, wblk, woff, cow_src = alloc_step(bst, pc["lengths"], bs, MB * bs,
+                                         False, cow=True)
+    assert int(jnp.sum(cow_src != wblk)) == int(shared)
+    assert int(jnp.sum(wblk == NB)) == 2          # two slots onto trash
+    copied = {lk: {n: (l.at[:, wblk].set(l[:, cow_src])
+                       if n in ("pk", "pv") else l)
+                   for n, l in lv.items()}
+              for lk, lv in pc["stack"].items()}
+    want_logits, want = step(params, tokens,
+                             {**pc, **b2, "stack": copied}, cow=False)
+    assert _same_bits(logits, want_logits)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert _same_bits(g, w)
+    if shared:   # the private copy holds the shared block's other rows
+        src, dst, off = int(cow_src[0]), int(wblk[0]), int(woff[0])
+        keep = np.arange(bs) != off
+        for name in ("pk", "pv"):
+            pool = np.asarray(got["stack"]["L0"][name], np.float32)
+            old = np.asarray(pc["stack"]["L0"][name], np.float32)
+            np.testing.assert_array_equal(pool[:, dst][:, keep],
+                                          old[:, src][:, keep])
+            np.testing.assert_array_equal(pool[:, src], old[:, src])
+
+
 def test_span_targets_drop_shared_watermark():
     """Prefill-chunk writes below the prefix-hit watermark are dropped
     (the cached rows already hold the identical KV): no slot ever writes a
@@ -237,6 +298,26 @@ def test_warm_partial_hit_on_saturated_pool_degrades_not_crashes():
     outs, stats = eng.serve([short], gen_tokens=8, return_stats=True)
     assert outs == contig_s
     assert stats["prefix_evictions"] > 0
+
+
+def test_serve_after_the_first_compiles_nothing_when_it_evicts():
+    """The serve that builds the prefix-cached loop's cache compiles every
+    entry the loop runs, eviction included: a later serve whose admissions
+    evict cached blocks compiles nothing (a compile mid-serve stalls every
+    slot)."""
+    cfg, model, params, spec = _setup()
+    eng = Engine(model, params, slots=1, cache_len=32, k_steps=2,
+                 paged=True, block_size=8, num_blocks=5, prefix_cache=True,
+                 chunk_size=8)
+    _, first = eng.serve([_tokens(spec, 22, seed=11)], gen_tokens=4,
+                         return_stats=True)
+    assert first["prefix_evictions"] == 0
+    before = eng.compile_counts()
+    _, stats = eng.serve([_tokens(spec, 22, seed=12),
+                          _tokens(spec, 22, seed=13)], gen_tokens=4,
+                         return_stats=True)
+    assert stats["prefix_evictions"] > 0
+    assert eng.compile_counts() == before
 
 
 # ---------------------------------------------------------------------------

@@ -8,6 +8,8 @@ chip, so such faults show up here instead of on the chip.
 The topology is described inside a module-scoped fixture only: describing it
 loads the TPU library, which one process at a time may hold.
 """
+import math
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -102,3 +104,74 @@ def test_fp8_round_trip_survives_v5e_fusion(one_chip):
     assert converts, "no fp8 rounding left in the compiled search"
     inner = [c for c in converts if not c.lstrip().startswith("ROOT")]
     assert not inner, inner[:2]
+
+
+def _paged_dispatch_compiled(sharding, entry, n_layers):
+    """``entry`` of the engine that serves glm4-9b from fp8 (channel
+    scales) through the paged, prefix-cached, chunked path: 64 slots,
+    cache_len 768, 3072 blocks of 16, chunk 64, 8 steps a dispatch.
+    Returns (compiled, per-layer pool shape)."""
+    import dataclasses
+    from repro.configs import get_arch
+    from repro.core.policy import path_str, should_quantize
+    from repro.engine import Engine
+    from repro.engine.scheduler import init_slot_state
+    from repro.models import build_model
+    from repro.quant_runtime.qparams import QuantizedTensor
+
+    model = build_model(dataclasses.replace(get_arch("glm4-9b"),
+                                            n_layers=n_layers))
+
+    def sds(x, dtype=None):
+        return jax.ShapeDtypeStruct(x.shape, dtype or x.dtype,
+                                    sharding=sharding)
+
+    def fp8(path, leaf):
+        if not should_quantize(path_str(path), leaf, ("norm", "bias")):
+            return sds(leaf)
+        scale = jax.ShapeDtypeStruct(leaf.shape[:-2] + (1, leaf.shape[-1]),
+                                     jnp.float32, sharding=sharding)
+        return QuantizedTensor(data=sds(leaf, jnp.float8_e4m3fn),
+                               scale=scale, fmt="fp8_e4m3",
+                               granularity="channel", block_size=128,
+                               out_dtype="bfloat16")
+
+    params = jax.tree_util.tree_map_with_path(
+        fp8, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    eng = Engine(model, params, slots=64, cache_len=768, num_blocks=3072,
+                 paged=True, prefix_cache=True, chunk_size=64, block_size=16,
+                 k_steps=8)
+    cache = jax.tree.map(sds, jax.eval_shape(
+        lambda: model.init_paged_cache(64, 768, block_size=16,
+                                       num_blocks=3072)))
+    state = jax.tree.map(sds, jax.eval_shape(
+        lambda: init_slot_state(64, prompt_cap=768)))
+    key = sds(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    fn = eng.entry_points()[entry]["fn"]
+    pool = cache["stack"]["L0"]["pk"].shape[1:]
+    return fn.lower(params, state, cache, key).compile(), pool
+
+
+@pytest.mark.parametrize("entry", ["_dispatch", "_dispatch_chunk"])
+def test_paged_dispatch_updates_pools_in_place(one_chip, entry):
+    """The decode and chunk dispatches update the stacked K/V pools in
+    place through the layer scan: no instruction copies a buffer of a
+    layer's pool or of the stacked pools, or passes one through a
+    conditional, and the temporaries do not grow by a layer's pools per
+    layer.  A per-layer slice of the pools out of the scan's inputs, a
+    ``lax.cond`` over them or a relayout between the row scatter and the
+    gather each shows here as such a copy."""
+    import re
+    compiled = {n: _paged_dispatch_compiled(one_chip, entry, n)
+                for n in (2, 4)}
+    (c2, pool), (c4, _) = compiled[2], compiled[4]
+    dims = ",".join(map(str, pool))
+    shape = re.compile(rf"bf16\[(\d+,)?{dims}\]")
+    op = re.compile(r"\s(copy|copy-start|conditional)\(")
+    bad = [ln.strip()[:160] for ln in c2.as_text().splitlines()
+           if shape.search(ln) and op.search(ln)]
+    assert not bad, bad
+    layer_kv = 2 * math.prod(pool) * 2               # K and V, bf16
+    grow = (c4.memory_analysis().temp_size_in_bytes
+            - c2.memory_analysis().temp_size_in_bytes)
+    assert grow < layer_kv, (grow, layer_kv)
