@@ -45,9 +45,12 @@ class ModelConfig:
     n_experts: int = 0
     top_k: int = 0
     n_shared_experts: int = 0        # DeepSeek-V3 style shared expert(s)
+    d_ff_shared: int = 0             # shared width (0 -> d_ff * n_shared)
+    n_experts_held: int = 0          # experts this chip holds (0 -> all):
+    expert_first: int = 0            # experts [first, first + held) of the
+                                     # router's n_experts (expert parallelism)
     first_k_dense: int = 0           # first k layers use dense FFN
     d_ff_dense: int = 0              # dense FFN width when first_k_dense > 0
-    capacity_factor: float = 1.25
     router_aux_loss: float = 0.001
 
     # --- SSM (Mamba-2 / SSD) ---
@@ -67,6 +70,7 @@ class ModelConfig:
     sliding_window: int = 0          # >0 -> sliding-window attention (Mixtral)
     attn_logit_softcap: float = 0.0
     qkv_bias: bool = False
+    attn_scale: float = 0.0          # softmax scale (0 -> 1 / sqrt(head_dim))
 
     # --- encoder-decoder ---
     n_enc_layers: int = 0
@@ -83,6 +87,11 @@ class ModelConfig:
     tie_embeddings: bool = False
     dtype: str = "bfloat16"
     norm_eps: float = 1e-5
+    # muP multipliers (Granite): x0 = m_emb * E[tok]; each sublayer adds
+    # m_res * f(x); logits = head(x) / logits_scaling
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     # --- provenance ---
     source: str = ""                 # citation from the assignment table
@@ -103,18 +112,30 @@ class ModelConfig:
     def n_ssm_heads(self) -> int:
         return self.resolved_d_inner // self.ssm_head_dim
 
-    def param_count(self) -> int:
-        """Analytic total parameter count (embedding included)."""
+    @property
+    def resolved_experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
+
+    @property
+    def resolved_d_ff_shared(self) -> int:
+        return self.d_ff_shared or self.d_ff * self.n_shared_experts
+
+    def param_count(self, held: bool = False) -> int:
+        """Analytic total parameter count (embedding included): the whole
+        model, or with ``held`` only the experts this chip holds."""
         D, F, V = self.d_model, self.d_ff, self.vocab_size
         H, Kv, hd = self.n_heads, self.n_kv_heads, self.resolved_head_dim
         embed = V * D
         head = 0 if self.tie_embeddings else D * V
         attn = D * H * hd + 2 * D * Kv * hd + H * hd * D
+        E = self.resolved_experts_held if held else self.n_experts
+        moe_ffn = (E * 3 * D * F + D * self.n_experts
+                   + 3 * D * self.resolved_d_ff_shared)
 
         def dense_ffn(width: int) -> int:
             return 3 * D * width  # SwiGLU: gate + up + down
 
-        total = embed + head
+        total = embed + head + D  # final norm
         if self.family in ("dense", "vlm"):
             per_layer = attn + dense_ffn(F) + 2 * D
             total += self.n_layers * per_layer
@@ -122,10 +143,8 @@ class ModelConfig:
                 n_cross = self.n_layers // self.cross_attn_every
                 total += n_cross * (attn + 2 * D)  # cross-attn projections+norms
         elif self.family == "moe":
-            moe_ffn = self.n_experts * 3 * D * F + D * self.n_experts
-            shared = self.n_shared_experts * 3 * D * F
             n_moe = self.n_layers - self.first_k_dense
-            total += n_moe * (attn + moe_ffn + shared + 2 * D)
+            total += n_moe * (attn + moe_ffn + 2 * D)
             total += self.first_k_dense * (attn + dense_ffn(self.d_ff_dense or F) + 2 * D)
         elif self.family == "ssm":
             di, N = self.resolved_d_inner, self.ssm_state
@@ -137,15 +156,17 @@ class ModelConfig:
         elif self.family == "hybrid":
             di, N = self.resolved_d_inner, self.ssm_state
             nh = self.n_ssm_heads
-            mamba_l = (D * (2 * di + 2 * N + nh) + self.conv_kernel * (di + 2 * N)
-                       + 2 * nh + di + di * D + 2 * D)
-            moe_ffn = self.n_experts * 3 * D * F + D * self.n_experts
+            # in_z, in_x, in_bc, in_dt; conv weights and biases over
+            # (x, B, C); a_log, dt_bias, d_skip; gated norm; out_proj
+            mamba_l = (D * (2 * di + 2 * N + nh)
+                       + (self.conv_kernel + 1) * (di + 2 * N)
+                       + 3 * nh + di + di * D)
             for idx in range(self.n_layers):
                 is_attn = self.attn_every and (idx % self.attn_every == self.attn_every // 2)
-                total += attn + 2 * D if is_attn else mamba_l
+                total += attn if is_attn else mamba_l
                 is_moe = self.moe_every and (idx % self.moe_every == self.moe_offset)
                 total += moe_ffn if is_moe else dense_ffn(F)
-                total += D  # ffn norm
+                total += 2 * D  # mixer and ffn norms
         elif self.family == "encdec":
             enc = self.n_enc_layers * (attn + dense_ffn(F) + 2 * D)
             dec = self.n_dec_layers * (2 * attn + dense_ffn(F) + 3 * D)

@@ -16,6 +16,7 @@ from repro.configs.kimi_k2_1t_a32b import CONFIG as _kimi
 from repro.configs.seamless_m4t_medium import CONFIG as _seamless
 from repro.configs.llama_3_2_vision_90b import CONFIG as _llamav
 from repro.configs.deepseek_v3 import CONFIG as _dsv3
+from repro.configs.granite_4_0_h_small import CONFIG as _granite
 
 # The 10 assigned architectures (order matters: it is the report order).
 ASSIGNED: tuple[ModelConfig, ...] = (
@@ -23,8 +24,9 @@ ASSIGNED: tuple[ModelConfig, ...] = (
     _jamba, _mixtral, _kimi, _seamless, _llamav,
 )
 
-# Paper's own model, available but not part of the 40-cell table.
-EXTRA: tuple[ModelConfig, ...] = (_dsv3,)
+# Available but not part of the 40-cell table: the paper's own model, and
+# the benchmark's hybrid configuration.
+EXTRA: tuple[ModelConfig, ...] = (_dsv3, _granite)
 
 ARCHS: dict[str, ModelConfig] = {c.name: c for c in ASSIGNED + EXTRA}
 
@@ -67,6 +69,10 @@ def reduced(cfg: ModelConfig, *, vocab: int = 512) -> ModelConfig:
     if cfg.family in ("moe", "hybrid"):
         kw["n_experts"] = min(8, cfg.n_experts)
         kw["top_k"] = min(2, cfg.top_k)
+        if cfg.n_experts_held:  # keep a share: half of the experts here
+            kw["n_experts_held"] = kw["n_experts"] // 2
+        if cfg.d_ff_shared:
+            kw["d_ff_shared"] = 96
         if cfg.n_shared_experts:
             kw["n_shared_experts"] = 1
         if cfg.first_k_dense:

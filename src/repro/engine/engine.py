@@ -35,12 +35,9 @@ no extra syncs); everything per-token lives on device:
   (scheduler.py), so long prompts stream instead of stalling decode.
   Chunk pieces are bit-exact vs one-shot prefill (same flash tile math
   with offset masks, SSD state threaded on the ``ssm_chunk`` grid, same-
-  dtype cache reads) with one carve-out: capacity-routed MoE is run
-  **dropless** inside chunks — GShard's round-major queue positions are
-  non-causal (a token's 2nd-choice position depends on later tokens' 1st
-  choices), so one-shot *drop* decisions cannot be reproduced from a
-  chunk's worth of tokens; outputs match exactly whenever the one-shot
-  path doesn't overflow an expert queue.
+  dtype cache reads); MoE layers serve dropless on every path
+  (``moe.apply_moe_dropless``), so a token's expert output depends on its
+  own hidden state alone.
 * **prefix caching** — ``prefix_cache=True`` (implies chunked prefill)
   shares full prompt blocks across requests: a host-side chained-hash
   index (engine/prefix.py) maps matched leading blocks into the new slot's
@@ -77,9 +74,9 @@ no extra syncs); everything per-token lives on device:
 Right-padded prefill is only exact when a row's hidden states cannot depend
 on positions after it or on other tokens' presence: pure causal attention
 qualifies; SWA ring caches (slot = position % window would index pad
-positions), Mamba state accumulation, and capacity-routed MoE (pad tokens
-compete for per-expert capacity and can displace real tokens) do not —
-those configs fall back to equal-length grouping automatically.
+positions) and Mamba state accumulation do not, and MoE stacks are left
+out too (their dropless layer would allow it) — those configs fall back
+to equal-length grouping automatically.
 """
 from __future__ import annotations
 
@@ -95,7 +92,7 @@ from repro.engine import paged as P
 from repro.engine.prefix import PrefixIndex
 from repro.engine.sampler import SamplingParams, sample
 from repro.engine.scheduler import init_slot_state, make_decode_dispatch
-from repro.models.lm import Model
+from repro.models.lm import Model, has_moe
 from repro.telemetry.counters import (COUNTER_KEYS, bump, counter_totals,
                                       init_counters)
 from repro.telemetry.trace import clock_us, span
@@ -169,12 +166,11 @@ class Engine:
         self.cfg = cfg
         self.mesh = mesh
         # right-padded prefill is exact only for pure-causal-attention
-        # stacks with non-ring caches AND no cross-token coupling: MoE is
-        # excluded because pad tokens join capacity-limited routing and can
-        # displace real tokens' expert assignments (see module docstring)
+        # stacks with non-ring caches (see module docstring)
         mcfg = model.cfg
         self._can_pad = (mcfg.family == "dense"
                          and not mcfg.sliding_window)
+        self._moe = has_moe(mcfg)   # expert-layer device counters
         self.params = self._place_params(params) if mesh is not None else params
 
         sp, K = cfg.sampling, cfg.k_steps
@@ -203,19 +199,6 @@ class Engine:
                     f"n_spec + 1 ({cfg.n_spec + 1}) must fit inside the "
                     f"sliding window ({mcfg.sliding_window}): a round's "
                     f"verify span may not wrap the whole ring")
-            has_moe = (mcfg.family == "moe"
-                       or (mcfg.family == "hybrid" and mcfg.moe_every))
-            if has_moe and mcfg.capacity_factor * mcfg.top_k < mcfg.n_experts:
-                raise ValueError(
-                    f"speculative verify routes MoE dropless, but this "
-                    f"config's decode path can drop tokens "
-                    f"(capacity_factor {mcfg.capacity_factor} * top_k "
-                    f"{mcfg.top_k} < n_experts {mcfg.n_experts}), so greedy "
-                    f"speculative output could diverge from the "
-                    f"non-speculative engine when an expert queue "
-                    f"overflows.  Serve dropless (capacity_factor >= "
-                    f"n_experts / top_k) to speculate — what a serving "
-                    f"engine wants regardless")
         elif draft_params is not None:
             raise ValueError("draft_params without n_spec > 0 does nothing: "
                              "set n_spec to enable speculative decoding")
@@ -817,7 +800,7 @@ class Engine:
             cache = model.init_cache(B, cfg.cache_len)
         stats["cache_bytes"] = sum(
             l.size * l.dtype.itemsize for l in jax.tree.leaves(cache))
-        state = init_slot_state(B)
+        state = init_slot_state(B, moe=self._moe)
         if self.mesh is not None:
             cache = self._place_cache(cache)
         key = jax.random.PRNGKey(cfg.seed if seed is None else seed)
@@ -953,7 +936,7 @@ class Engine:
             cache = model.init_paged_cache(B, cfg.cache_len,
                                            block_size=bs,
                                            num_blocks=self._num_blocks)
-            state = init_slot_state(B, prompt_cap=pcap)
+            state = init_slot_state(B, prompt_cap=pcap, moe=self._moe)
             if self.mesh is not None:
                 cache = self._place_cache(cache)
             # eviction first runs once the index has filled the pool, a
@@ -968,7 +951,7 @@ class Engine:
         # zero the device counters for this serve() (host-side tree
         # rebuild — covers a reused persistent state).  Index-held blocks
         # survive across serves, so conservation baselines on them.
-        state = {**state, "ctr": init_counters()}
+        state = {**state, "ctr": init_counters(self._moe)}
         self._ctr_live0 = len(self._hold_blocks)
         tr = self.tracer
         t_enq = time.perf_counter()

@@ -39,19 +39,21 @@ from repro.models.lm import Model
 from repro.telemetry.counters import bump, init_counters
 
 
-def init_slot_state(n_slots: int, prompt_cap: int = 0) -> dict:
+def init_slot_state(n_slots: int, prompt_cap: int = 0,
+                    moe: bool = False) -> dict:
     """Zeroed device-side slot state for a fresh pool of ``n_slots``.
 
     ``prompt_cap > 0`` adds the chunked-prefill fields: a per-slot prompt
     buffer plus prefill cursor/length and the post-first-token decode
     budget (armed by the engine's admission).  ``ctr`` is the
     device-resident telemetry counter tree (repro.telemetry.counters):
-    bumped inside the scan, read for free at the existing dispatch sync."""
+    bumped inside the scan, read for free at the existing dispatch sync;
+    ``moe`` adds the expert-layer counters (models with MoE layers)."""
     st = {
         "cur": jnp.zeros((n_slots, 1), jnp.int32),      # last sampled token
         "active": jnp.zeros((n_slots,), bool),          # slot serving a req?
         "remaining": jnp.zeros((n_slots,), jnp.int32),  # decode budget left
-        "ctr": init_counters(),                         # device counters
+        "ctr": init_counters(moe),                      # device counters
     }
     if prompt_cap:
         st["prompt"] = jnp.zeros((n_slots, prompt_cap), jnp.int32)
@@ -64,28 +66,24 @@ def init_slot_state(n_slots: int, prompt_cap: int = 0) -> dict:
 
 @jax.named_scope("keep_rows")
 def _keep_rows(new_cache: dict, old_cache: dict, keep) -> dict:
-    """Merge two paged caches per slot: rows of slots in ``keep`` come from
-    ``new_cache``, others are restored from ``old_cache``.  Pool leaves
-    (``pk``/``pv``) and the global allocator state stay from ``new_cache``
-    (writes of non-kept slots were trash-routed); per-slot leaves (SSM
-    state, batch axis 1 under the period axis) and ``lengths`` select."""
-    def sel(name, n, o):
-        if name in ("pk", "pv"):
-            return n
-        m = keep.reshape((1, keep.shape[0]) + (1,) * (n.ndim - 2))
-        return jnp.where(m, n, o)
+    """Merge two paged caches per slot: ``lengths`` of slots in ``keep``
+    come from ``new_cache``, the others' from ``old_cache``.  Everything
+    else stays from ``new_cache``: writes of non-kept slots into the pools
+    were trash-routed, and per-slot (SSM) state is kept in the layer scan
+    itself (``keep`` of ``decode_step_paged``; a prefill chunk with
+    ``valid == 0`` leaves a slot's state exactly as it was), so no
+    state-sized select or copy is made here."""
+    return {**new_cache,
+            "lengths": jnp.where(keep, new_cache["lengths"],
+                                 old_cache["lengths"])}
 
-    merged = dict(new_cache)
-    for grp in ("stack", "prefix"):
-        if grp not in new_cache:
-            continue
-        merged[grp] = {
-            lk: {name: sel(name, lv[name], old_cache[grp][lk][name])
-                 for name in lv}
-            for lk, lv in new_cache[grp].items()}
-    merged["lengths"] = jnp.where(keep, new_cache["lengths"],
-                                  old_cache["lengths"])
-    return merged
+
+def _bump_moe(ctr: dict, cache: dict) -> dict:
+    """Add the last forward's MoE counts (models with MoE layers)."""
+    if "moe_counts" not in cache:
+        return ctr
+    c = cache["moe_counts"]
+    return bump(ctr, moe_assigned=c[0], moe_rows=c[1])
 
 
 @jax.named_scope("prefill_chunk")
@@ -115,6 +113,7 @@ def chunk_prefill_substep(model: Model, sp: SamplingParams, chunk: int,
     logits_pf, new_cache = model.prefill_chunk_paged(
         params, toks, cache, st["pf_pos"], valid, st["pf_shared"])
     cache = _keep_rows(new_cache, cache, prefilling)
+    st = {**st, "ctr": _bump_moe(st["ctr"], cache)}
     completed = prefilling & (pf_left <= chunk)
     first = sample(logits_pf, first_key, sp)
     go = completed & (st["budget"] > 0)
@@ -192,8 +191,9 @@ def make_decode_dispatch(model: Model, sp: SamplingParams, k_steps: int,
         if model.decode_step_paged is None:
             raise NotImplementedError(
                 f"model family {model.cfg.family!r} has no paged decode path")
-        def step_fn(params, toks, cache):
-            return model.decode_step_paged(params, toks, cache, cow=cow)
+        def step_fn(params, toks, cache, keep):
+            return model.decode_step_paged(params, toks, cache, cow=cow,
+                                           keep=keep)
     if chunk:
         if not paged or model.prefill_chunk_paged is None:
             raise NotImplementedError(
@@ -206,7 +206,12 @@ def make_decode_dispatch(model: Model, sp: SamplingParams, k_steps: int,
             # ---- decode sub-step (slots in decode phase) ----------------
             if paged:   # allocator deltas around the step count pops/CoW
                 nf0, ref0 = cache["n_free"], cache["ref"]
-            logits, new_cache = step_fn(params, st["cur"], cache)
+                # idle and prefilling slots keep their per-slot state
+                logits, new_cache = step_fn(params, st["cur"], cache,
+                                            st["active"])
+                ctr = _bump_moe(ctr, new_cache)
+            else:
+                logits, new_cache = step_fn(params, st["cur"], cache)
             if chunk:  # prefilling/idle slots' rows must stay untouched
                 new_cache = _keep_rows(new_cache, cache, st["active"])
             cache = new_cache

@@ -249,8 +249,9 @@ def cache_shardings(cache_shape: Any, cfg: ModelConfig, mesh) -> Any:
             dp = _dp(mesh, leaf.shape[0])
             return NamedSharding(mesh, P(dp))
         from repro.engine.paged import BSTATE_KEYS
-        if name in BSTATE_KEYS:
-            # paged-cache allocator state: tiny int/bool arrays, replicated
+        if name in BSTATE_KEYS or name == "moe_counts":
+            # paged-cache allocator state and the MoE counts: tiny int/bool
+            # arrays, replicated
             return NamedSharding(mesh, P(*([None] * leaf.ndim)))
         if name in ("pk", "pv"):              # [n, NB+1, bs, Kv_eff, hd]
             # block pool: same head-axis layout as the dense cache; the

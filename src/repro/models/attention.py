@@ -23,6 +23,9 @@ from repro.models.common import apply_rope, dense_init, rope_cos_sin, split
 from repro.quant_runtime import qlinear
 
 NEG_INF = -1e30
+# Largest float32 score tile [B, heads, q_chunk, kv_chunk] of a paged
+# prefill chunk: a wider batch of slots takes narrower kv tiles.
+TILE_BYTES = 640 << 20
 
 
 # ---------------------------------------------------------------------------
@@ -46,7 +49,11 @@ def init_attn(key, cfg: ModelConfig, dtype) -> dict:
 
 
 def qkv_proj(p: dict, x: jnp.ndarray, cfg: ModelConfig):
-    """x [B, S, D] -> q [B,S,H,hd], k/v [B,S,Kv,hd]."""
+    """x [B, S, D] -> q [B,S,H,hd], k/v [B,S,Kv,hd].
+
+    A softmax scale other than 1/sqrt(hd) (``cfg.attn_scale``) is folded
+    into q here, so that every attention path, which scales its scores by
+    1/sqrt(hd), applies it."""
     B, S, _ = x.shape
     H, Kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
     q = qlinear.matmul(x, p["wq"])
@@ -56,6 +63,9 @@ def qkv_proj(p: dict, x: jnp.ndarray, cfg: ModelConfig):
         q = q + p["bias_q"].astype(q.dtype)
         k = k + p["bias_k"].astype(k.dtype)
         v = v + p["bias_v"].astype(v.dtype)
+    if cfg.attn_scale:
+        q = (q.astype(jnp.float32) * (cfg.attn_scale * hd ** 0.5)
+             ).astype(q.dtype)
     return (q.reshape(B, S, H, hd), k.reshape(B, S, Kv, hd),
             v.reshape(B, S, Kv, hd))
 
@@ -230,9 +240,14 @@ def paged_prefill_attention(q, pk, pv, layer, k_new, v_new, tbl, start,
     gv = jnp.where(fm, jnp.take_along_axis(v_new, idx, axis=1),
                    gv.astype(v_new.dtype))
     n_valid = start + valid - kv_off                        # local kv count
+    from repro.runtime import flags
+    ck = flags["kv_chunk"]
+    while ck > 128 and B * q.shape[2] * C * ck * 4 > TILE_BYTES:
+        ck //= 2
     return chunked_attention(q, gk, gv, causal=True, window=sliding_window,
                              softcap=softcap, q_offsets=start,
-                             kv_offsets=kv_off, kv_lengths=n_valid)
+                             kv_offsets=kv_off, kv_lengths=n_valid,
+                             kv_chunk=ck)
 
 
 @jax.named_scope("attention")

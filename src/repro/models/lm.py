@@ -68,6 +68,31 @@ def layer_plan(cfg: ModelConfig):
     raise ValueError(f"unknown family {cfg.family!r}")
 
 
+def has_moe(cfg: ModelConfig) -> bool:
+    """Whether any layer of the model has an MoE FFN."""
+    prefix_specs, _, specs, _ = layer_plan(cfg)
+    return any(ffn == "moe" for _, ffn in prefix_specs + specs)
+
+
+def _scaled(y, m: float):
+    """``m * y`` in ``y``'s dtype: a muP multiplier (1 leaves ``y``)."""
+    if m == 1.0:
+        return y
+    return (y.astype(jnp.float32) * m).astype(y.dtype)
+
+
+def _embed(params, tokens, cfg: ModelConfig):
+    return _scaled(embed_tokens(params["embed"], tokens),
+                   cfg.embedding_multiplier)
+
+
+def _final(params, x, cfg: ModelConfig):
+    """Final norm, divided by ``logits_scaling``: the head's input, so that
+    every head (tied, untied, chunked loss) gives logits / logits_scaling."""
+    return _scaled(apply_norm(params["final_norm"], x, cfg.norm_eps),
+                   1.0 / cfg.logits_scaling)
+
+
 # ---------------------------------------------------------------------------
 # Single-layer init / apply
 # ---------------------------------------------------------------------------
@@ -115,7 +140,10 @@ def apply_layer_train(p: dict, x, cfg: ModelConfig, spec: LayerSpec, *,
     lower to reduce-scatter instead of all-reduce+slice (Megatron SP) —
     without this GSPMD all-reduces full [B,S,D] partials per sublayer
     (llama-vision train: 3.1 TB/chip of all-reduce observed)."""
-    from repro.runtime import residual_constraint as rc
+    from repro.runtime import residual_constraint
+
+    def rc(y):
+        return residual_constraint(_scaled(y, cfg.residual_multiplier))
     mixer, ffn = spec
     aux = jnp.float32(0.0)
     h = apply_norm(p["ln1"], x, cfg.norm_eps)
@@ -134,7 +162,9 @@ def apply_layer_train(p: dict, x, cfg: ModelConfig, spec: LayerSpec, *,
     if ffn != "none":
         h2 = apply_norm(p["ln2"], x, cfg.norm_eps)
         if ffn == "moe":
-            y, aux = MOE.apply_moe(p["moe"], h2, cfg)
+            y, _ = MOE.apply_moe_dropless(p["moe"], h2, cfg)
+            aux = cfg.router_aux_loss * MOE.load_balance_loss(
+                p["moe"]["router"], h2)
             x = x + rc(y)
         else:
             x = x + rc(apply_mlp(p["mlp"], h2))
@@ -342,33 +372,56 @@ def _attn_prefill_paged(p, x, cache, pctx, cfg: ModelConfig):
     return y, {**cache, "pk": pk, "pv": pv}
 
 
+def _ffn_serve(p: dict, x, cfg: ModelConfig, ffn: str, valid=None):
+    """The FFN half of a serving layer: (x, MoE counts or None).  MoE runs
+    dropless over the held experts (moe.apply_moe_dropless), so a token's
+    output never depends on the other rows of the batch; ``valid`` marks
+    the rows that are routed (pads and idle slots are not)."""
+    if ffn == "none":
+        return x, None
+    h2 = apply_norm(p["ln2"], x, cfg.norm_eps)
+    counts = None
+    if ffn == "moe":
+        y, counts = MOE.apply_moe_dropless(p["moe"], h2, cfg, valid)
+    else:
+        y = apply_mlp(p["mlp"], h2)
+    return x + _scaled(y, cfg.residual_multiplier), counts
+
+
+def _state_barrier(x, cache):
+    """A prefill layer's recurrent state is computed before the next layer
+    starts: left to the end of the layer scan's body, every layer's [B, C,
+    ...] inputs to it would stay live together.  The one-shot and the
+    chunked prefill both place it, so the compiler fuses the two alike
+    (chunked prefill stays bit-exact with one-shot)."""
+    return jax.lax.optimization_barrier((x, cache))
+
+
+def _rows(pctx: dict, C: int):
+    """[B, C] real rows of a chunk (``valid`` per slot)."""
+    return jnp.arange(C)[None, :] < pctx["valid"][:, None]
+
+
 def apply_layer_prefill_paged(p: dict, x, cache: dict, pctx: dict,
                               cfg: ModelConfig, spec: LayerSpec):
     """Prefill-chunk variant of :func:`apply_layer_decode_paged`: attention
     writes the chunk's rows into the pool, Mamba/SSM layers thread their
-    per-slot recurrent state chunk-to-chunk."""
+    per-slot recurrent state chunk-to-chunk.  Returns (x, cache, MoE
+    counts or None)."""
     mixer, ffn = spec
     h = apply_norm(p["ln1"], x, cfg.norm_eps)
     if mixer in ("attn", "enc_attn"):
         y, cache = _attn_prefill_paged(p["attn"], h, cache, pctx, cfg)
-        x = x + y
     elif mixer == "mamba":
         y, cache = SSM.mamba_prefill_chunk(p["mamba"], x, h, cfg, cache,
                                            pctx["valid"])
-        x = x + y
     else:
         raise ValueError(f"paged prefill not supported for mixer {mixer!r}")
-    if ffn != "none":
-        h2 = apply_norm(p["ln2"], x, cfg.norm_eps)
-        if ffn == "moe":
-            # full capacity: the chunk batch mixes unrelated slots' rows
-            # (and pad garbage), so capacity competition would couple
-            # tokens across slots and break chunked == one-shot exactness
-            y, _ = MOE.apply_moe(p["moe"], h2, cfg, full_capacity=True)
-            x = x + y
-        else:
-            x = x + apply_mlp(p["mlp"], h2)
-    return x, cache
+    x = x + _scaled(y, cfg.residual_multiplier)
+    x, counts = _ffn_serve(p, x, cfg, ffn, _rows(pctx, x.shape[1]))
+    if mixer == "mamba":
+        x, cache = _state_barrier(x, cache)
+    return x, cache, counts
 
 
 def _attn_verify_paged(p, x, cache, pctx, cfg: ModelConfig):
@@ -404,55 +457,42 @@ def apply_layer_verify_paged(p: dict, x, cache: dict, pctx: dict,
     """Speculative-verify variant of :func:`apply_layer_decode_paged`: each
     chunk row reproduces per-token decode bitwise — attention mirrors the
     decode softmax over the gathered-and-overlaid table, Mamba/SSM layers
-    step the exact recurrence (ssm.mamba_verify_chunk), and capacity-routed
-    MoE runs dropless so the chunk batch (which mixes slots' rows and pad
-    garbage) cannot couple tokens through expert queues (outputs equal
-    decode's whenever decode's own routing doesn't overflow a queue, as
-    with chunked prefill)."""
+    step the exact recurrence (ssm.mamba_verify_chunk), and MoE is dropless
+    as in decode."""
     mixer, ffn = spec
     h = apply_norm(p["ln1"], x, cfg.norm_eps)
     if mixer in ("attn", "enc_attn"):
         y, cache = _attn_verify_paged(p["attn"], h, cache, pctx, cfg)
-        x = x + y
     elif mixer == "mamba":
         y, cache = SSM.mamba_verify_chunk(p["mamba"], x, h, cfg, cache,
                                           pctx["valid"])
-        x = x + y
     else:
         raise ValueError(f"speculative verify not supported for mixer "
                          f"{mixer!r}")
-    if ffn != "none":
-        h2 = apply_norm(p["ln2"], x, cfg.norm_eps)
-        if ffn == "moe":
-            y, _ = MOE.apply_moe(p["moe"], h2, cfg, full_capacity=True)
-            x = x + y
-        else:
-            x = x + apply_mlp(p["mlp"], h2)
-    return x, cache
+    x = x + _scaled(y, cfg.residual_multiplier)
+    x, counts = _ffn_serve(p, x, cfg, ffn, _rows(pctx, x.shape[1]))
+    return x, cache, counts
 
 
 def apply_layer_decode_paged(p: dict, x, cache: dict, pctx: dict,
                              cfg: ModelConfig, spec: LayerSpec):
     """Paged variant of :func:`apply_layer_decode`; Mamba/SSM layers keep
-    their contiguous per-slot state and are routed around the pool."""
+    their contiguous per-slot state and are routed around the pool.  Only
+    the slots in ``pctx["keep"]`` (all when absent) are routed to experts.
+    Returns (x, cache, MoE counts or None)."""
     mixer, ffn = spec
     h = apply_norm(p["ln1"], x, cfg.norm_eps)
     if mixer in ("attn", "enc_attn"):
         y, cache = _attn_decode_paged(p["attn"], h, cache, pctx, cfg)
-        x = x + y
     elif mixer == "mamba":
         y, cache = SSM.mamba_decode(p["mamba"], h, cache, cfg)
-        x = x + y
     else:
         raise ValueError(f"paged decode not supported for mixer {mixer!r}")
-    if ffn != "none":
-        h2 = apply_norm(p["ln2"], x, cfg.norm_eps)
-        if ffn == "moe":
-            y, _ = MOE.apply_moe(p["moe"], h2, cfg)
-            x = x + y
-        else:
-            x = x + apply_mlp(p["mlp"], h2)
-    return x, cache
+    x = x + _scaled(y, cfg.residual_multiplier)
+    keep = pctx.get("keep")
+    x, counts = _ffn_serve(p, x, cfg, ffn,
+                           None if keep is None else keep[:, None])
+    return x, cache, counts
 
 
 def apply_layer_decode(p: dict, x, cache: dict, lengths, cfg: ModelConfig,
@@ -461,10 +501,10 @@ def apply_layer_decode(p: dict, x, cache: dict, lengths, cfg: ModelConfig,
     h = apply_norm(p["ln1"], x, cfg.norm_eps)
     if mixer in ("attn", "enc_attn"):
         y, cache = _attn_decode(p["attn"], h, cache, lengths, cfg)
-        x = x + y
+        x = x + _scaled(y, cfg.residual_multiplier)
     elif mixer == "mamba":
         y, cache = SSM.mamba_decode(p["mamba"], h, cache, cfg)
-        x = x + y
+        x = x + _scaled(y, cfg.residual_multiplier)
     elif mixer == "cross":
         x = x + A.cross_attn_cached(p["cross"], h, cache["mk"], cache["mv"], cfg)
     elif mixer == "attn_cross":
@@ -474,13 +514,7 @@ def apply_layer_decode(p: dict, x, cache: dict, lengths, cfg: ModelConfig,
         cache = {**cache, "k": sub["k"], "v": sub["v"]}
         hx = apply_norm(p["ln_x"], x, cfg.norm_eps)
         x = x + A.cross_attn_cached(p["xattn"], hx, cache["mk"], cache["mv"], cfg)
-    if ffn != "none":
-        h2 = apply_norm(p["ln2"], x, cfg.norm_eps)
-        if ffn == "moe":
-            y, _ = MOE.apply_moe(p["moe"], h2, cfg)
-            x = x + y
-        else:
-            x = x + apply_mlp(p["mlp"], h2)
+    x, _ = _ffn_serve(p, x, cfg, ffn)
     return x, cache
 
 
@@ -502,7 +536,8 @@ def apply_layer_prefill(p: dict, x, cfg: ModelConfig, spec: LayerSpec, *,
                                   window=cfg.sliding_window,
                                   softcap=cfg.attn_logit_softcap)
         from repro.quant_runtime import qlinear
-        x = x + qlinear.matmul(out.reshape(B, S, -1), p["attn"]["wo"])
+        x = x + _scaled(qlinear.matmul(out.reshape(B, S, -1), p["attn"]["wo"]),
+                        cfg.residual_multiplier)
         r = _kv_eff(cfg) // cfg.n_kv_heads
         if r > 1:  # repeat-sharded cache layout
             k = jnp.repeat(k, r, axis=2)
@@ -534,7 +569,7 @@ def apply_layer_prefill(p: dict, x, cfg: ModelConfig, spec: LayerSpec, *,
                                         mv.astype(_cache_dtype()))
     elif mixer == "mamba":
         y, cache = SSM.mamba_forward(p["mamba"], x, h, cfg, cache=None)
-        x = x + y
+        x = x + _scaled(y, cfg.residual_multiplier)
     elif mixer == "cross":
         x = x + A.cross_attn(p["cross"], h, memory, cfg)
         mk, mv = A.precompute_cross_kv(p["cross"], memory, cfg)
@@ -544,13 +579,9 @@ def apply_layer_prefill(p: dict, x, cfg: ModelConfig, spec: LayerSpec, *,
             mv = jnp.repeat(mv, rx, axis=2)
         cache = {"mk": mk.astype(_cache_dtype()),
                  "mv": mv.astype(_cache_dtype())}
-    if ffn != "none":
-        h2 = apply_norm(p["ln2"], x, cfg.norm_eps)
-        if ffn == "moe":
-            y, _ = MOE.apply_moe(p["moe"], h2, cfg)
-            x = x + y
-        else:
-            x = x + apply_mlp(p["mlp"], h2)
+    x, _ = _ffn_serve(p, x, cfg, ffn)
+    if mixer == "mamba":
+        x, cache = _state_barrier(x, cache)
     return x, cache
 
 
@@ -614,38 +645,53 @@ def run_stack_decode(stack, cache, x, lengths, cfg, specs):
 def run_stack_paged(apply_layer, stack, cache, x, pctx, cfg, specs):
     """Layer scan of a paged forward pass: ``apply_layer`` is
     :func:`apply_layer_decode_paged`, :func:`apply_layer_prefill_paged` or
-    :func:`apply_layer_verify_paged`.
+    :func:`apply_layer_verify_paged`.  Returns (x, cache, MoE counts
+    int32 [2]: assignments and grouped-matmul rows, summed over layers;
+    None without MoE layers).
 
-    The stacked K/V pools (``pk``/``pv``, ``[n_periods, NB+1, bs, Kv, hd]``)
-    ride the scan's carry whole and the layer index is scanned: each layer
-    scatters its rows into ``pool[layer]`` in place and attention gathers
-    ``pool[layer][tbl]`` with the index folded into the gather, so no
-    layer's pool is sliced out, copied or written back, and the donated
-    pools alias through the dispatch.  Per-slot (SSM) state is scanned as
-    before.  The write targets / block table in ``pctx`` are shared by
-    every layer (all layers advance in lockstep) and ride the closure."""
-    pools = {lk: {n: v for n, v in lc.items() if n in POOL_KEYS}
-             for lk, lc in cache.items()}
-    state = {lk: {n: v for n, v in lc.items() if n not in POOL_KEYS}
-             for lk, lc in cache.items()}
+    The whole stacked cache rides the scan's carry and the layer index is
+    scanned, so no leaf is sliced out of the scan's inputs or written back
+    through its outputs, and the donated buffers alias through the
+    dispatch.  The K/V pools (``pk``/``pv``, ``[n_periods, NB+1, bs, Kv,
+    hd]``) are addressed at the layer inside attention: each layer
+    scatters its rows into ``pool[layer]`` and gathers ``pool[layer][tbl]``
+    with the index folded into the gather.  Per-slot (SSM) state
+    ``[n_periods, B, ...]`` is read at the layer and written back there in
+    place; slots outside ``pctx["keep"]`` (when given) keep their state.
+    The write targets / block table in ``pctx`` are shared by every layer
+    (all layers advance in lockstep) and ride the closure."""
     n = jax.tree.leaves(stack)[0].shape[0]
+    keep = pctx.get("keep")
+    moe = any(ffn == "moe" for _, ffn in specs)
 
     def body(carry, xs):
-        h, pools = carry
-        lp, ls, layer = xs
+        h, cache, counts = carry
+        lp, layer = xs
         lctx = {**pctx, "layer": layer}
-        new_pools, new_state = {}, {}
+        new_cache = {}
         for i, spec in enumerate(specs):
             lk = f"L{i}"
-            h, nc = apply_layer(lp[lk], h, {**ls[lk], **pools[lk]}, lctx,
-                                cfg, spec)
-            new_pools[lk] = {k: nc[k] for k in pools[lk]}
-            new_state[lk] = {k: nc[k] for k in ls[lk]}
-        return (h, new_pools), new_state
+            state = {k: jax.lax.dynamic_index_in_dim(v, layer, 0, False)
+                     for k, v in cache[lk].items() if k not in POOL_KEYS}
+            h, nc, cnt = apply_layer(lp[lk], h, {**cache[lk], **state},
+                                     lctx, cfg, spec)
+            new = {k: nc[k].astype(v.dtype) for k, v in state.items()}
+            if keep is not None:
+                new = {k: jnp.where(
+                    keep.reshape(keep.shape + (1,) * (v.ndim - 1)), v,
+                    state[k]) for k, v in new.items()}
+            new_cache[lk] = {
+                k: (nc[k] if k in POOL_KEYS else
+                    jax.lax.dynamic_update_index_in_dim(v, new[k], layer, 0))
+                for k, v in cache[lk].items()}
+            if cnt is not None:
+                counts = counts + cnt
+        return (h, new_cache, counts), None
 
-    (x, pools), state = jax.lax.scan(
-        body, (x, pools), (stack, state, jnp.arange(n, dtype=jnp.int32)))
-    return x, {lk: {**state[lk], **pools[lk]} for lk in cache}
+    (x, cache, counts), _ = jax.lax.scan(
+        body, (x, cache, jnp.zeros((2,), jnp.int32) if moe else None),
+        (stack, jnp.arange(n, dtype=jnp.int32)))
+    return x, cache, counts
 
 
 def run_stack_prefill(stack, x, cfg, specs, *, memory=None, cache_len=0):
@@ -727,7 +773,7 @@ def build_model(cfg: ModelConfig) -> Model:
         return None
 
     def loss_fn(params, batch, remat: str = "full"):
-        x = embed_tokens(params["embed"], batch["tokens"])
+        x = _embed(params, batch["tokens"], cfg)
         mem = _memory(params, batch)
         aux = jnp.float32(0.0)
         if n_prefix:
@@ -737,7 +783,7 @@ def build_model(cfg: ModelConfig) -> Model:
         x, a = run_stack_train(params["stack"], x, cfg, specs, memory=mem,
                                remat=remat)
         aux = aux + a
-        x = apply_norm(params["final_norm"], x, cfg.norm_eps)
+        x = _final(params, x, cfg)
         loss, acc, n_tok = chunked_xent(params["embed"], x, batch["labels"])
         total = loss + aux
         return total, {"loss": loss, "aux_loss": aux, "accuracy": acc,
@@ -757,13 +803,12 @@ def build_model(cfg: ModelConfig) -> Model:
     def prefill(params, batch, cache_len: int | None = None, lengths=None):
         """``lengths`` [B]: per-row true lengths of a right-padded batch.
         Exact for dense causal-attention stacks (pad rows never feed real
-        rows); ring (SWA) caches and Mamba state are position-keyed and MoE
-        capacity routing couples tokens, so callers must pass equal-length
-        batches there (the engine does)."""
+        rows); ring (SWA) caches and Mamba state are position-keyed, so
+        callers must pass equal-length batches there (the engine does)."""
         tokens = batch["tokens"]
         B, S = tokens.shape
         cache_len = cache_len or S
-        x = embed_tokens(params["embed"], tokens)
+        x = _embed(params, tokens, cfg)
         mem = _memory(params, batch)
         cache: dict = {}
         if n_prefix:
@@ -772,7 +817,7 @@ def build_model(cfg: ModelConfig) -> Model:
                 cache_len=cache_len)
         x, cache["stack"] = run_stack_prefill(
             params["stack"], x, cfg, specs, memory=mem, cache_len=cache_len)
-        x = apply_norm(params["final_norm"], x, cfg.norm_eps)
+        x = _final(params, x, cfg)
         logits = last_token_logits(params["embed"], x, lengths)
         cache["lengths"] = (jnp.full((B,), S, jnp.int32) if lengths is None
                             else lengths.astype(jnp.int32))
@@ -780,7 +825,7 @@ def build_model(cfg: ModelConfig) -> Model:
 
     def decode_step(params, tokens, cache):
         """tokens [B, 1] -> (logits [B, V], new cache)."""
-        x = embed_tokens(params["embed"], tokens)
+        x = _embed(params, tokens, cfg)
         lengths = cache["lengths"]
         new_cache = dict(cache)
         if n_prefix:
@@ -789,7 +834,7 @@ def build_model(cfg: ModelConfig) -> Model:
                 prefix_specs)
         x, new_cache["stack"] = run_stack_decode(
             params["stack"], cache["stack"], x, lengths, cfg, specs)
-        x = apply_norm(params["final_norm"], x, cfg.norm_eps)
+        x = _final(params, x, cfg)
         logits = lm_logits(params["embed"], x)[:, 0]
         new_cache["lengths"] = lengths + 1
         return logits, new_cache
@@ -799,6 +844,7 @@ def build_model(cfg: ModelConfig) -> Model:
     # the period too, so the stack leaf is a sufficient geometry probe
     _attn_idx = next((i for i, s in enumerate(specs)
                       if s[0] in ("attn", "enc_attn")), None)
+    _moe = has_moe(cfg)
 
     def init_paged_cache(batch, cache_len, *, block_size: int,
                          num_blocks: int):
@@ -826,6 +872,8 @@ def build_model(cfg: ModelConfig) -> Model:
                                          num_blocks, block_size),
              "lengths": jnp.zeros((batch,), jnp.int32),
              **init_block_state(batch, mb, num_blocks)}
+        if _moe:  # the last forward's MoE counts (run_stack_paged)
+            c["moe_counts"] = jnp.zeros((2,), jnp.int32)
         if n_prefix:
             c["prefix"] = _stack_cache_paged(cfg, prefix_specs, n_prefix,
                                              batch, num_blocks, block_size)
@@ -834,25 +882,34 @@ def build_model(cfg: ModelConfig) -> Model:
     def _run_stacks_paged(apply_layer, params, new_cache, x, pctx):
         """The prefix group (if any), then the period stack, through
         :func:`run_stack_paged`; each group's cache is replaced in
-        ``new_cache``.  Returns the hidden state."""
+        ``new_cache``, and so are the MoE counts.  Returns the final
+        norm's output, the head's input."""
+        counts = []
         if n_prefix:
-            x, new_cache["prefix"] = run_stack_paged(
+            x, new_cache["prefix"], c = run_stack_paged(
                 apply_layer, params["prefix"], new_cache["prefix"], x, pctx,
                 cfg, prefix_specs)
-        x, new_cache["stack"] = run_stack_paged(
+            counts.append(c)
+        x, new_cache["stack"], c = run_stack_paged(
             apply_layer, params["stack"], new_cache["stack"], x, pctx, cfg,
             specs)
-        return x
+        counts = [c for c in counts + [c] if c is not None]
+        if counts:
+            new_cache["moe_counts"] = sum(counts[1:], counts[0])
+        return _final(params, x, cfg)
 
-    def decode_step_paged(params, tokens, pcache, cow: bool = False):
+    def decode_step_paged(params, tokens, pcache, cow: bool = False,
+                          keep=None):
         """tokens [B, 1] -> (logits [B, V], new paged cache).  Block
         allocation and write targets are computed once per step and shared
         by every attention layer (the stack advances in lockstep).  With
         ``cow=True`` (refcounted prefix caching) a write landing in a
         shared block pops a private copy, copied for every layer before the
-        layer scan (:func:`cow_copy_blocks`) — see engine/paged.py."""
+        layer scan (:func:`cow_copy_blocks`) — see engine/paged.py.
+        ``keep`` [B] (optional): the slots that decode; the others keep
+        their per-slot state and route no token to an expert."""
         from repro.engine.paged import BSTATE_KEYS, alloc_step
-        x = embed_tokens(params["embed"], tokens)
+        x = _embed(params, tokens, cfg)
         lengths = pcache["lengths"]
         new_cache = dict(pcache)
         if _attn_idx is not None:
@@ -870,9 +927,10 @@ def build_model(cfg: ModelConfig) -> Model:
                 new_cache = cow_copy_blocks(new_cache, cow_src, wblk)
         else:  # pure-SSM stack: contiguous state, no pools to manage
             pctx = {"lengths": lengths}
+        if keep is not None:
+            pctx["keep"] = keep
         x = _run_stacks_paged(apply_layer_decode_paged, params, new_cache, x,
                               pctx)
-        x = apply_norm(params["final_norm"], x, cfg.norm_eps)
         logits = lm_logits(params["embed"], x)[:, 0]
         new_cache["lengths"] = lengths + 1
         return logits, new_cache
@@ -890,7 +948,7 @@ def build_model(cfg: ModelConfig) -> Model:
         per-slot lengths advanced to ``start + valid``."""
         from repro.engine.paged import BSTATE_KEYS, span_targets
         B, C = tokens.shape
-        x = embed_tokens(params["embed"], tokens)
+        x = _embed(params, tokens, cfg)
         start = start.astype(jnp.int32)
         valid = valid.astype(jnp.int32)
         pctx = {"start": start, "valid": valid}
@@ -906,7 +964,6 @@ def build_model(cfg: ModelConfig) -> Model:
             pctx.update(tbl=bstate["tbl"], wblk=wblk, woff=woff)
         x = _run_stacks_paged(apply_layer_prefill_paged, params, new_cache, x,
                               pctx)
-        x = apply_norm(params["final_norm"], x, cfg.norm_eps)
         idx = jnp.clip(valid - 1, 0, C - 1)
         xg = x[jnp.arange(B), idx][:, None]
         logits = lm_logits(params["embed"], xg)[:, 0]
@@ -928,7 +985,7 @@ def build_model(cfg: ModelConfig) -> Model:
         beyond ``valid[b]`` are state no-ops with garbage logits."""
         from repro.engine.paged import BSTATE_KEYS, span_targets
         B, C = tokens.shape
-        x = embed_tokens(params["embed"], tokens)
+        x = _embed(params, tokens, cfg)
         start = start.astype(jnp.int32)
         valid = valid.astype(jnp.int32)
         pctx = {"start": start, "valid": valid}
@@ -944,7 +1001,6 @@ def build_model(cfg: ModelConfig) -> Model:
             pctx.update(tbl=bstate["tbl"], wblk=wblk, woff=woff)
         x = _run_stacks_paged(apply_layer_verify_paged, params, new_cache, x,
                               pctx)
-        x = apply_norm(params["final_norm"], x, cfg.norm_eps)
         logits = lm_logits(params["embed"], x)
         new_cache["lengths"] = jnp.where(valid > 0, start + valid,
                                          pcache["lengths"])

@@ -28,6 +28,9 @@ from repro.configs.base import ModelConfig
 from repro.models.common import dense_init, split
 from repro.quant_runtime import qlinear
 
+# Bytes of one head group's [B, Q, Q, heads] intra-chunk decay in ssd_scan.
+INTRA_BYTES = 64 << 20
+
 
 def init_mamba(key, cfg: ModelConfig, dtype) -> dict:
     D = cfg.d_model
@@ -53,6 +56,7 @@ def init_mamba(key, cfg: ModelConfig, dtype) -> dict:
     }
 
 
+@jax.named_scope("mamba")
 def _causal_conv(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
                  state: jnp.ndarray | None = None, valid=None):
     """Depthwise causal conv over S.  x [B,S,C]; w [K,C].
@@ -80,6 +84,7 @@ def _causal_conv(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
     return jax.nn.silu(out), new_state
 
 
+@jax.named_scope("mamba")
 def _gated_norm(y: jnp.ndarray, z: jnp.ndarray, scale: jnp.ndarray,
                 eps: float = 1e-5) -> jnp.ndarray:
     """RMSNormGated: norm(y * silu(z)) * scale (fp32 internals)."""
@@ -116,6 +121,7 @@ def _project(p: dict, h: jnp.ndarray, cfg: ModelConfig,
 # Chunked SSD forward (train / prefill)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("mamba")
 def ssd_scan(xh, Bc, Cc, dt, a_log, chunk: int, h0=None):
     """Chunked SSD.  xh [B,S,nh,P], Bc/Cc [B,S,N], dt [B,S,nh] (post-softplus).
 
@@ -143,6 +149,16 @@ def ssd_scan(xh, Bc, Cc, dt, a_log, chunk: int, h0=None):
 
     if h0 is None:
         h0 = jnp.zeros((Bsz, nh, P, N), jnp.float32)
+    # heads per group of the intra-chunk term: its [B, Q, Q, heads] decay
+    # tensor is the largest temporary, so it is built a group at a time
+    g = nh
+    while g > 1 and (Bsz * Q * Q * g * 4 > INTRA_BYTES or nh % g):
+        g -= 1
+    tri = jnp.tril(jnp.ones((Q, Q), bool))
+
+    def heads(a):  # [B, Q, nh, ...] -> [nh // g, B, Q, g, ...]
+        a = a.reshape(a.shape[:2] + (nh // g, g) + a.shape[3:])
+        return jnp.moveaxis(a, 2, 0)
 
     def body(h, xs):
         xq, bq, cq, dq, lq = xs                        # per-chunk tensors
@@ -150,20 +166,33 @@ def ssd_scan(xh, Bc, Cc, dt, a_log, chunk: int, h0=None):
         bq = bq.astype(jnp.float32)
         cq = cq.astype(jnp.float32)
         cum = jnp.cumsum(lq, axis=1)                   # [B,Q,nh] inclusive
-        # intra-chunk: scores[t,s] = (C_t.B_s) * exp(cum_t - cum_s) * dt_s, s<=t
-        seg = cum[:, :, None, :] - cum[:, None, :, :]  # [B,Q(t),Q(s),nh]
-        tri = jnp.tril(jnp.ones((Q, Q), bool))
-        decay = jnp.exp(jnp.where(tri[None, :, :, None], seg, -jnp.inf))
         cb = jnp.einsum("btn,bsn->bts", cq, bq)        # [B,Q,Q]
-        w = cb[..., None] * decay * dq[:, None, :, :]  # [B,t,s,nh]
-        y_intra = jnp.einsum("btsh,bshp->bthp", w, xq)
+
+        # intra-chunk: scores[t,s] = (C_t.B_s) * exp(cum_t - cum_s) * dt_s,
+        # s <= t
+        def intra(args):
+            cg, dg, xg = args                          # [B,Q,g] x2, [B,Q,g,P]
+            seg = cg[:, :, None, :] - cg[:, None, :, :]    # [B,t,s,g]
+            decay = jnp.exp(jnp.where(tri[None, :, :, None], seg, -jnp.inf))
+            w = cb[..., None] * decay * dg[:, None, :, :]
+            return jnp.einsum("btsh,bshp->bthp", w, xg)
+
+        if g == nh:
+            y_intra = intra((cum, dq, xq))
+        else:
+            y_intra = jax.lax.map(intra, (heads(cum), heads(dq), heads(xq)))
+            y_intra = jnp.moveaxis(y_intra, 0, 2).reshape(Bsz, Q, nh, P)
+        # the state as [B, nh*P, N]: one layout for decode and prefill
+        hf = h.reshape(Bsz, nh * P, N)
         # inter-chunk: contribution of h (state before this chunk)
-        state_decay = jnp.exp(cum)                     # exp(sum_{r<=t} la_r)
-        y_inter = jnp.einsum("btn,bhpn->bthp", cq, h) * state_decay[..., None]
-        # chunk state update
-        rem = jnp.exp(cum[:, -1:, :] - cum)            # decay from s to end
-        contrib = jnp.einsum("bshp,bsn,bsh,bsh->bhpn", xq, bq, dq, rem)
-        h_new = h * jnp.exp(cum[:, -1])[..., None, None] + contrib
+        y_inter = jnp.einsum("btn,bkn->btk", cq, hf).reshape(Bsz, Q, nh, P)
+        y_inter = y_inter * jnp.exp(cum)[..., None]    # exp(sum_{r<=t} la_r)
+        # chunk state update: decay from s to the chunk's end
+        rem = jnp.exp(cum[:, -1:, :] - cum)
+        xw = (xq * (dq * rem)[..., None]).reshape(Bsz, Q, nh * P)
+        contrib = jnp.einsum("bsk,bsn->bkn", xw, bq)
+        h_new = (hf * jnp.repeat(jnp.exp(cum[:, -1]), P, axis=1)[..., None]
+                 + contrib).reshape(Bsz, nh, P, N)
         return h_new, y_intra + y_inter
 
     h_fin, ys = jax.lax.scan(body, h0, (xc, bc, cc, dc, lc))
@@ -248,6 +277,7 @@ def mamba_verify_chunk(p: dict, x_in: jnp.ndarray, h: jnp.ndarray,
     vm = jnp.arange(C)[None, :] < valid[:, None]            # [B, C]
     dt_m = jnp.where(vm[:, :, None], dt, 0.0)
 
+    @jax.named_scope("mamba")
     def body(hst, xs):
         xt, bt, ct, dtt = xs                   # [B,nh,P] [B,N] [B,N] [B,nh]
         a = jnp.exp(A[None] * dtt)
@@ -285,14 +315,15 @@ def mamba_decode(p: dict, x: jnp.ndarray, cache: dict, cfg: ModelConfig):
     Bsz = x.shape[0]
     di = cfg.resolved_d_inner
     z, xh, Bc, Cc, dt, conv_state = _project(p, x, cfg, cache)
-    A = -jnp.exp(p["a_log"].astype(jnp.float32))
-    a = jnp.exp(A[None] * dt[:, 0])                    # [B,nh]
-    xh32 = xh.astype(jnp.float32)
-    dBx = jnp.einsum("bhp,bn,bh->bhpn", xh32[:, 0], Bc[:, 0].astype(jnp.float32),
-                     dt[:, 0])
-    h = cache["h"] * a[..., None, None] + dBx
-    y = jnp.einsum("bn,bhpn->bhp", Cc[:, 0].astype(jnp.float32), h)
-    y = y + p["d_skip"].astype(jnp.float32)[None, :, None] * xh32[:, 0]
+    with jax.named_scope("mamba"):
+        A = -jnp.exp(p["a_log"].astype(jnp.float32))
+        a = jnp.exp(A[None] * dt[:, 0])                # [B,nh]
+        xh32 = xh.astype(jnp.float32)
+        dBx = jnp.einsum("bhp,bn,bh->bhpn", xh32[:, 0],
+                         Bc[:, 0].astype(jnp.float32), dt[:, 0])
+        h = cache["h"] * a[..., None, None] + dBx
+        y = jnp.einsum("bn,bhpn->bhp", Cc[:, 0].astype(jnp.float32), h)
+        y = y + p["d_skip"].astype(jnp.float32)[None, :, None] * xh32[:, 0]
     y = _gated_norm(y.reshape(Bsz, 1, di), z, p["norm_scale"])
     out = qlinear.matmul(y.astype(x.dtype), p["out_proj"])
     return out, {"h": h, **conv_state}

@@ -22,8 +22,6 @@ flags: dict = {
     "seq_shard": True,
     # constrain the residual batch dim over the dp axes between layers
     "batch_constraint": True,
-    # MoE dispatch group target size (tokens per routing group)
-    "moe_group": 1024,
     # attention q/kv chunk sizes for the online-softmax scan
     "q_chunk": 1024,
     "kv_chunk": 1024,
@@ -40,10 +38,10 @@ flags: dict = {
     #                       all-gathered over data at every use
     #   "ep_data_tp_model"  E over `data`, F over `model` — weights fully
     #                       local; REFUTED for the GShard einsum-dispatch
-    #                       formulation (kimi train collective 160s -> 526s:
-    #                       routing tensors blow up when E shards the batch
-    #                       axis).  Kept for the §Perf log; a sort-based
-    #                       all-to-all dispatch would be needed to win here.
+    #                       formulation this repo used to train with (kimi
+    #                       train collective 160s -> 526s: routing tensors
+    #                       blow up when E shards the batch axis); not
+    #                       measured for the dropless sort-based layer.
     "moe_sharding": "ep_model",
 }
 

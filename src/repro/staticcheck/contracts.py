@@ -171,7 +171,8 @@ def entry_args(eng, case: Case, name: str) -> tuple:
     if eng.mesh is not None:
         cache = eng._place_cache(cache)
     pcap = cfg.cache_len
-    state = init_slot_state(B, prompt_cap=pcap if cfg.chunk_size else 0)
+    state = init_slot_state(B, prompt_cap=pcap if cfg.chunk_size else 0,
+                            moe=eng._moe)
 
     if name == "_dispatch":
         return (eng.params, state, cache, key)

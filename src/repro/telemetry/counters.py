@@ -54,11 +54,21 @@ COUNTER_KEYS = (
                          # failed, pool dry) — they retry next round
 )
 
+# Only for models with MoE layers (``init_counters(moe=True)``): bumped in
+# the decode and chunked-prefill sub-steps of ``scheduler.py`` from the
+# paged cache's ``moe_counts`` (``lm.run_stack_paged``).
+MOE_COUNTER_KEYS = (
+    "moe_assigned",      # (token, held expert) assignments, every layer
+    "moe_rows",          # rows the grouped expert matmuls computed for
+                         # them, tile padding included (moe.row_tile)
+)
 
-def init_counters() -> dict:
+
+def init_counters(moe: bool = False) -> dict:
     """Zeroed counter pytree — strong int32 scalars (a weak-typed literal
     here would retrace every dispatch; see staticcheck weak-type rule)."""
-    return {k: jnp.zeros((), jnp.int32) for k in COUNTER_KEYS}
+    keys = COUNTER_KEYS + (MOE_COUNTER_KEYS if moe else ())
+    return {k: jnp.zeros((), jnp.int32) for k in keys}
 
 
 def bump(ctr: dict, **deltas) -> dict:
@@ -72,5 +82,7 @@ def bump(ctr: dict, **deltas) -> dict:
 
 def counter_totals(ctr_host: dict) -> dict:
     """Host-side view of a fetched counter tree as plain ints, in
-    COUNTER_KEYS order (stable for snapshots and stats)."""
-    return {k: int(ctr_host[k]) for k in COUNTER_KEYS}
+    COUNTER_KEYS (then MOE_COUNTER_KEYS) order (stable for snapshots and
+    stats)."""
+    return {k: int(ctr_host[k]) for k in COUNTER_KEYS + MOE_COUNTER_KEYS
+            if k in ctr_host}

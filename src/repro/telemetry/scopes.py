@@ -7,6 +7,12 @@ The model and the engine wrap their device work in ``jax.named_scope``:
 * ``attention``     — the attention core only (paged decode / prefill /
                       verify attention, decode attention), not the
                       projections around it, so no op is under both;
+* ``mamba``         — the Mamba-2 core (``models.ssm``): causal conv, SSD
+                      chunk scan or per-token recurrence, gated norm; not
+                      its projections;
+* ``moe``           — the dropless expert layer (``models.moe``): routing,
+                      the sort, the grouped expert matmuls and the combine;
+                      not the shared expert (a ``qlinear`` MLP);
 * ``prefill_chunk`` — the in-scan chunked-prefill piece
                       (``scheduler.chunk_prefill_substep``);
 * ``keep_rows``     — the per-slot cache merge (``scheduler._keep_rows``);
@@ -23,7 +29,8 @@ from __future__ import annotations
 
 from repro.analysis.hlo import HloModule
 
-SCOPES = ("qlinear", "attention", "prefill_chunk", "keep_rows", "sample")
+SCOPES = ("qlinear", "attention", "mamba", "moe", "prefill_chunk",
+          "keep_rows", "sample")
 
 _OP_NAME = r'op_name="([^"]*)"'
 

@@ -1,6 +1,5 @@
 """Serving-engine tests: greedy parity with the old host loop, slot reuse,
 sampler behavior, sharded smoke, quantized embedding gather."""
-import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -15,10 +14,8 @@ from repro.models import build_model
 KEY = jax.random.PRNGKey(0)
 
 
-def _setup(arch="glm4-9b", **repl):
+def _setup(arch="glm4-9b"):
     cfg = reduced(get_arch(arch))
-    if repl:
-        cfg = dataclasses.replace(cfg, **repl)
     model = build_model(cfg)
     params = model.init(KEY)
     spec = LanguageSpec(vocab=cfg.vocab_size)
@@ -58,13 +55,12 @@ def test_engine_greedy_parity_unequal_lengths_padded_prefill():
 
 
 def test_engine_bucketed_prefill_for_ring_ssm_and_moe():
-    """SWA-ring, Mamba-state and capacity-routed MoE configs refuse padding
-    (pad tokens would corrupt ring slots / SSM state / expert capacity) and
-    group prompts by exact length — outputs still match the legacy loop."""
-    for arch, repl in (("mixtral-8x22b", {"capacity_factor": 8.0}),
-                       ("mamba2-780m", {}),
-                       ("deepseek-v3", {})):   # moe, no sliding window
-        cfg, model, params, spec = _setup(arch, **repl)
+    """SWA-ring, Mamba-state and MoE configs refuse padding (pad tokens
+    would corrupt ring slots / SSM state) and group prompts by exact
+    length — outputs still match the legacy loop."""
+    for arch in ("mixtral-8x22b", "mamba2-780m",
+                 "deepseek-v3"):               # moe, no sliding window
+        cfg, model, params, spec = _setup(arch)
         prompts = _prompts(spec, [9, 12, 9])
         eng = Engine(model, params, slots=2, cache_len=34, k_steps=2)
         assert not eng._can_pad

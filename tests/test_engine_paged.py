@@ -75,17 +75,19 @@ def test_paged_token_exact_swa_ring():
     assert peng.serve(prompts, gen_tokens=5) == legacy
 
 
-def test_paged_routes_around_contiguous_state():
-    """Mamba (pure SSM) and hybrid (Jamba) stacks: SSM state has no
-    sequence axis to page and stays per-slot dense; outputs still match."""
-    for arch in ("mamba2-780m", "jamba-v0.1-52b"):
-        cfg, model, params, spec = _setup(arch)
-        prompts = _prompts(spec, [9, 12, 9])
-        contig = Engine(model, params, slots=2, cache_len=34,
-                        k_steps=2).serve(prompts, gen_tokens=4)
-        pout = Engine(model, params, slots=2, cache_len=34, k_steps=2,
-                      paged=True, block_size=8).serve(prompts, gen_tokens=4)
-        assert pout == contig, arch
+@pytest.mark.parametrize("arch", ["mamba2-780m", "jamba-v0.1-52b",
+                                  "granite-4.0-h-small"])
+def test_paged_routes_around_contiguous_state(arch):
+    """Mamba (pure SSM) and hybrid (Jamba; Granite with a held expert
+    share) stacks: SSM state has no sequence axis to page and stays
+    per-slot dense; outputs still match."""
+    cfg, model, params, spec = _setup(arch)
+    prompts = _prompts(spec, [9, 12, 9])
+    contig = Engine(model, params, slots=2, cache_len=34,
+                    k_steps=2).serve(prompts, gen_tokens=4)
+    pout = Engine(model, params, slots=2, cache_len=34, k_steps=2,
+                  paged=True, block_size=8).serve(prompts, gen_tokens=4)
+    assert pout == contig, arch
 
 
 def test_paged_tight_pool_serializes_but_stays_exact():
@@ -137,33 +139,22 @@ def test_paged_gen_tokens_one_releases_blocks_at_admission():
 # Chunked prefill: bit-identical to one-shot prefill across the families
 # ---------------------------------------------------------------------------
 
-def _setup_dropless(arch):
-    """MoE configs at dropless capacity (capacity_factor = n_experts, so no
-    token can overflow an expert queue).  Chunked prefill routes each chunk
-    at full capacity by construction — GShard *round-major* capacity
-    positions are non-causal (a token's 2nd-choice queue position depends
-    on LATER tokens' 1st choices), so one-shot drop decisions are
-    fundamentally unreproducible from a chunk's worth of tokens.  Exactness
-    is therefore defined (and asserted) on dropless routing, which is what
-    a serving engine wants regardless; weights are unaffected."""
-    import dataclasses
-    key = ("dropless", arch)
-    if key not in _BUILT:
-        cfg = reduced(get_arch(arch))
-        cfg = dataclasses.replace(cfg, capacity_factor=float(cfg.n_experts))
-        model = build_model(cfg)
-        params = model.init(KEY)
-        _BUILT[key] = (cfg, model, params,
-                       LanguageSpec(vocab=cfg.vocab_size))
-    return _BUILT[key]
-
-
-def test_chunked_prefill_token_exact_matrix():
+@pytest.mark.parametrize("arch, moe, chunk, lens, cache_len", [
+    ("glm4-9b", False, 8, [10, 25, 6, 17], 40),      # dense
+    ("mixtral-8x22b", True, 8, [9, 21, 9, 14], 34),  # SWA ring + MoE
+    ("deepseek-v3", True, 8, [9, 21, 14], 34),       # MoE (no window)
+    ("mamba2-780m", False, 32, [9, 40, 12], 48),     # pure SSM
+    ("jamba-v0.1-52b", True, 32, [9, 40, 12], 48),   # hybrid
+    ("granite-4.0-h-small", True, 32, [9, 40, 12], 48),  # hybrid, held
+                                                         # expert share
+], ids=lambda v: v if isinstance(v, str) else None)
+def test_chunked_prefill_token_exact_matrix(arch, moe, chunk, lens,
+                                            cache_len):
     """Chunked prefill (prompts streaming through the decode dispatch in
     chunk_size pieces) must be token-exact vs the one-shot-prefill
     contiguous engine on every family: dense, SWA-ring (chunks wrap the
-    paged ring), capacity-routed MoE (dropless — see _setup_dropless),
-    pure SSM and hybrid (state threaded chunk-to-chunk on the SSD grid).
+    paged ring), MoE (dropless on every path: the expert layer counts its
+    assignments), pure SSM and hybrid (state threaded chunk-to-chunk on the SSD grid).
     Prompts cross chunk AND block boundaries and mix with in-flight
     decode (slot churn: more requests than slots).
 
@@ -173,24 +164,17 @@ def test_chunked_prefill_token_exact_matrix():
     direction.  SWA/SSM/hybrid run with matching disabled (position-keyed
     rings / recurrent state can't be shared), so their warm pass pins that
     the persistent cache stays exact with sharing inert."""
-    cases = [
-        ("glm4-9b", False, 8, [10, 25, 6, 17], 40),      # dense
-        ("mixtral-8x22b", True, 8, [9, 21, 9, 14], 34),  # SWA ring + MoE
-        ("deepseek-v3", True, 8, [9, 21, 14], 34),       # MoE (no window)
-        ("mamba2-780m", False, 32, [9, 40, 12], 48),     # pure SSM
-        ("jamba-v0.1-52b", True, 32, [9, 40, 12], 48),   # hybrid
-    ]
-    for arch, moe, chunk, lens, cache_len in cases:
-        cfg, model, params, spec = (_setup_dropless(arch) if moe
-                                    else _setup(arch))
-        prompts = _prompts(spec, lens)
-        contig = Engine(model, params, slots=2, cache_len=cache_len,
-                        k_steps=2).serve(prompts, gen_tokens=4)
-        peng = Engine(model, params, slots=2, cache_len=cache_len,
-                      k_steps=2, paged=True, block_size=8, chunk_size=chunk,
-                      prefix_cache=True, check_invariants=True)
-        assert peng.serve(prompts, gen_tokens=4) == contig, arch   # cold
-        assert peng.serve(prompts, gen_tokens=4) == contig, arch   # warm
+    cfg, model, params, spec = _setup(arch)
+    prompts = _prompts(spec, lens)
+    contig = Engine(model, params, slots=2, cache_len=cache_len,
+                    k_steps=2).serve(prompts, gen_tokens=4)
+    peng = Engine(model, params, slots=2, cache_len=cache_len,
+                  k_steps=2, paged=True, block_size=8, chunk_size=chunk,
+                  prefix_cache=True, check_invariants=True)
+    outs, stats = peng.serve(prompts, gen_tokens=4, return_stats=True)
+    assert outs == contig, arch                                  # cold
+    assert (stats["counters"].get("moe_assigned", 0) > 0) == moe, arch
+    assert peng.serve(prompts, gen_tokens=4) == contig, arch   # warm
 
 
 def test_chunked_prefill_without_prefix_cache_exact():

@@ -7,7 +7,6 @@ the dynamic-depth controller (pinned trajectories + zero recompiles),
 the acceptance rules as pure functions, sampler distribution correctness
 (temperature / top-k / top-p frequency + lossless rejection-sampling
 unbiasedness), config validation, and the quantized-head matmul."""
-import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -29,15 +28,12 @@ KEY = jax.random.PRNGKey(0)
 _BUILT: dict = {}
 
 
-def _setup(arch="glm4-9b", dropless=False):
+def _setup(arch="glm4-9b"):
     """Model + params (+ a quantized absmax draft tree and a wrong-seed
     'bad' draft), cached per arch so jit caches stay warm."""
-    key = (arch, dropless)
+    key = arch
     if key not in _BUILT:
         cfg = reduced(get_arch(arch))
-        if dropless:
-            cfg = dataclasses.replace(cfg,
-                                      capacity_factor=float(cfg.n_experts))
         model = build_model(cfg)
         params = model.init(KEY)
         draft, _ = quantize(params, None,
@@ -74,19 +70,18 @@ def _shared_prompts(spec, lens, share, seed=0, dup=True):
 def test_spec_token_exact_matrix():
     """Speculative greedy output must equal the non-speculative paged
     engine token for token on dense, SWA-ring+MoE, MoE, pure-SSM and
-    hybrid configs (MoE at dropless capacity, as for chunked prefill: the
-    verify chunk routes dropless by construction).  The draft is a real
+    hybrid configs (MoE dropless on every path).  The draft is a real
     absmax-quantized tree, so rounds mix accepts and rejections; the
     acceptance rate must be meaningful (> 0) for a draft this close."""
     cases = [
-        ("glm4-9b", False, [10, 25, 6, 17], 40),
-        ("mixtral-8x22b", True, [9, 21, 9, 14], 34),   # SWA ring + MoE
-        ("deepseek-v3", True, [9, 21, 14], 34),        # MoE, prefix stack
-        ("mamba2-780m", False, [9, 40, 12], 48),       # pure SSM
-        ("jamba-v0.1-52b", True, [9, 40, 12], 48),     # hybrid
+        ("glm4-9b", [10, 25, 6, 17], 40),
+        ("mixtral-8x22b", [9, 21, 9, 14], 34),   # SWA ring + MoE
+        ("deepseek-v3", [9, 21, 14], 34),        # MoE, prefix stack
+        ("mamba2-780m", [9, 40, 12], 48),        # pure SSM
+        ("jamba-v0.1-52b", [9, 40, 12], 48),     # hybrid
     ]
-    for arch, moe, lens, cache_len in cases:
-        cfg, model, params, draft, _, spec = _setup(arch, dropless=moe)
+    for arch, lens, cache_len in cases:
+        cfg, model, params, draft, _, spec = _setup(arch)
         prompts = _prompts(spec, lens)
         base = Engine(model, params, slots=2, cache_len=cache_len,
                       k_steps=3, paged=True, block_size=8
@@ -111,15 +106,15 @@ def test_spec_composed_token_exact_matrix():
     the same composition unshared — exactness must hold with zero hits
     too."""
     cases = [
-        # arch, moe, chunk, lens (> share), cache_len, hits expected
-        ("glm4-9b", False, 8, [18, 25, 18, 21], 40, True),
-        ("mixtral-8x22b", True, 8, [18, 21, 18], 34, False),   # SWA ring
-        ("deepseek-v3", True, 8, [18, 21, 18], 34, True),      # MoE
-        ("mamba2-780m", False, 32, [18, 40, 18], 48, False),   # pure SSM
-        ("jamba-v0.1-52b", True, 32, [18, 40, 18], 48, False),  # hybrid
+        # arch, chunk, lens (> share), cache_len, hits expected
+        ("glm4-9b", 8, [18, 25, 18, 21], 40, True),
+        ("mixtral-8x22b", 8, [18, 21, 18], 34, False),   # SWA ring
+        ("deepseek-v3", 8, [18, 21, 18], 34, True),      # MoE
+        ("mamba2-780m", 32, [18, 40, 18], 48, False),    # pure SSM
+        ("jamba-v0.1-52b", 32, [18, 40, 18], 48, False),  # hybrid
     ]
-    for arch, moe, chunk, lens, cache_len, can_hit in cases:
-        cfg, model, params, draft, _, spec = _setup(arch, dropless=moe)
+    for arch, chunk, lens, cache_len, can_hit in cases:
+        cfg, model, params, draft, _, spec = _setup(arch)
         prompts = _shared_prompts(spec, lens, share=16)
         base = Engine(model, params, slots=2, cache_len=cache_len,
                       k_steps=3, paged=True, block_size=8, chunk_size=chunk,
@@ -644,24 +639,10 @@ def test_spec_composes_with_prefix_and_chunking():
     assert eng.serve(prompts, gen_tokens=4) == base
 
 
-def test_spec_rejects_capacity_routed_moe():
-    """The verify forward routes MoE dropless; a config whose decode path
-    can drop tokens (capacity_factor * top_k < n_experts) could diverge
-    from the non-speculative engine on an overflowing queue, so the engine
-    refuses it early instead of silently weakening the lossless claim.
-    (Construction fails before params are touched, so stubs suffice.)"""
-    cfg = reduced(get_arch("deepseek-v3"))
-    assert cfg.capacity_factor * cfg.top_k < cfg.n_experts  # droppy default
-    model = build_model(cfg)
-    with pytest.raises(ValueError, match="dropless"):
-        Engine(model, {}, slots=2, cache_len=32, paged=True, block_size=8,
-               n_spec=2, draft_params={"stub": True})
-
-
 def test_swa_block_size_validation_is_early():
     """block_size not dividing the SWA window fails at Engine construction
     with a friendly message, not as a deep shape error at first serve."""
-    cfg, model, params, _, _, spec = _setup("mixtral-8x22b", dropless=True)
+    cfg, model, params, _, _, spec = _setup("mixtral-8x22b")
     with pytest.raises(ValueError, match="must divide the sliding window"):
         Engine(model, params, slots=2, cache_len=34, paged=True,
                block_size=6)

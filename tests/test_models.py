@@ -64,8 +64,6 @@ def test_arch_decode(arch):
 def test_decode_matches_prefill(arch):
     """Decoding token S from a cache == prefilling S+1 tokens directly."""
     cfg = reduced(get_arch(arch))
-    if cfg.n_experts:  # capacity dropping differs between paths
-        cfg = dataclasses.replace(cfg, capacity_factor=8.0)
     model = build_model(cfg)
     params = model.init(KEY)
     toks = jax.random.randint(KEY, (B, S + 1), 0, cfg.vocab_size)
@@ -108,8 +106,7 @@ def test_sliding_window_ring_cache():
     # Mixtral rides the identical ring code path; pin the ring-sized cache
     # shape and decode finiteness, and token-level agreement within the
     # window (no wraparound yet, routing margins unchallenged).
-    mcfg = dataclasses.replace(reduced(get_arch("mixtral-8x22b")),
-                               capacity_factor=8.0)
+    mcfg = reduced(get_arch("mixtral-8x22b"))
     assert mcfg.sliding_window == 16
     mmodel = build_model(mcfg)
     mparams = mmodel.init(KEY)
@@ -121,6 +118,25 @@ def test_sliding_window_ring_cache():
     mb, _ = mmodel.prefill(mparams, {"tokens": mtoks}, cache_len=13)
     assert np.isfinite(np.asarray(ma, np.float32)).all()
     assert jnp.array_equal(jnp.argmax(ma, -1), jnp.argmax(mb, -1))
+
+
+def test_load_balance_loss():
+    """The training loss term of the dropless expert layer: 1 when tokens
+    and probability spread evenly over the experts, E when every token's
+    first choice and all its probability go to one expert; ``loss_fn``
+    reports ``router_aux_loss`` times it, summed over the MoE layers."""
+    from repro.models import moe as MOE
+    E, D = 8, 16
+    x = jnp.ones((2, 32, D))
+    even = MOE.load_balance_loss(jnp.zeros((D, E)), x)
+    one = MOE.load_balance_loss(jnp.zeros((D, E)).at[:, 3].set(100.0), x)
+    assert abs(float(even) - 1.0) < 1e-6 and abs(float(one) - E) < 1e-4
+    cfg = reduced(get_arch("deepseek-v3"))
+    model = build_model(cfg)
+    _, metrics = jax.jit(model.loss_fn)(model.init(KEY), _batch(cfg))
+    n_moe = cfg.n_layers - cfg.first_k_dense
+    aux = float(metrics["aux_loss"]) / cfg.router_aux_loss
+    assert 0.9 * n_moe < aux < E * n_moe, aux
 
 
 def test_param_count_close_to_analytic():

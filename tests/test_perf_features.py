@@ -66,9 +66,7 @@ def test_fp8_kv_cache_close():
 
 def test_fp8_kv_cache_ring():
     """fp8 cache + SWA ring compose."""
-    import dataclasses
-    cfg = dataclasses.replace(reduced(get_arch("mixtral-8x22b")),
-                              capacity_factor=8.0)
+    cfg = reduced(get_arch("mixtral-8x22b"))
     runtime.flags["kv_cache_dtype"] = "float8_e4m3fn"
     model = build_model(cfg)
     params = model.init(KEY)

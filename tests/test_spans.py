@@ -138,8 +138,10 @@ def test_scope_path_reads_op_names():
     assert scope_path("jit(f)/prefill_chunk/attention/attention/exp") \
         == "prefill_chunk/attention"
     assert scope_path("jit(f)/while/body/add") == ""
-    assert SCOPES == ("qlinear", "attention", "prefill_chunk", "keep_rows",
-                      "sample")
+    assert scope_path("jit(f)/prefill_chunk/moe/qlinear/dot") \
+        == "prefill_chunk/moe/qlinear"
+    assert SCOPES == ("qlinear", "attention", "mamba", "moe",
+                      "prefill_chunk", "keep_rows", "sample")
 
 
 def test_hlo_texts_needs_a_served_prefix_engine(tiny):

@@ -175,3 +175,125 @@ def test_paged_dispatch_updates_pools_in_place(one_chip, entry):
     grow = (c4.memory_analysis().temp_size_in_bytes
             - c2.memory_analysis().temp_size_in_bytes)
     assert grow < layer_kv, (grow, layer_kv)
+
+
+def _hybrid_dispatch_compiled(sharding, entry, n_layers):
+    """``entry`` of an engine that serves a Granite 4.0-H hybrid at its
+    published widths (Mamba-2: 128 heads of 64, state 128; 9 of 72 experts
+    held) from fp8, with a period of two layers (Mamba-2, then attention):
+    the cell's 24 slots, cache_len 512, chunk 256, 2 steps a dispatch.  Returns
+    (compiled, per-layer SSM state shape)."""
+    import dataclasses
+    from repro.configs import get_arch
+    from repro.core.policy import path_str, should_quantize
+    from repro.engine import Engine
+    from repro.engine.scheduler import init_slot_state
+    from repro.models import build_model
+    from repro.quant_runtime.qparams import QuantizedTensor
+
+    model = build_model(dataclasses.replace(
+        get_arch("granite-4.0-h-small"), attn_every=2, n_layers=n_layers))
+
+    def sds(x, dtype=None):
+        return jax.ShapeDtypeStruct(x.shape, dtype or x.dtype,
+                                    sharding=sharding)
+
+    skip = ("norm", "bias", "router", "a_log", "conv")
+
+    def fp8(path, leaf):
+        if not should_quantize(path_str(path), leaf, skip):
+            return sds(leaf)
+        scale = jax.ShapeDtypeStruct(leaf.shape[:-2] + (1, leaf.shape[-1]),
+                                     jnp.float32, sharding=sharding)
+        return QuantizedTensor(data=sds(leaf, jnp.float8_e4m3fn),
+                               scale=scale, fmt="fp8_e4m3",
+                               granularity="channel", block_size=128,
+                               out_dtype="bfloat16")
+
+    params = jax.tree_util.tree_map_with_path(
+        fp8, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    eng = Engine(model, params, slots=24, cache_len=512, num_blocks=768,
+                 paged=True, chunk_size=256, block_size=16, k_steps=2)
+    cache = jax.tree.map(sds, jax.eval_shape(
+        lambda: model.init_paged_cache(24, 512, block_size=16,
+                                       num_blocks=768)))
+    state = jax.tree.map(sds, jax.eval_shape(
+        lambda: init_slot_state(24, prompt_cap=512, moe=True)))
+    key = sds(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    fn = eng.entry_points()[entry]["fn"]
+    return (fn.lower(params, state, cache, key).compile(),
+            cache["stack"]["L0"]["h"].shape[1:])
+
+
+@pytest.mark.parametrize("entry", ["_dispatch", "_dispatch_chunk"])
+def test_hybrid_dispatch_updates_ssm_state_in_place(one_chip, entry):
+    """The decode and chunk dispatches update the per-slot Mamba-2 state in
+    place through the layer scan: no instruction copies a buffer of a
+    layer's state or of the stacked state (a slice out of the scan's
+    inputs written back through its outputs, a per-slot select of the whole
+    state, or a relayout for the chunk's SSD scan each shows here as such a
+    copy), and the temporaries grow by less than the state of the Mamba-2
+    layers added (four periods against eight)."""
+    import re
+    compiled = {n: _hybrid_dispatch_compiled(one_chip, entry, n)
+                for n in (8, 16)}
+    (c2, h), (c4, _) = compiled[8], compiled[16]
+    shape = re.compile(r"f32\[(\d+,)?%s\]" % ",".join(map(str, h)))
+    op = re.compile(r"\s(copy|copy-start|conditional)\(")
+    # an async copy into another memory space (``S(1)``) is a prefetch,
+    # not a copy in HBM
+    bad = [ln.strip()[:160] for ln in c2.as_text().splitlines()
+           if shape.search(ln) and op.search(ln) and "S(1)" not in ln]
+    assert not bad, bad
+    # eight layers more hold four Mamba-2 layers' float32 state more: a
+    # copy of the stacked state would grow the temporaries by all of it
+    added = 4 * 4 * math.prod(h)
+    grow = (c4.memory_analysis().temp_size_in_bytes
+            - c2.memory_analysis().temp_size_in_bytes)
+    assert grow < added, (grow, added)
+
+
+@pytest.mark.parametrize("tokens", [24, 24 * 256], ids=["decode", "chunk"])
+def test_moe_row_tile_is_the_grouped_matmul_tile(one_chip, tokens):
+    """``moe.row_tile``, which the expert layer's ``moe_rows`` counter
+    counts padding by, is the row tile of the grouped matmul that the TPU
+    compiles ``jax.lax.ragged_dot`` to, at the Granite 4.0-H cell's token
+    counts (24 slots decoding; 24 slots prefilling a 256-token chunk).  The
+    kernel's metadata lists one entry per tile it may visit: the ``M /
+    tile`` row tiles plus ``groups - 1`` that two groups share."""
+    import re
+    from repro.configs import get_arch
+    from repro.models import moe as MOE
+    from repro.quant_runtime.qparams import QuantizedTensor
+
+    cfg = get_arch("granite-4.0-h-small")
+    H = cfg.resolved_experts_held
+
+    def sds(x, dtype=None):
+        return jax.ShapeDtypeStruct(x.shape, dtype or x.dtype,
+                                    sharding=one_chip)
+
+    def fp8(path, leaf):
+        if "router" in jax.tree_util.keystr(path) or leaf.ndim < 3:
+            return sds(leaf)
+        scale = jax.ShapeDtypeStruct(leaf.shape[:-2] + (1, leaf.shape[-1]),
+                                     jnp.float32, sharding=one_chip)
+        return QuantizedTensor(data=sds(leaf, jnp.float8_e4m3fn),
+                               scale=scale, fmt="fp8_e4m3",
+                               granularity="channel", block_size=128,
+                               out_dtype="bfloat16")
+
+    p = jax.tree_util.tree_map_with_path(fp8, jax.eval_shape(
+        lambda: MOE.init_moe(jax.random.PRNGKey(0), cfg, jnp.bfloat16)))
+    x = jax.ShapeDtypeStruct((24, tokens // 24, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+    text = jax.jit(lambda p, x: MOE.apply_moe_dropless(p, x, cfg)).lower(
+        p, x).compile().as_text()
+    meta = [ln for ln in text.splitlines() if "ragged-dot-metadata = (" in ln]
+    assert meta, "ragged_dot did not compile to the grouped-matmul kernel"
+    offsets, tiles = (int(n) for n in
+                      re.findall(r"s32\[(\d+)\]", meta[0])[:2])
+    assert offsets == H + 1
+    M = min(tokens, MOE.TOKEN_BLOCK) * min(cfg.top_k, H)
+    assert M % (tiles - (H - 1)) == 0
+    assert MOE.row_tile(M) == M // (tiles - (H - 1))
