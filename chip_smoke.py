@@ -16,6 +16,7 @@ from a seed; only the depth is cut, to ``DEPTH`` layers on one chip.
 
 One chip:
   kernels   each Pallas kernel, compiled, against its ref at glm4 widths
+            (the paged attention kernel at the decode cell's shapes)
   quantize  seeded base tree, post = base + seeded dW, then
             ``repro.quantize.quantize(method="daq", metric="sign",
             fmt="fp8_e4m3", granularity="channel", mode="storage")``
@@ -163,6 +164,31 @@ def phase_kernels(K: int = 4096, N: int = 13696) -> None:
         f"stats max rel err {c_err:.2e}, sign-match diff {n_err:.2e} of "
         f"elements")
     check(c_err <= 1e-4 and n_err <= 1e-4, "scale_search disagrees with ref")
+
+    # paged_attention: 64 slots of 2 KV heads x 16 query heads, tables of
+    # 48 blocks of 16 (the decode cell's), lengths 0..767 and a verify
+    # chunk of 4; the kernel's online softmax against the gather's plain
+    # one, both with bf16 dot inputs and f32 accumulation (bf16 output)
+    from repro.kernels.paged_attention.ops import paged_attention_kernel
+    from repro.kernels.paged_attention.ref import paged_attention_ref
+    B, MB, NB, hd = 64, 48, 3072, 128
+    ks = jax.random.split(jax.random.PRNGKey(11), 5)
+    pool = [jax.random.normal(k, (2, NB + 1, 16, 2, hd), jnp.bfloat16)
+            for k in ks[:2]]
+    start = jnp.asarray(np.random.RandomState(0).randint(0, MB * 16 - 4, B),
+                        jnp.int32)
+    tbl = jax.random.permutation(ks[2], NB)[:B * MB].reshape(B, MB)
+    tbl = jnp.where(jnp.arange(MB)[None] * 16 < start[:, None] + 4, tbl, -1)
+    for C in (1, 4):
+        q = jax.random.normal(ks[3], (B, C, 32, hd), jnp.bfloat16)
+        kv = jax.random.normal(ks[4], (2, B, C, 2, hd), jnp.bfloat16)
+        args = (q, kv[0], kv[1], *pool, jnp.int32(1), tbl, start)
+        a = jax.jit(paged_attention_kernel)(*args).astype(jnp.float32)
+        r = jax.jit(paged_attention_ref)(*args).astype(jnp.float32)
+        a_err = float(jnp.max(jnp.abs(a - r)))
+        log(f"[kernels] paged_attention [{B},{C},32,{hd}] over {MB} blocks "
+            f"of 16: max abs err {a_err:.2e}")
+        check(a_err <= 2e-2, "paged_attention disagrees with ref")
 
 
 def make_pair(model, seed: int, mesh=None):

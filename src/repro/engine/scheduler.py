@@ -35,7 +35,7 @@ import jax.numpy as jnp
 
 from repro.engine.paged import BSTATE_KEYS, release_slots
 from repro.engine.sampler import SamplingParams, sample
-from repro.models.lm import Model
+from repro.models.lm import Model, decode_rows_read
 from repro.telemetry.counters import bump, init_counters
 
 
@@ -206,6 +206,8 @@ def make_decode_dispatch(model: Model, sp: SamplingParams, k_steps: int,
             # ---- decode sub-step (slots in decode phase) ----------------
             if paged:   # allocator deltas around the step count pops/CoW
                 nf0, ref0 = cache["n_free"], cache["ref"]
+                ctr = bump(ctr, kv_rows_read=decode_rows_read(cache,
+                                                              model.cfg))
                 # idle and prefilling slots keep their per-slot state
                 logits, new_cache = step_fn(params, st["cur"], cache,
                                             st["active"])

@@ -1,12 +1,17 @@
 """Pallas TPU kernels, each a kernel / ops / ref triad:
 
-  scale_search -- fused DAQ candidate sweep (the paper's Alg. 1 hot-spot)
-  fp8_matmul   -- fused block-dequant matmul
-  fp8_quant    -- one-pass block absmax + E4M3 cast
+  scale_search    -- fused DAQ candidate sweep (the paper's Alg. 1 hot-spot)
+  fp8_matmul      -- fused block-dequant matmul
+  fp8_quant       -- one-pass block absmax + E4M3 cast
+  paged_attention -- attention against the paged K/V pools through the
+                     block table, up to each slot's length
 
-Serving does not call them: ``quant_runtime.qlinear`` runs quantized weights
-as an XLA dequantize + matmul.  ``tests/test_tpu_compile.py`` compiles each
-kernel for a v5e chip at glm4-9b widths.
+Serving runs ``paged_attention`` on a TPU: the engine's paged decode and
+speculative-verify attention (``models.attention.paged_decode_attention``,
+``paged_verify_attention``) call it where the pools' layout suits it.  It
+does not call the other three: ``quant_runtime.qlinear`` runs quantized
+weights as an XLA dequantize + matmul.  ``tests/test_tpu_compile.py``
+compiles each kernel for a v5e chip at glm4-9b widths.
 """
 from __future__ import annotations
 

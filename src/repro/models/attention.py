@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels.paged_attention import ops as PA
 from repro.models.common import apply_rope, dense_init, rope_cos_sin, split
 from repro.quant_runtime import qlinear
 
@@ -260,18 +261,17 @@ def paged_verify_attention(q, pk, pv, layer, k_new, v_new, tbl, start,
 
     The speculative decoder (engine/spec.py) accepts a drafted token only
     when the verifier's logits agree with what the non-speculative engine
-    would have computed at the same position — so unlike
-    :func:`paged_prefill_attention` (flash tiles, online softmax), this
-    path assembles the same kv buffer a decode step would see (block-table
-    gather in slot order, fresh rows overlaid in pool dtype, the exact
-    length/ring masks) and runs the plain-softmax decode math batched over
-    the ``C`` chunk rows, keeping greedy speculative serving token-exact
-    against per-token decode.  Memory is O(C * cap) per head group (ring:
-    O(C * W) buffers) — the chunk is ``n_spec + 1`` rows, so this stays
-    small; a production flash verify would trade the bitwise-decode mirror
-    for tile math.
+    would have computed at the same position.  On a TPU the linear layout
+    runs the paged attention kernel that decode runs, whose row ``j`` is
+    its one-row call at ``start + j`` bit for bit.  Elsewhere, and for
+    rings, this path assembles the same kv buffer a decode step would see
+    (block-table gather in slot order, fresh rows overlaid in pool dtype,
+    the exact length/ring masks) and runs the plain-softmax decode math
+    batched over the ``C`` chunk rows.  Either way greedy speculative
+    serving stays token-exact against per-token decode.  The gather's
+    memory is O(C * cap) per head group (ring: O(C * W) buffers); the
+    chunk is ``n_spec + 1`` rows, so this stays small.
     """
-    from repro.engine.paged import gather_blocks
     B, C = k_new.shape[:2]
     bs = pk.shape[2]
     MB = tbl.shape[1]
@@ -312,52 +312,42 @@ def paged_verify_attention(q, pk, pv, layer, k_new, v_new, tbl, start,
         out = jnp.einsum("bkgqc,bqckh->bqkgh", p.astype(vbuf.dtype), vbuf,
                          preferred_element_type=jnp.float32)
         return out.reshape(B, C, H, hd).astype(q.dtype)
-    # linear layout: one shared buffer — a row's own/later positions are
-    # overlaid fresh, and the per-row length mask hides rows past it (the
-    # exact mask decode_attention applies)
-    pos = jnp.broadcast_to(jnp.arange(cap)[None], (B, cap))
-    gk = gather_blocks(pk, tbl, layer)
-    gv = gather_blocks(pv, tbl, layer)
-    rel = pos - start[:, None]
-    fresh = (rel >= 0) & (rel < C)
-    idx = jnp.clip(rel, 0, C - 1)[..., None, None]
-    fm = fresh[..., None, None]
-    kbuf = jnp.where(fm, jnp.take_along_axis(k_new, idx, axis=1), gk)
-    vbuf = jnp.where(fm, jnp.take_along_axis(v_new, idx, axis=1), gv)
-    if kbuf.dtype.itemsize == 1:                            # fp8 cache
-        kbuf = kbuf.astype(jnp.bfloat16)
-        vbuf = vbuf.astype(jnp.bfloat16)
-    s = _tile_scores(qg, kbuf, softcap)                     # [B,Kv,G,C,cap]
-    qpos = start[:, None] + jnp.arange(C)[None]             # [B, C]
-    mask = pos[:, None, :] < (qpos + 1)[..., None]          # [B, C, cap]
-    if sliding_window > 0:                                  # non-ring SWA
-        mask = mask & (pos[:, None, :] > (qpos[..., None] - sliding_window))
-    s = jnp.where(mask[:, None, None], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bkgqc,bckh->bqkgh", p.astype(vbuf.dtype), vbuf,
-                     preferred_element_type=jnp.float32)
-    return out.reshape(B, C, H, hd).astype(q.dtype)
+    # linear layout: the kernel on a TPU, else one shared buffer gathered
+    # through the table, a row's own/later positions overlaid fresh, under
+    # the per-row length mask (the exact mask decode_attention applies)
+    return PA.paged_attention(q, k_new, v_new, pk, pv, layer, tbl, start,
+                              window=sliding_window, softcap=softcap)
 
 
 @jax.named_scope("attention")
-def paged_decode_attention(q, pk, pv, layer, tbl, lengths, *,
+def paged_decode_attention(q, pk, pv, layer, tbl, lengths, k_new, v_new, *,
                            sliding_window=0, softcap=0.0) -> jnp.ndarray:
     """Decode attention against layer ``layer`` of the stacked K/V pools.
 
-    q [B,1,H,hd]; pools [n, NB+1, bs, Kv, hd]; ``tbl`` [B, MB] block table.
-    The gather reproduces the dense cache layout (linear positions, or ring
-    positions when the table spans exactly the sliding window) so this is
-    value-identical to :func:`decode_attention` on a contiguous cache.
+    q [B,1,H,hd]; pools [n, NB+1, bs, Kv, hd], holding the step's new row
+    ``k_new``/``v_new`` [B,1,Kv,hd] (pool dtype) at ``lengths``; ``tbl``
+    [B, MB] block table.  On a TPU the kernel reads each slot's blocks up
+    to its length and takes the new row as its fresh row
+    (:mod:`repro.kernels.paged_attention`).  Elsewhere, and for the layouts
+    the kernel does not serve, the gather reproduces the dense cache
+    layout (linear positions, or ring positions when the table spans
+    exactly the sliding window), so this is value-identical to
+    :func:`decode_attention` on a contiguous cache.
     """
-    from repro.engine.paged import gather_blocks
-    gk = gather_blocks(pk, tbl, layer)
-    gv = gather_blocks(pv, tbl, layer)
-    cap = gk.shape[1]
-    if sliding_window and cap == sliding_window:   # ring layout
-        eff_len = jnp.minimum(lengths + 1, cap)
-        return decode_attention(q, gk, gv, eff_len, softcap=softcap)
-    return decode_attention(q, gk, gv, lengths + 1, window=sliding_window,
-                            softcap=softcap)
+    def gather():
+        from repro.engine.paged import gather_blocks
+        gk = gather_blocks(pk, tbl, layer)
+        gv = gather_blocks(pv, tbl, layer)
+        cap = gk.shape[1]
+        if sliding_window and cap == sliding_window:   # ring layout
+            eff_len = jnp.minimum(lengths + 1, cap)
+            return decode_attention(q, gk, gv, eff_len, softcap=softcap)
+        return decode_attention(q, gk, gv, lengths + 1,
+                                window=sliding_window, softcap=softcap)
+
+    return PA.paged_attention(q, k_new, v_new, pk, pv, layer, tbl, lengths,
+                              window=sliding_window, softcap=softcap,
+                              ref=gather)
 
 
 # ---------------------------------------------------------------------------

@@ -312,14 +312,30 @@ def cow_copy_blocks(pcache: dict, src, dst):
             **jax.lax.fori_loop(0, jnp.sum(cow), copy_one, groups)}
 
 
+def decode_rows_read(pcache: dict, cfg: ModelConfig):
+    """Pool rows (K and V alike, int32) one paged decode step's attention
+    reads over all its attention layers and slots, at the step's
+    ``pcache["lengths"]``; 0 without pools."""
+    from repro.kernels.paged_attention.ops import rows_read
+    total = jnp.zeros((), jnp.int32)
+    for g in ("prefix", "stack"):
+        for lc in pcache.get(g, {}).values():
+            if "pk" in lc:
+                total += lc["pk"].shape[0] * rows_read(
+                    pcache["lengths"], lc["pk"], pcache["tbl"],
+                    window=cfg.sliding_window)
+    return total
+
+
 def _attn_decode_paged(p, x, cache, pctx, cfg: ModelConfig):
     """Self-attn decode against the paged block pool.
 
     Mirrors :func:`_attn_decode` exactly for active slots: the new K/V lands
     at the slot's write target (``pctx["wblk"]/["woff"]``, precomputed once
-    per step — trash block for inactive slots), and attention runs over the
-    block-table gather, which reproduces the dense cache layout (linear
-    positions, or ring positions for SWA) value-for-value.  ``cache["pk"]``
+    per step — trash block for inactive slots), and attention reads the
+    slot's rows through the block table (:func:`A.paged_decode_attention`:
+    on a TPU the kernel, up to the slot's length; else the gather, which
+    reproduces the dense cache layout value-for-value).  ``cache["pk"]``
     / ``["pv"]`` are the whole stacked pools; the layer's rows are
     addressed at ``pctx["layer"]`` (:func:`run_stack_paged`)."""
     B = x.shape[0]
@@ -337,6 +353,7 @@ def _attn_decode_paged(p, x, cache, pctx, cfg: ModelConfig):
     pk, pv = A.write_paged_kv(cache["pk"], cache["pv"], k[:, 0], v[:, 0],
                               layer, pctx["wblk"], pctx["woff"])
     out = A.paged_decode_attention(q, pk, pv, layer, pctx["tbl"], lengths,
+                                   k.astype(pk.dtype), v.astype(pv.dtype),
                                    sliding_window=cfg.sliding_window,
                                    softcap=cfg.attn_logit_softcap)
     from repro.quant_runtime import qlinear

@@ -52,6 +52,11 @@ COUNTER_KEYS = (
     "chunks_completed",  # prompts that finished in-scan prefill
     "blocked_retries",   # spec slots masked out of a round (CoW pop
                          # failed, pool dry) — they retry next round
+    "kv_rows_read",      # pool K/V rows decode attention read, summed
+                         # over attention layers and decode steps
+                         # (lm.decode_rows_read: whole blocks up to each
+                         # slot's length on the kernel path, the whole
+                         # table on the gather path)
 )
 
 # Only for models with MoE layers (``init_counters(moe=True)``): bumped in

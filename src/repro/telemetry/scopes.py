@@ -16,7 +16,10 @@ The model and the engine wrap their device work in ``jax.named_scope``:
 * ``prefill_chunk`` — the in-scan chunked-prefill piece
                       (``scheduler.chunk_prefill_substep``);
 * ``keep_rows``     — the per-slot cache merge (``scheduler._keep_rows``);
-* ``sample``        — token sampling (``engine.sampler.sample``).
+* ``sample``        — token sampling (``engine.sampler.sample``);
+* ``paged_attn``    — the paged attention kernel
+                      (``kernels.paged_attention``), nested in
+                      ``attention``: decode and verify on a TPU.
 
 A scope is metadata: it lands in each HLO instruction's ``op_name`` and
 leaves the compiled code as it was.  A device trace carries no scope (its
@@ -30,7 +33,7 @@ from __future__ import annotations
 from repro.analysis.hlo import HloModule
 
 SCOPES = ("qlinear", "attention", "mamba", "moe", "prefill_chunk",
-          "keep_rows", "sample")
+          "keep_rows", "sample", "paged_attn")
 
 _OP_NAME = r'op_name="([^"]*)"'
 

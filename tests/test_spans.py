@@ -140,8 +140,11 @@ def test_scope_path_reads_op_names():
     assert scope_path("jit(f)/while/body/add") == ""
     assert scope_path("jit(f)/prefill_chunk/moe/qlinear/dot") \
         == "prefill_chunk/moe/qlinear"
+    assert scope_path("jit(f)/while/body/attention/cond/branch_0_fun/"
+                      "paged_attn/cond/branch_0_fun/paged_attention/"
+                      "pallas_call") == "attention/paged_attn"
     assert SCOPES == ("qlinear", "attention", "mamba", "moe",
-                      "prefill_chunk", "keep_rows", "sample")
+                      "prefill_chunk", "keep_rows", "sample", "paged_attn")
 
 
 def test_hlo_texts_needs_a_served_prefix_engine(tiny):

@@ -274,3 +274,48 @@ def test_serve_metrics_free_on_hot_path(tmp_path):
             == len(e_on._hold_blocks))
     assert c2["prefix_hit_tokens"] == st2["prefix_hits"]
     assert c2["prefix_hit_tokens"] > c["prefix_hit_tokens"]  # warm hits
+
+
+def test_kv_rows_read_counts_what_decode_attention_reads(monkeypatch):
+    """``kv_rows_read``: the whole table (``MB * bs`` a slot) every decode
+    step on the gather path; on the kernel path (run here in interpret
+    mode) each slot's blocks up to its length — for one slot decoding
+    from a prompt of ``P`` rows, ``ceil((P + i) / bs) * bs`` at step
+    ``i`` — in every attention layer.  Both paths serve the same tokens."""
+    import dataclasses
+    import jax.numpy as jnp
+    from repro.kernels.paged_attention import ops
+    from repro.models.lm import decode_rows_read
+
+    cfg = dataclasses.replace(reduced(get_arch("glm4-9b")), head_dim=128)
+    model = build_model(cfg)
+    params = model.init(KEY)
+    spec = LanguageSpec(vocab=cfg.vocab_size)
+    P, gen, bs, K = 21, 7, 16, 4
+    prompt = sample_batch(jax.random.PRNGKey(3), spec, 1, P)[0]
+
+    def serve():
+        eng = Engine(model, params, slots=1, cache_len=64, k_steps=K,
+                     paged=True, block_size=bs)
+        return eng.serve([prompt], gen_tokens=gen, return_stats=True)
+
+    outs, st = serve()
+    MB, steps = 64 // bs, st["decode_steps"]
+    assert st["counters"]["kv_rows_read"] == steps * MB * bs * cfg.n_layers
+
+    monkeypatch.setattr(ops, "KERNEL_PLATFORMS", ("tpu", "cpu"))
+    outs_k, st_k = serve()
+    assert outs_k == outs
+    want = sum(-(-(P + i) // bs) * bs for i in range(steps)) * cfg.n_layers
+    assert st_k["decode_steps"] == steps
+    assert st_k["counters"]["kv_rows_read"] == want
+
+    # the step's count from a cache: rounded lengths, or the whole table
+    pk = jnp.zeros((cfg.n_layers, 9, bs, cfg.n_kv_heads, 128), jnp.bfloat16)
+    cache = {"lengths": jnp.asarray([0, 1, 16, 23], jnp.int32),
+             "tbl": jnp.zeros((4, MB), jnp.int32),
+             "stack": {"L0": {"pk": pk, "pv": pk}}}
+    assert int(decode_rows_read(cache, cfg)) == (0 + 16 + 16 + 32) * \
+        cfg.n_layers
+    monkeypatch.setattr(ops, "KERNEL_PLATFORMS", ("tpu",))
+    assert int(decode_rows_read(cache, cfg)) == 4 * MB * bs * cfg.n_layers

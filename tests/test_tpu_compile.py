@@ -106,6 +106,32 @@ def test_fp8_round_trip_survives_v5e_fusion(one_chip):
     assert not inner, inner[:2]
 
 
+@pytest.mark.parametrize("B,Kv,G,MB", [(64, 2, 16, 48), (24, 8, 4, 144)],
+                         ids=["glm4", "granite"])
+def test_paged_attention_kernel_compiles_for_v5e(one_chip, B, Kv, G, MB):
+    """The paged attention kernel lowers through Mosaic at the serving
+    cells' widths (glm4-9b: 2 KV heads of 16 query heads; Granite 4.0-H:
+    8 of 4), one decode row and a verify chunk of 4, against the cells'
+    stacked pools, which it reads in place: no copy of a pool."""
+    import re
+    from repro.kernels.paged_attention.ops import paged_attention_kernel
+    n, NB, hd = 4, 3072, 128
+    for C in (1, 4):
+        specs = [((B, C, Kv * G, hd), jnp.bfloat16),
+                 ((B, C, Kv, hd), jnp.bfloat16),
+                 ((B, C, Kv, hd), jnp.bfloat16),
+                 ((n, NB + 1, 16, Kv, hd), jnp.bfloat16),
+                 ((n, NB + 1, 16, Kv, hd), jnp.bfloat16),
+                 ((), jnp.int32), ((B, MB), jnp.int32), ((B,), jnp.int32)]
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in specs]
+        text = jax.jit(paged_attention_kernel).lower(*args).compile() \
+            .as_text()
+        assert "tpu_custom_call" in text
+        pool = re.compile(rf"bf16\[{n},{NB + 1},16,{Kv},{hd}\]\S* copy\(")
+        assert not pool.search(text)
+
+
 def _paged_dispatch_compiled(sharding, entry, n_layers):
     """``entry`` of the engine that serves glm4-9b from fp8 (channel
     scales) through the paged, prefix-cached, chunked path: 64 slots,
@@ -175,6 +201,26 @@ def test_paged_dispatch_updates_pools_in_place(one_chip, entry):
     grow = (c4.memory_analysis().temp_size_in_bytes
             - c2.memory_analysis().temp_size_in_bytes)
     assert grow < layer_kv, (grow, layer_kv)
+
+
+def test_paged_decode_reads_the_pools_through_the_kernel(one_chip):
+    """glm4-9b's decode steps attend through the paged attention kernel: no
+    instruction builds a slot's whole table (``[64, 48, 16, 2, 128]`` or
+    ``[64, 768, 2, 128]``) in the decode dispatch, and in the chunk
+    dispatch only its prefill chunk (flash tiles over the table) still
+    gathers the table's blocks."""
+    import re
+    from repro.telemetry.scopes import scope_path
+    table = re.compile(r"= bf16\[64,(48,16|768),2,128\]\S* (gather|fusion)\(")
+    blocks = re.compile(r"= bf16\[64,48,16,2,128\]\S* gather\(")
+    for entry in ("_dispatch", "_dispatch_chunk"):
+        text = _paged_dispatch_compiled(one_chip, entry, 2)[0].as_text()
+        assert "tpu_custom_call" in text, entry
+        if entry == "_dispatch":
+            assert not table.search(text), table.search(text).group(0)
+        built = [scope_path(re.search(r'op_name="([^"]*)"', ln).group(1))
+                 for ln in text.splitlines() if blocks.search(ln)]
+        assert all("prefill_chunk" in p for p in built), (entry, built)
 
 
 def _hybrid_dispatch_compiled(sharding, entry, n_layers):
