@@ -72,7 +72,7 @@ def run(arch: str = "glm4-9b", requests: int = 8, batch: int = 4,
     cache_len = prompt_len + gen + n_spec + 8
 
     peng = Engine(model, params, slots=batch, cache_len=cache_len,
-                  k_steps=k_steps, paged=True, block_size=block_size)
+                  k_steps=k_steps, block_size=block_size)
     engines = {"paged": lambda: peng.serve(prompts, gen_tokens=gen,
                                            return_stats=True)}
     drafts = {}
@@ -84,7 +84,7 @@ def run(arch: str = "glm4-9b", requests: int = 8, batch: int = 4,
         drafts[method] = rep
         drafts_trees[method] = dtree
         eng = Engine(model, params, slots=batch, cache_len=cache_len,
-                     k_steps=k_steps, paged=True, block_size=block_size,
+                     k_steps=k_steps, block_size=block_size,
                      n_spec=n_spec, draft_params=dtree, metrics=reg)
         engines[f"spec-{method}"] = (
             lambda e=eng: e.serve(prompts, gen_tokens=gen,
@@ -165,7 +165,7 @@ def _run_shared(model, params, drafts_trees: dict, spec, *, batch: int,
         kw = dict(n_spec=n_spec, draft_params=dtree) if dtree is not None \
             else {}
         return Engine(model, params, slots=batch, cache_len=cache_len,
-                      k_steps=k_steps, paged=True, block_size=block_size,
+                      k_steps=k_steps, block_size=block_size,
                       chunk_size=chunk, prefix_cache=True, metrics=metrics,
                       **kw)
 

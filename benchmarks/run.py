@@ -64,9 +64,14 @@ def decode_throughput():
     cfg = reduced(get_arch("glm4-9b"))
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
-    cache = model.init_cache(8, 256)
-    cache["lengths"] = jnp.full((8,), 128, jnp.int32)
-    state = init_slot_state(8)
+    # 8 slots, each holding its whole 256-row table of 16-row blocks
+    nb = 8 * 16
+    cache = model.init_paged_cache(8, 256, block_size=16, num_blocks=nb)
+    cache.update(tbl=jnp.arange(nb, dtype=jnp.int32).reshape(8, 16),
+                 ref=jnp.ones((nb,), jnp.int32), n_free=jnp.int32(0),
+                 slot_active=jnp.ones((8,), bool),
+                 lengths=jnp.full((8,), 128, jnp.int32))
+    state = init_slot_state(8, prompt_cap=256)
     state["active"] = jnp.ones((8,), bool)
     state["remaining"] = jnp.full((8,), 10**6, jnp.int32)
     K = 8
@@ -75,12 +80,6 @@ def decode_throughput():
     us = time_call(lambda: dispatch(params, state, cache, key)[2])
     emit(f"serve.decode_glm4smoke_b8_cache256_k{K}", us,
          f"tok_per_s={8*K/(us/1e6):.0f}")
-
-
-def serve_bench():
-    """Legacy host loop vs device-resident engine (BENCH_serve.json)."""
-    from benchmarks import bench_serve
-    bench_serve.main([])
 
 
 def spec_bench():
@@ -105,7 +104,6 @@ BENCHES = {
     "kernels": kernels,
     "train": train_throughput,
     "decode": decode_throughput,
-    "serve": serve_bench,
     "spec": spec_bench,
     "roofline": roofline,
 }

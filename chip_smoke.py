@@ -21,7 +21,7 @@ One chip:
             ``repro.quantize.quantize(method="daq", metric="sign",
             fmt="fp8_e4m3", granularity="channel", mode="storage")``
   serve     8 requests of a few hundred prompt tokens (four share a
-            prefix) through ``Engine(paged=True, prefix_cache=True)``,
+            prefix) through ``Engine(prefix_cache=True)``,
             greedy, 32 generated tokens each
   logits    for two requests: prefill + decode through the cache against
             one prefill over prompt + generated tokens
@@ -254,7 +254,7 @@ def phase_serve(model, qparams, prompts, mesh=None):
     cache); returns the first run's outputs."""
     V = model.cfg.vocab_size
     eng = Engine(model, qparams, slots=SLOTS, cache_len=CACHE_LEN,
-                 k_steps=8, paged=True, block_size=BLOCK, prefix_cache=True,
+                 k_steps=8, block_size=BLOCK, prefix_cache=True,
                  mesh=mesh)
     runs = []
     for label in ("cold", "warm"):

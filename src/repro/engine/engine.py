@@ -1,88 +1,65 @@
-"""Device-resident serving engine: continuous batching with on-device
-scheduling, batched multi-slot prefill, real sampling, opt-in sharding.
+"""Device-resident serving engine: continuous batching over a paged KV
+pool, with prompt prefill streamed in chunks through the decode dispatch.
 
-The host keeps only what it must (the request queue and a mirror of each
-slot's budget, maintained from the dispatch results it already fetched —
-no extra syncs); everything per-token lives on device:
+The host keeps only what it must (the request queue, each slot's budget
+and its worst-case block reservation, maintained from the dispatch results
+it already fetched — no extra syncs); everything per-token lives on device:
 
-* **decode** — one jitted dispatch runs ``k_steps`` decode steps under
-  ``lax.scan`` (scheduler.make_decode_dispatch); the host syncs once per
-  dispatch to drain the emitted-token grid.
-* **prefill** — all free slots' pending prompts go through batched
-  ``model.prefill`` calls (a single right-padded call when the model
-  supports it, else one call per distinct prompt length) and their cache
-  rows are scattered into the live cache in one jitted update.  When the
-  whole pool is being (re)filled in one equal-length batch the returned
-  cache simply *replaces* the live one — the scatter-free path.
-* **sampling** — greedy / temperature / top-k via engine.sampler with a
-  per-step threaded PRNG key (the old host loop's ``greedy=False`` was
-  silently argmax).
+* **one dispatch** — a jitted dispatch runs ``k_steps`` scan steps
+  (scheduler.make_decode_dispatch); the host syncs once per dispatch to
+  drain the emitted-token grid.  Each step runs one decode step for the
+  decoding slots and one ``chunk_size``-token prefill piece for the slots
+  still prefilling, so long prompts stream instead of stalling decode.
+  The step a prompt's last chunk lands, its first token is sampled from
+  the chunk's logits.  Chunk pieces are bit-exact vs one-shot prefill
+  (same flash tile math with offset masks, SSD state threaded on the
+  ``ssm_chunk`` grid); MoE layers serve dropless on every path
+  (``moe.apply_moe_dropless``).  ``chunk_size`` 0 derives the piece in
+  one place (:func:`default_chunk_size`).
+* **paged KV cache** — a global block pool with per-slot block tables and
+  a device-resident free-list (engine/paged.py): memory tracks live
+  tokens instead of ``slots * cache_len``.  Admission maps a slot's blocks
+  and arms its prompt buffer (no model forward); it reserves each
+  request's lifetime worst case against the pool (FIFO; requests wait
+  when the head doesn't fit), and blocks recycle inside the K-step scan
+  as slots drain.  SSM state has no sequence axis and stays per slot.
+* **prefix caching** — ``prefix_cache=True`` shares full prompt blocks
+  across requests: a host-side chained-hash index (engine/prefix.py) maps
+  matched leading blocks into the new slot's table with ``refcount += 1``
+  and only the unmatched tail is prefilled; released blocks stay cached
+  (the index holds one reference) until LRU eviction makes room.  A
+  partially-matched last block is mapped shared and copy-on-write
+  protected: the first decode write pops a private copy.  The reservation
+  ledger counts only non-shared blocks, so a warm cache admits more
+  concurrency from the same pool.  Sharing is content-sound for causal
+  attention stacks without position-keyed ring caches or recurrent state;
+  SWA / SSM / hybrid configs run with matching disabled.
+* **self-speculative decoding** — ``n_spec > 0`` (pass a quantized
+  ``draft_params`` tree) swaps each dispatch step for a speculative round:
+  the quantized tree drafts up to ``n_spec`` tokens, one full-precision
+  multi-token verify forward accepts a prefix (greedy match, or lossless
+  rejection sampling for temperature/top-k/top-p), and rejected positions
+  roll back per slot (engine/spec.py).  Chunk pieces, CoW prefix writes
+  and speculative rounds are orthogonal phases of one scan step.  The
+  depth is dynamic by default (``spec_dynamic``): a host-side AIMD
+  controller walks it 1..n_spec from the acceptance telemetry — the depth
+  is a traced operand, so moves never recompile.  Greedy outputs stay
+  token-exact vs the non-speculative engine for any draft.
+* **sampling** — greedy / temperature / top-k / top-p via engine.sampler
+  with a per-step threaded PRNG key.
 * **sharding** — pass ``mesh=`` to place params with
   ``launch.sharding.params_shardings`` (quantized ``wq/data`` / ``wq/scale``
-  leaves inherit the dense weight's layout by tree path) and the decode
-  cache with ``cache_shardings``; all jitted steps then run GSPMD-partitioned.
-* **paged KV cache** — ``paged=True`` swaps the per-slot contiguous cache
-  for a global block pool with per-slot block tables and a device-resident
-  free-list (engine/paged.py): memory tracks live tokens instead of
-  ``slots * cache_len``, admission reserves each request's lifetime worst
-  case against the pool (FIFO; requests wait when the head doesn't fit),
-  and blocks recycle inside the K-step scan as slots drain.  Greedy
-  outputs stay token-exact vs the contiguous cache.
-* **chunked prefill** — ``chunk_size > 0`` (paged only) moves prompt
-  prefill *into* the decode dispatch: admission just maps blocks and arms
-  the slot's prompt buffer, and each scan step prefills one
-  ``chunk_size``-token piece alongside the other slots' decode step
-  (scheduler.py), so long prompts stream instead of stalling decode.
-  Chunk pieces are bit-exact vs one-shot prefill (same flash tile math
-  with offset masks, SSD state threaded on the ``ssm_chunk`` grid, same-
-  dtype cache reads); MoE layers serve dropless on every path
-  (``moe.apply_moe_dropless``), so a token's expert output depends on its
-  own hidden state alone.
-* **prefix caching** — ``prefix_cache=True`` (implies chunked prefill)
-  shares full prompt blocks across requests: a host-side chained-hash
-  index (engine/prefix.py) maps matched leading blocks into the new slot's
-  table with ``refcount += 1`` and only the unmatched tail is prefilled;
-  released blocks stay cached (the index holds one reference) until LRU
-  eviction makes room.  A partially-matched last block is mapped shared
-  and copy-on-write protected: the first decode write pops a private copy.
-  The reservation ledger counts only non-shared blocks, so a warm cache
-  admits more concurrency from the same pool.  Sharing is content-sound
-  for causal attention stacks without position-keyed ring caches or
-  recurrent state; SWA / SSM / hybrid configs run with matching disabled
-  (the chunked machinery still applies, outputs stay exact, nothing is
-  shared).
+  leaves inherit the dense weight's layout by tree path) and the pool with
+  ``cache_shardings``; every jitted entry then runs GSPMD-partitioned.
 
-* **self-speculative decoding** — ``n_spec > 0`` (paged only; pass a
-  quantized ``draft_params`` tree) swaps each dispatch step for a
-  speculative round: the quantized tree drafts up to ``n_spec`` tokens,
-  one full-precision multi-token verify forward accepts a prefix (greedy
-  match, or lossless rejection sampling for temperature/top-k/top-p), and
-  rejected positions roll back per slot (engine/spec.py).  Speculation
-  **composes** with chunked prefill and prefix caching: chunk pieces, CoW
-  prefix writes and speculative rounds are orthogonal phases of one scan
-  step sharing a spec-aware span allocation (a draft write into a shared
-  prompt block CoWs exactly like a decode write), so shared-prefix
-  workloads can measure draft fidelity too.  The speculation depth is
-  dynamic by default (``spec_dynamic``): a host-side AIMD controller
-  walks it 1..n_spec from the acceptance telemetry — the depth is a
-  traced operand, so moves never recompile.  Greedy outputs stay
-  token-exact vs the non-speculative engine for any draft and any depth
-  trajectory; the draft acceptance rate (stats ``draft_accepted /
-  draft_tokens``) doubles as a data-free behavioral-fidelity metric for
-  the quantization method.
-
-Right-padded prefill is only exact when a row's hidden states cannot depend
-on positions after it or on other tokens' presence: pure causal attention
-qualifies; SWA ring caches (slot = position % window would index pad
-positions) and Mamba state accumulation do not, and MoE stacks are left
-out too (their dropless layer would allow it) — those configs fall back
-to equal-length grouping automatically.
+Greedy outputs are token-exact vs ``engine.legacy.serve_host_loop``, the
+contiguous-cache host loop the parity tests compare against.
 """
 from __future__ import annotations
 
 import time
 from collections import deque
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 
 import jax
 import jax.numpy as jnp
@@ -90,7 +67,7 @@ import numpy as np
 
 from repro.engine import paged as P
 from repro.engine.prefix import PrefixIndex
-from repro.engine.sampler import SamplingParams, sample
+from repro.engine.sampler import SamplingParams
 from repro.engine.scheduler import init_slot_state, make_decode_dispatch
 from repro.models.lm import Model, has_moe
 from repro.telemetry.counters import (COUNTER_KEYS, bump, counter_totals,
@@ -108,29 +85,37 @@ def _abstract(x):
                                 sharding=x.sharding if x.committed else None)
 
 
+def default_chunk_size(mcfg, block_size: int) -> int:
+    """Prefill tokens per scan step when ``chunk_size`` is 0: the SSD chunk
+    for stacks with SSM state (chunked prefill is bit-exact only on that
+    grid), else four blocks, at most the sliding window (a chunk wider than
+    the ring would write two of its rows to one ring row)."""
+    if mcfg.family in ("ssm", "hybrid"):
+        return mcfg.ssm_chunk
+    chunk = 4 * block_size
+    return min(chunk, mcfg.sliding_window) if mcfg.sliding_window else chunk
+
+
 @dataclass(frozen=True)
 class EngineConfig:
     slots: int = 2          # size of the continuous-batching pool
-    cache_len: int = 256    # decode cache capacity per slot
-    k_steps: int = 8        # decode steps per dispatch (1 host sync each)
+    cache_len: int = 256    # per-slot capacity (prompt + generated rows)
+    k_steps: int = 8        # scan steps per dispatch (1 host sync each)
     sampling: SamplingParams = field(default_factory=SamplingParams)
     seed: int = 0
-    paged: bool = False     # paged KV cache (block pool + block tables)
-    block_size: int = 16    # tokens per KV block (paged only)
+    paged: bool = True      # must be True: the paged pool is the only cache
+    block_size: int = 16    # tokens per KV block
     num_blocks: int = 0     # pool size; 0 -> slots * ceil(cap / block_size)
-                            # (capacity parity with the contiguous cache)
-    chunk_size: int = 0     # >0: chunked prefill inside the decode dispatch
-                            # (paged only; tokens per in-scan prefill piece)
-    prefix_cache: bool = False  # refcounted prompt-block sharing (paged;
-                                # implies chunked prefill)
+    chunk_size: int = 0     # prompt tokens prefilled per scan step;
+                            # 0 -> default_chunk_size
+    prefix_cache: bool = False  # refcounted prompt-block sharing
     n_spec: int = 0         # >0: self-speculative decoding — draft up to
                             # n_spec tokens per round with the quantized
                             # ``draft_params`` tree, verify with one
-                            # full-precision forward (paged only; pass
-                            # draft_params= to Engine).  Composes with
-                            # chunk_size and prefix_cache: speculation,
-                            # chunked prefill and CoW prefix writes are
-                            # orthogonal phases of one dispatch scan step
+                            # full-precision forward (pass draft_params= to
+                            # Engine).  Composes with prefix_cache:
+                            # speculation, chunked prefill and CoW prefix
+                            # writes are orthogonal phases of one scan step
     spec_dynamic: bool = True   # move the speculation depth 1..n_spec at
                                 # runtime from acceptance telemetry
                                 # (spec.DepthController); depth is a traced
@@ -155,34 +140,28 @@ class Engine:
         # enabling them changes no jitted signature and adds no host sync
         self.metrics = metrics      # MetricsRegistry | None
         self.tracer = tracer        # Tracer | None
-        if model.cfg.family in ("vlm", "encdec"):
+        mcfg = model.cfg
+        if mcfg.family in ("vlm", "encdec"):
             raise NotImplementedError(
                 "Engine drives LM-style models; vlm/encdec need modality "
                 "inputs (see examples/)")
-        if cfg.prefix_cache and not cfg.chunk_size:
-            cfg = EngineConfig(**{**cfg.__dict__,
-                                  "chunk_size": 4 * cfg.block_size})
+        if not cfg.paged:
+            raise ValueError(
+                "paged=False: the contiguous cache was removed; the engine "
+                "serves only through the paged block pool")
+        if not cfg.chunk_size:
+            cfg = replace(cfg, chunk_size=default_chunk_size(
+                mcfg, cfg.block_size))
         self.model = model
         self.cfg = cfg
         self.mesh = mesh
-        # right-padded prefill is exact only for pure-causal-attention
-        # stacks with non-ring caches (see module docstring)
-        mcfg = model.cfg
-        self._can_pad = (mcfg.family == "dense"
-                         and not mcfg.sliding_window)
         self._moe = has_moe(mcfg)   # expert-layer device counters
         self.params = self._place_params(params) if mesh is not None else params
 
         sp, K = cfg.sampling, cfg.k_steps
         if K < 1:
             raise ValueError(f"k_steps must be >= 1, got {K}")
-        if (cfg.chunk_size or cfg.prefix_cache) and not cfg.paged:
-            raise ValueError("chunk_size / prefix_cache need paged=True")
         if cfg.n_spec:
-            if not cfg.paged:
-                raise ValueError(
-                    "speculative decoding (n_spec > 0) rides the paged "
-                    "engine: pass paged=True")
             if cfg.n_spec >= K:
                 raise ValueError(
                     f"n_spec must be < k_steps (got n_spec={cfg.n_spec}, "
@@ -202,108 +181,80 @@ class Engine:
         elif draft_params is not None:
             raise ValueError("draft_params without n_spec > 0 does nothing: "
                              "set n_spec to enable speculative decoding")
-        if cfg.paged:
-            window = mcfg.sliding_window
-            cap = min(cfg.cache_len, window) if window else cfg.cache_len
-            if window and cap != window:
-                raise ValueError(
-                    f"paged SWA serving needs cache_len >= sliding_window "
-                    f"({cfg.cache_len} < {window})")
-            if window and window % cfg.block_size:
-                raise ValueError(
-                    f"block_size {cfg.block_size} must divide the sliding "
-                    f"window {window}: ring positions are block-mapped "
-                    f"(pos % window straddles the block grid otherwise)")
-            self._mb = P.blocks_for(cap, cfg.block_size)  # blocks per slot
-            self._num_blocks = cfg.num_blocks or cfg.slots * self._mb
-        if cfg.chunk_size:
-            if (mcfg.family in ("ssm", "hybrid")
-                    and cfg.chunk_size % mcfg.ssm_chunk):
-                raise ValueError(
-                    f"chunked prefill over SSM state is bit-exact only on "
-                    f"the SSD chunk grid: chunk_size {cfg.chunk_size} must "
-                    f"be a multiple of ssm_chunk {mcfg.ssm_chunk}")
-            # prompt-block sharing is content-sound only when a block's KV
-            # is a pure function of the token prefix: ring caches are
-            # position-keyed (mod window) and SSM state is recurrent, so
-            # those families run chunked but unshared
-            self._can_match = (cfg.prefix_cache
-                               and mcfg.family in ("dense", "moe")
-                               and not mcfg.sliding_window)
-            self._index = PrefixIndex(cfg.block_size)
-            self._hold_blocks: set[int] = set()   # index + pending holds
-            self._pcache = None                   # persistent cache/state
-            self._pstate = None
+        window = mcfg.sliding_window
+        cap = min(cfg.cache_len, window) if window else cfg.cache_len
+        if window and cap != window:
+            raise ValueError(
+                f"paged SWA serving needs cache_len >= sliding_window "
+                f"({cfg.cache_len} < {window})")
+        if window and window % cfg.block_size:
+            raise ValueError(
+                f"block_size {cfg.block_size} must divide the sliding "
+                f"window {window}: ring positions are block-mapped "
+                f"(pos % window straddles the block grid otherwise)")
+        if window and cfg.chunk_size > window:
+            raise ValueError(
+                f"chunk_size {cfg.chunk_size} exceeds the sliding window "
+                f"{window}: two rows of one chunk would write one ring row")
+        if (mcfg.family in ("ssm", "hybrid")
+                and cfg.chunk_size % mcfg.ssm_chunk):
+            raise ValueError(
+                f"chunked prefill over SSM state is bit-exact only on "
+                f"the SSD chunk grid: chunk_size {cfg.chunk_size} must "
+                f"be a multiple of ssm_chunk {mcfg.ssm_chunk}")
+        self._mb = P.blocks_for(cap, cfg.block_size)  # blocks per slot
+        self._num_blocks = cfg.num_blocks or cfg.slots * self._mb
+        # prompt-block sharing is content-sound only when a block's KV
+        # is a pure function of the token prefix: ring caches are
+        # position-keyed (mod window) and SSM state is recurrent, so
+        # those families run chunked but unshared
+        self._can_match = (cfg.prefix_cache
+                           and mcfg.family in ("dense", "moe")
+                           and not window)
+        self._index = PrefixIndex(cfg.block_size)
+        self._hold_blocks: set[int] = set()   # index + pending holds
+        self._pcache = None                   # persistent cache/state
+        self._pstate = None
+        self._ctr_live0 = 0     # blocks out of the pool at serve() start
         # Every jitted entry point goes through _register so the compile
         # contracts (repro.staticcheck) and the serve telemetry see one
         # authoritative registry: name -> jitted fn, donated argnums, and
         # where the cache tree sits in the signature / result.
         self._entries: dict[str, dict] = {}
         self._first_args: dict[str, tuple] = {}
-        self._dispatch = self._register(
-            "_dispatch",
-            make_decode_dispatch(model, sp, K, paged=cfg.paged,
-                                 cow=cfg.prefix_cache),
-            donate=(1, 2), cache_arg=2, cache_out=1)
+        cow, C = cfg.prefix_cache, cfg.chunk_size
         if cfg.n_spec:
             self._draft_params = (self._place_params(draft_params)
                                   if mesh is not None else draft_params)
             self._dispatch_spec = self._register(
                 "_dispatch_spec",
-                make_decode_dispatch(model, sp, K, paged=True,
-                                     cow=cfg.prefix_cache,
+                make_decode_dispatch(model, sp, K, cow=cow,
                                      n_spec=cfg.n_spec),
                 donate=(2, 3), cache_arg=3, cache_out=1)
-        if cfg.chunk_size:
+            self._dispatch_spec_chunk = self._register(
+                "_dispatch_spec_chunk",
+                make_decode_dispatch(model, sp, K, cow=cow, chunk=C,
+                                     n_spec=cfg.n_spec),
+                donate=(2, 3), cache_arg=3, cache_out=1)
+        else:
+            self._dispatch = self._register(
+                "_dispatch", make_decode_dispatch(model, sp, K, cow=cow),
+                donate=(1, 2), cache_arg=2, cache_out=1)
             self._dispatch_chunk = self._register(
                 "_dispatch_chunk",
-                make_decode_dispatch(model, sp, K, paged=True,
-                                     cow=cfg.prefix_cache,
-                                     chunk=cfg.chunk_size),
+                make_decode_dispatch(model, sp, K, cow=cow, chunk=C),
                 donate=(1, 2), cache_arg=2, cache_out=1)
-            if cfg.n_spec:
-                self._dispatch_spec_chunk = self._register(
-                    "_dispatch_spec_chunk",
-                    make_decode_dispatch(model, sp, K, paged=True,
-                                         cow=cfg.prefix_cache,
-                                         chunk=cfg.chunk_size,
-                                         n_spec=cfg.n_spec),
-                    donate=(2, 3), cache_arg=3, cache_out=1)
-            self._admit_chunk = self._register(
-                "_admit_chunk", self._admit_chunk_impl, donate=(0, 1),
-                cache_arg=0, cache_out=0)
-            self._evict = self._register(
-                "_evict", self._evict_impl, donate=(0, 1),
-                cache_arg=0, cache_out=0)
-        self._scatter = self._register(
-            "_scatter", self._scatter_impl, donate=(0, 1),
+        self._admit_chunk = self._register(
+            "_admit_chunk", self._admit_chunk_impl, donate=(0, 1),
             cache_arg=0, cache_out=0)
-        self._scatter_paged = self._register(
-            "_scatter_paged", self._scatter_paged_impl, donate=(0, 1),
+        self._evict = self._register(
+            "_evict", self._evict_impl, donate=(0, 1),
             cache_arg=0, cache_out=0)
-        # paged prefill sizes the part cache to the admitted group (block-
-        # aligned prompt rows), so admission cost tracks prompt length; the
-        # contiguous path always materializes cache_len rows.
-        # Donation is intentionally impossible here: the only large operand
-        # is ``params``, which must survive every future dispatch/prefill
-        # call, and the part cache is *produced*, not consumed — there is
-        # no dead input buffer for the output to alias.
-        self._prefill_full = self._register(
-            "_prefill_full",
-            lambda p, toks, cl: model.prefill(p, {"tokens": toks},
-                                              cache_len=cl),
-            static_argnums=(2,))
-        self._prefill_padded = self._register(
-            "_prefill_padded",
-            lambda p, toks, lens, cl: model.prefill(p, {"tokens": toks},
-                                                    cache_len=cl,
-                                                    lengths=lens),
-            static_argnums=(3,))
 
     # -- jitted entry-point registry ----------------------------------------
 
     def _register(self, name: str, fun, *, donate: tuple = (),
-                  static_argnums: tuple = (), cache_arg: int | None = None,
+                  cache_arg: int | None = None,
                   cache_out: int | None = None):
         """Jit ``fun`` and record it as a named engine entry point.
 
@@ -312,11 +263,9 @@ class Engine:
         aliases an output); ``cache_arg``/``cache_out`` locate the cache
         tree in the signature and the result tuple so dtype-hygiene checks
         can compare leaf dtypes input -> output."""
-        jitted = jax.jit(fun, donate_argnums=donate,
-                         static_argnums=static_argnums)
+        jitted = jax.jit(fun, donate_argnums=donate)
         self._entries[name] = {
-            "fn": jitted, "fun": fun, "donate": tuple(donate),
-            "static_argnums": tuple(static_argnums),
+            "fn": jitted, "donate": tuple(donate),
             "cache_arg": cache_arg, "cache_out": cache_out,
         }
 
@@ -371,104 +320,7 @@ class Engine:
                                 self.model.cfg, self.mesh)
         return jax.device_put(cache, shard)
 
-    # -- batched prefill + single-scatter admission -------------------------
-
-    @staticmethod
-    def _scatter_impl(cache, state, part_cache, slots, first, remaining0):
-        """Scatter ``part_cache`` rows (batch axis 1 under the period axis)
-        into the live cache at ``slots`` and arm the slot state — one jitted
-        update for the whole admitted group."""
-        def sc(full, part):
-            return full.at[:, slots].set(part.astype(full.dtype))
-
-        new = dict(cache)
-        new["stack"] = jax.tree.map(sc, cache["stack"], part_cache["stack"])
-        if "prefix" in cache:
-            new["prefix"] = jax.tree.map(sc, cache["prefix"],
-                                         part_cache["prefix"])
-        new["lengths"] = cache["lengths"].at[slots].set(
-            part_cache["lengths"])
-        state = {
-            **state,
-            "cur": state["cur"].at[slots, 0].set(first),
-            "active": state["active"].at[slots].set(remaining0 > 0),
-            "remaining": state["remaining"].at[slots].set(remaining0),
-        }
-        return new, state
-
-    @staticmethod
-    def _scatter_paged_impl(cache, state, part_cache, slots, lens, first,
-                            remaining0, counts):
-        """Admit one prefilled group into the paged cache: release the
-        target slots' stale blocks, allocate ``counts[i]`` fresh blocks per
-        slot, scatter the part cache's K/V rows block-wise into the pools
-        (rows past a slot's true need land in the trash block) and dense
-        (SSM) leaves slot-wise — one jitted update for the whole group."""
-        B = state["active"].shape[0]
-        bstate = {k: cache[k] for k in _BKEYS}
-        nf0 = bstate["n_free"]
-        done = jnp.zeros((B,), bool).at[slots].set(True)
-        bstate = P.release_slots(bstate, done)
-        released = bstate["n_free"] - nf0
-
-        # static block geometry from the part tree (absent for pure-SSM)
-        nbl = 0
-        for lcache in part_cache["stack"].values():
-            if "k" in lcache:
-                bs = next(l for l in cache["stack"].values()
-                          if "pk" in l)["pk"].shape[2]
-                nbl = lcache["k"].shape[2] // bs
-                break
-        popped = jnp.int32(0)
-        if nbl:
-            nf1 = bstate["n_free"]
-            bstate, wids = P.alloc_admit(bstate, slots, counts, nbl)
-            popped = nf1 - bstate["n_free"]
-        # a slot that owes no decode steps must not write or grow; its
-        # blocks are released again right below (the KV is never read —
-        # the single output token came straight from the prefill logits)
-        bstate["slot_active"] = bstate["slot_active"].at[slots].set(
-            remaining0 > 0)
-        nf2 = bstate["n_free"]
-        bstate = P.release_slots(bstate, done & (remaining0 <= 0))
-        released = released + (bstate["n_free"] - nf2)
-
-        def scatter_group(pool_group, part_group):
-            new_group = {}
-            for lkey, lcache in pool_group.items():
-                pl, nl = part_group[lkey], {}
-                for name, leaf in lcache.items():
-                    if name in ("pk", "pv"):
-                        src = pl["k" if name == "pk" else "v"]
-                        n, g, L = src.shape[:3]
-                        blocks = src.reshape(n, g * nbl, L // nbl,
-                                             *src.shape[3:])
-                        nl[name] = leaf.at[:, wids.reshape(-1)].set(
-                            blocks.astype(leaf.dtype))
-                    else:  # contiguous per-slot leaf (SSM state)
-                        nl[name] = leaf.at[:, slots].set(
-                            pl[name].astype(leaf.dtype))
-                new_group[lkey] = nl
-            return new_group
-
-        new = dict(cache)
-        new.update(bstate)
-        new["stack"] = scatter_group(cache["stack"], part_cache["stack"])
-        if "prefix" in cache:
-            new["prefix"] = scatter_group(cache["prefix"],
-                                          part_cache["prefix"])
-        new["lengths"] = cache["lengths"].at[slots].set(lens)
-        state = {
-            **state,
-            "cur": state["cur"].at[slots, 0].set(first),
-            "active": state["active"].at[slots].set(remaining0 > 0),
-            "remaining": state["remaining"].at[slots].set(remaining0),
-            "ctr": bump(state["ctr"], blocks_popped=popped,
-                        blocks_released=released),
-        }
-        return new, state
-
-    # -- chunked / prefix-cached admission ----------------------------------
+    # -- admission ----------------------------------------------------------
 
     def _admit_chunk_impl(self, cache, state, slot, tokens, L, shared_ids,
                           n_shared, n_new, n_retained, pf_start,
@@ -553,7 +405,7 @@ class Engine:
         counts = np.zeros(NB, np.int64)
         for b in tbl[tbl >= 0].reshape(-1):
             counts[b] += 1
-        holds = getattr(self, "_hold_blocks", set())
+        holds = self._hold_blocks
         for b in range(NB):
             expect = counts[b] + (1 if b in holds else 0)
             assert ref[b] == expect, (
@@ -561,7 +413,7 @@ class Engine:
                 f"hold {int(b in holds)}")
         if state is not None:
             ctr = counter_totals(jax.device_get(state["ctr"]))
-            live0 = getattr(self, "_ctr_live0", 0)
+            live0 = self._ctr_live0
             popped, released = ctr["blocks_popped"], ctr["blocks_released"]
             assert live0 + popped - released == NB - n_free, (
                 f"counter leak: base {live0} + popped {popped} - released "
@@ -569,85 +421,6 @@ class Engine:
             assert ctr["drafted"] == ctr["accepted"] + ctr["rejected"], (
                 f"spec counter leak: drafted {ctr['drafted']} != accepted "
                 f"{ctr['accepted']} + rejected {ctr['rejected']}")
-
-    def _group_cache_len(self, Lmax: int) -> int:
-        """Prefill cache rows for one admitted group.  Contiguous: always
-        the full per-slot capacity.  Paged: SWA pages the whole ring (the
-        ring cap must match the decode cap), dense pages just the block-
-        aligned prompt rows — admission memory tracks the prompt."""
-        cfg = self.cfg
-        if not cfg.paged:
-            return cfg.cache_len
-        if self.model.cfg.sliding_window:
-            return cfg.cache_len
-        return min(P.blocks_for(Lmax, cfg.block_size), self._mb) \
-            * cfg.block_size
-
-    def _admit(self, cache, state, free_slots, prompts, gen_tokens, key):
-        """Prefill ``prompts`` into ``free_slots``.  Returns (cache, state,
-        first_tokens host list, n_prefill_calls)."""
-        cfg = self.cfg
-        B = cfg.slots
-        lens = [int(p.shape[0]) for p in prompts]
-        if len(set(lens)) == 1:
-            groups = [list(range(len(prompts)))]
-        elif self._can_pad:
-            groups = [list(range(len(prompts)))]
-        else:  # ring/SSM caches: exact per-length batches
-            by_len: dict[int, list[int]] = {}
-            for i, L in enumerate(lens):
-                by_len.setdefault(L, []).append(i)
-            groups = list(by_len.values())
-
-        firsts: dict[int, int] = {}
-        rem0 = jnp.int32(gen_tokens - 1)
-        for g in groups:
-            key, sub = jax.random.split(key)
-            Lmax = max(lens[i] for i in g)
-            cl = self._group_cache_len(Lmax)
-            toks = jnp.stack([
-                jnp.pad(prompts[i], (0, Lmax - lens[i])) for i in g
-            ]).astype(jnp.int32)
-            if all(lens[i] == Lmax for i in g):
-                logits, part = self._prefill_full(self.params, toks, cl)
-            else:
-                glens = jnp.asarray([lens[i] for i in g], jnp.int32)
-                logits, part = self._prefill_padded(self.params, toks,
-                                                    glens, cl)
-            first = sample(logits, sub, self.cfg.sampling)
-            g_slots = [free_slots[i] for i in g]
-            if cfg.paged:
-                if self.model.cfg.sliding_window:
-                    counts = jnp.full((len(g),), self._mb, jnp.int32)
-                else:
-                    # clamp to per-slot capacity: an over-long prompt only
-                    # keeps its first cap rows (the contiguous cache drops
-                    # the overflow the same way) — without the clamp the
-                    # allocator would debit blocks the scatter never places
-                    counts = jnp.asarray(
-                        [min(P.blocks_for(lens[i], cfg.block_size),
-                             self._mb) for i in g], jnp.int32)
-                cache, state = self._scatter_paged(
-                    cache, state, part, jnp.asarray(g_slots, jnp.int32),
-                    jnp.asarray([lens[i] for i in g], jnp.int32),
-                    first, rem0, counts)
-            elif len(g) == B and g_slots == list(range(B)):
-                # scatter-free: the prefill result IS the new cache
-                if self.mesh is not None:
-                    part = self._place_cache(part)
-                cache = part
-                state = {**state,
-                         "cur": first[:, None].astype(jnp.int32),
-                         "active": jnp.broadcast_to(rem0 > 0, (B,)),
-                         "remaining": jnp.broadcast_to(rem0, (B,))}
-            else:
-                cache, state = self._scatter(
-                    cache, state, part, jnp.asarray(g_slots, jnp.int32),
-                    first, rem0)
-            for i, t in zip(g, jax.device_get(first)):
-                firsts[i] = int(t)
-        return cache, state, [firsts[i] for i in range(len(prompts))], \
-            len(groups)
 
     # -- serve --------------------------------------------------------------
 
@@ -664,8 +437,8 @@ class Engine:
     def _blocks_needed(self, prompt_len: int, gen_tokens: int) -> int:
         """Worst-case pool blocks one request can ever hold: SWA rings page
         the whole window; dense requests write ``prompt + gen - 1`` cache
-        rows over their lifetime (capacity-clamped, like the contiguous
-        cache drops overflow writes).  Speculative rounds overshoot by up
+        rows over their lifetime (capacity-clamped: writes past the slot's
+        capacity route to the trash block).  Speculative rounds overshoot by up
         to ``n_spec`` rows past the budget before rolling back (the last
         round's span), so the reservation covers that transient too."""
         if self.model.cfg.sliding_window:
@@ -719,22 +492,17 @@ class Engine:
         if tr is None:
             return
         tr.counter("tokens", {"emitted": totals["tokens"]})
-        if self.cfg.paged:
-            live = (getattr(self, "_ctr_live0", 0)
-                    + totals["blocks_popped"] - totals["blocks_released"])
-            tr.counter("blocks", {"live": live,
-                                  "cow": totals["cow_copies"]})
+        live = (self._ctr_live0
+                + totals["blocks_popped"] - totals["blocks_released"])
+        tr.counter("blocks", {"live": live, "cow": totals["cow_copies"]})
 
     def _finalize_serve(self, stats, ctr_host):
         """End-of-serve telemetry: expose the device counters through
         ``stats["counters"]`` and fold them plus the allocator / spec
         gauges into the registry.  ``ctr_host`` is the last counter tree
-        the dispatch sync fetched (None when no dispatch ran — e.g.
-        ``gen_tokens == 1`` on the non-chunked path, where every token
-        comes from prefill; the counters then read zero rather than
-        costing a dedicated sync)."""
-        totals = (counter_totals(ctr_host) if ctr_host is not None
-                  else dict.fromkeys(COUNTER_KEYS, 0))
+        the dispatch sync fetched (every request's first token comes from
+        a dispatch, so one ran)."""
+        totals = counter_totals(ctr_host)
         stats["counters"] = totals
         m = self.metrics
         if m is None:
@@ -746,17 +514,14 @@ class Engine:
             acc = m.gauge("spec.acceptance_rate")   # None -> "n/a"
             if totals["drafted"]:
                 acc.set(totals["accepted"] / totals["drafted"])
-        if self.cfg.paged:
-            live = (getattr(self, "_ctr_live0", 0)
-                    + totals["blocks_popped"] - totals["blocks_released"])
-            m.gauge("alloc.live_blocks").set(live)
-            m.gauge("alloc.free_blocks").set(self._num_blocks - live)
-        if self.cfg.chunk_size:
-            holds = len(self._hold_blocks)
-            m.gauge("alloc.index_holds").set(holds)
-            m.gauge("alloc.ledger_headroom").set(self._num_blocks - holds)
-            m.counter("prefix.evictions").inc(
-                stats.get("prefix_evictions", 0))
+        live = (self._ctr_live0
+                + totals["blocks_popped"] - totals["blocks_released"])
+        m.gauge("alloc.live_blocks").set(live)
+        m.gauge("alloc.free_blocks").set(self._num_blocks - live)
+        holds = len(self._hold_blocks)
+        m.gauge("alloc.index_holds").set(holds)
+        m.gauge("alloc.ledger_headroom").set(self._num_blocks - holds)
+        m.counter("prefix.evictions").inc(stats["prefix_evictions"])
 
     def serve(self, requests, *, gen_tokens: int, seed: int | None = None,
               return_stats: bool = False):
@@ -764,7 +529,7 @@ class Engine:
         generated tokens.  Returns outputs in request order (and a stats
         dict when ``return_stats``)."""
         cfg, model = self.cfg, self.model
-        B = cfg.slots
+        B, K, C = cfg.slots, cfg.k_steps, cfg.chunk_size
         requests = [jnp.asarray(r, jnp.int32).reshape(-1) for r in requests]
         stats = {"host_syncs": 0, "dispatches": 0, "prefill_calls": 0,
                  "decode_steps": 0, "tokens": 0, "prefill_tokens": 0,
@@ -775,154 +540,15 @@ class Engine:
                          spec_depth=spec_ctl.depth)
         if gen_tokens < 1 or not requests:
             return ([], stats) if return_stats else []
-        if cfg.chunk_size:
-            return self._serve_chunked(requests, gen_tokens, seed,
-                                       return_stats, stats, spec_ctl)
-        outputs: dict[int, list[int]] = {}
-        tr = self.tracer
-        t_enq = time.perf_counter()     # all requests enqueue at serve()
-        req_admit: dict[int, float] = {}
-        ctr_host = None                 # last fetched device counter tree
-        ctr_prev = dict.fromkeys(COUNTER_KEYS, 0)
-        self._ctr_live0 = 0             # fresh cache: no live blocks yet
-
-        if cfg.paged:
-            cache = model.init_paged_cache(B, cfg.cache_len,
-                                           block_size=cfg.block_size,
-                                           num_blocks=self._num_blocks)
-            for r in requests:
-                need = self._blocks_needed(int(r.shape[0]), gen_tokens)
-                if need > self._num_blocks:
-                    raise ValueError(
-                        f"request of {int(r.shape[0])} tokens needs {need} "
-                        f"blocks but the pool has {self._num_blocks}")
-        else:
-            cache = model.init_cache(B, cfg.cache_len)
-        stats["cache_bytes"] = sum(
-            l.size * l.dtype.itemsize for l in jax.tree.leaves(cache))
-        state = init_slot_state(B, moe=self._moe)
-        if self.mesh is not None:
-            cache = self._place_cache(cache)
-        key = jax.random.PRNGKey(cfg.seed if seed is None else seed)
-        queue = deque(range(len(requests)))
-        slot_rid = [-1] * B     # request id per slot (host mirror)
-        slot_rem = [0] * B      # remaining budget     (host mirror)
-        # host mirror of worst-case block reservations (paged): a slot
-        # reserves its request's lifetime maximum at admission and drops it
-        # when the request finishes — the device free-list only ever runs
-        # *ahead* of this view (it reclaims blocks mid-scan), so admission
-        # against reservations can never underflow the pool
-        slot_rsv = [0] * B
-
-        while queue or any(r >= 0 for r in slot_rid):
-            free = [s for s in range(B) if slot_rid[s] < 0]
-            if queue and free:
-                if cfg.paged:
-                    take_slots, rids = [], []
-                    rsv_total = sum(slot_rsv)
-                    for s in free:
-                        if not queue:
-                            break
-                        need = self._blocks_needed(
-                            int(requests[queue[0]].shape[0]), gen_tokens)
-                        if rsv_total + need > self._num_blocks:
-                            break   # FIFO: head request must fit first
-                        rsv_total += need
-                        slot_rsv[s] = need
-                        take_slots.append(s)
-                        rids.append(queue.popleft())
-                    assert take_slots or any(r >= 0 for r in slot_rid), \
-                        "admission stalled with an idle pool"
-                else:
-                    take = min(len(free), len(queue))
-                    take_slots = free[:take]
-                    rids = [queue.popleft() for _ in range(take)]
-                if rids:
-                    n_prompt = sum(int(requests[r].shape[0]) for r in rids)
-                    with span("engine", "admit", tr, requests=len(rids),
-                              prompt_len=n_prompt, prefix_hit=0):
-                        key, sub = jax.random.split(key)
-                        cache, state, first, ncalls = self._admit(
-                            cache, state, take_slots,
-                            [requests[r] for r in rids], gen_tokens, sub)
-                        ta = time.perf_counter()
-                        stats["prefill_calls"] += ncalls
-                        stats["host_syncs"] += ncalls
-                        stats["tokens"] += len(rids)
-                        stats["prefill_tokens"] += n_prompt
-                        for s, r, t in zip(take_slots, rids, first):
-                            outputs[r] = [t]
-                            slot_rid[s], slot_rem[s] = r, gen_tokens - 1
-                            # first token comes from the prefill logits, so
-                            # admission time IS first-token time here
-                            req_admit[r] = ta
-                        for s in take_slots:   # gen_tokens == 1 ends now
-                            if slot_rem[s] <= 0:
-                                r = slot_rid[s]
-                                self._request_done(
-                                    int(requests[r].shape[0]), gen_tokens,
-                                    t_enq, req_admit[r], req_admit[r])
-                                slot_rid[s] = -1
-                                slot_rsv[s] = 0
-            if not any(r >= 0 for r in slot_rid):
-                continue
-
-            with span("engine", "dispatch", tr,
-                      dispatch="spec" if cfg.n_spec else "decode"):
-                key, sub = jax.random.split(key)
-                if cfg.n_spec:
-                    state, cache, toks, emitted = self._dispatch_spec(
-                        self.params, self._draft_params, state, cache,
-                        jnp.int32(spec_ctl.depth), sub)
-                else:
-                    state, cache, toks, emitted = self._dispatch(
-                        self.params, state, cache, sub)
-            # the counter tree rides the returned state: same sync, no cost
-            with span("engine", "sync", tr):
-                toks_h, em_h, ctr_host = jax.device_get(
-                    (toks, emitted, state["ctr"]))
-            with span("engine", "host", tr):
-                totals = counter_totals(ctr_host)
-                self._count_dispatch(stats, totals, ctr_prev, spec_ctl)
-                ctr_prev = totals
-                if cfg.paged and cfg.check_invariants:
-                    self._assert_invariants(cache, state)
-                for s in range(B):
-                    r = slot_rid[s]
-                    if r < 0:
-                        continue
-                    row = [int(t) for t in toks_h[s][em_h[s]]]
-                    outputs[r].extend(row)
-                    stats["tokens"] += len(row)
-                    slot_rem[s] -= len(row)
-                    if slot_rem[s] <= 0:
-                        slot_rid[s] = -1
-                        slot_rsv[s] = 0  # device freed the blocks mid-scan
-                        self._request_done(int(requests[r].shape[0]),
-                                           gen_tokens, t_enq, req_admit[r],
-                                           req_admit[r])
-
-        self._finalize_serve(stats, ctr_host)
-        outs = [outputs[i] for i in sorted(outputs)]
-        return (outs, stats) if return_stats else outs
-
-    # -- chunked / prefix-cached serve loop ---------------------------------
-
-    def _serve_chunked(self, requests, gen_tokens, seed, return_stats,
-                       stats, spec_ctl=None):
-        cfg, model = self.cfg, self.model
-        B, K, C = cfg.slots, cfg.k_steps, cfg.chunk_size
         bs = cfg.block_size
         pcap = cfg.cache_len
-        cap_rows = self._mb * bs if not model.cfg.sliding_window \
-            else model.cfg.sliding_window
         persist = cfg.prefix_cache
         for r in requests:
             L = int(r.shape[0])
             if L > pcap:
                 raise ValueError(
-                    f"chunked prefill streams prompts through the paged "
-                    f"cache: prompt of {L} tokens exceeds cache_len {pcap}")
+                    f"prompt of {L} tokens exceeds cache_len {pcap}: "
+                    f"prompts stream through the slot's paged cache")
             need = self._blocks_needed(L, gen_tokens)
             if need > self._num_blocks:
                 raise ValueError(
@@ -956,6 +582,7 @@ class Engine:
         tr = self.tracer
         t_enq = time.perf_counter()
         ctr_host = None
+        ctr_stale = False   # a release ran on the device since ctr_host
         ctr_prev = dict.fromkeys(COUNTER_KEYS, 0)
         req_admit: dict[int, float] = {}
         req_first: dict[int, float] = {}
@@ -975,11 +602,12 @@ class Engine:
         def drop_holds(ids):
             """Release host holds on ``ids`` (eviction / duplicate unwind);
             padded to the pool size so the jitted release compiles once."""
-            nonlocal cache, state
+            nonlocal cache, state, ctr_stale
             arr = np.full((self._num_blocks,), -1, np.int32)
             arr[:len(ids)] = ids
             cache, state = self._evict(cache, state, jnp.asarray(arr))
             self._hold_blocks.difference_update(ids)
+            ctr_stale = True
 
         def try_evict(want: int) -> int:
             ids = self._index.evict(want) if self._can_match else []
@@ -1032,16 +660,8 @@ class Engine:
                         shared = matched_ids + ([partial_id] if partial_hit
                                                 else [])
                         n_ret = new_full if self._can_match else 0
-                        # speculative rounds overshoot the budget by up to
-                        # n_spec rows before rolling back (the last
-                        # round's span), so the lifetime worst case covers
-                        # that transient too — mirrors _blocks_needed
-                        lifetime = min(
-                            P.blocks_for(min(L + gen_tokens - 1
-                                             + cfg.n_spec, cap_rows),
-                                         bs),
-                            self._mb)
-                        decode_alloc = lifetime - P.blocks_for(L, bs)
+                        decode_alloc = (self._blocks_needed(L, gen_tokens)
+                                        - P.blocks_for(L, bs))
                         cow_extra = 1 if (partial_hit and gen_tokens > 1) \
                             else 0
                         slot_need = (n_new - n_ret) + decode_alloc \
@@ -1116,6 +736,7 @@ class Engine:
             with span("engine", "sync", tr):
                 toks_h, em_h, ctr_host = jax.device_get(
                     (toks, emitted, state["ctr"]))
+                ctr_stale = False
             with span("engine", "host", tr):
                 totals = counter_totals(ctr_host)
                 self._count_dispatch(stats, totals, ctr_prev, spec_ctl)
@@ -1171,6 +792,9 @@ class Engine:
                             req_admit[r], req_first.get(r, req_admit[r]),
                             prefix_hit_frac=req_pf.get(r))
 
+        if ctr_stale:   # holds dropped in the last host work freed blocks
+            ctr_host = jax.device_get(state["ctr"])
+            stats["host_syncs"] += 1
         self._finalize_serve(stats, ctr_host)
         if persist:
             self._pcache, self._pstate = cache, state

@@ -1,10 +1,12 @@
-"""The pre-engine host-driven serving loop, kept as a benchmark baseline.
+"""The host-driven serving loop over the contiguous cache: the engine's
+parity reference.
 
-This is the loop ``launch/serve.py`` used to run: batch-1 prefill with a
-Python-side cache scatter per slot, and a blocking ``int()`` host sync per
-slot per decoded token.  ``benchmarks/bench_serve.py`` races it against the
-device-resident engine, and the engine's greedy-parity tests pin
-token-exactness against it.
+Batch-1 prefill (one ``model.prefill`` a request) with a Python-side cache
+scatter per slot, then one ``model.decode_step`` at a time with a blocking
+``int()`` host sync per slot per decoded token.  It shares no code with the
+engine's paged pool, chunked prefill or dispatch scan, which is what makes
+it an oracle: the engine's greedy-parity tests pin token-exactness against
+it.
 
 The one behavioral change from the historical code: the ``greedy=False``
 branch used to compute ``int(logits.argmax())`` — identical to the greedy
@@ -49,7 +51,8 @@ def serve_host_loop(model: Model, params, requests: list[jnp.ndarray], *,
                     batch: int, gen_tokens: int, cache_len: int,
                     sampling: SamplingParams | None = None, seed: int = 0,
                     return_stats: bool = False):
-    """Serve ``requests`` with the old B-slot host-scheduled batcher."""
+    """Serve ``requests`` with a B-slot host-scheduled batcher over the
+    contiguous cache (``model.init_cache``); each gets ``gen_tokens``."""
     sp = sampling or SamplingParams()
     step = jax.jit(make_decode_step(model, sp), donate_argnums=2)
     cache = model.init_cache(batch, cache_len)
@@ -63,23 +66,26 @@ def serve_host_loop(model: Model, params, requests: list[jnp.ndarray], *,
              "decode_steps": 0, "tokens": 0}
 
     def fill_slot(slot, cache, cur, key):
-        rid = queue.pop(0)
-        logits, cache = single_slot_prefill(model, params, cache,
-                                            requests[rid], slot, cache_len)
-        key, sub = jax.random.split(key)
-        nxt = int(sample(logits[None], sub, sp)[0])
-        stats["prefill_calls"] += 1
-        stats["host_syncs"] += 1
-        stats["tokens"] += 1
-        cur = cur.at[slot, 0].set(nxt)
-        outputs[rid] = [nxt]
-        active[slot] = rid
-        remaining[slot] = gen_tokens - 1
+        # a request whose whole budget is its first token leaves the slot
+        # to the next one at once
+        while queue and active[slot] < 0:
+            rid = queue.pop(0)
+            logits, cache = single_slot_prefill(model, params, cache,
+                                                requests[rid], slot,
+                                                cache_len)
+            key, sub = jax.random.split(key)
+            nxt = int(sample(logits[None], sub, sp)[0])
+            stats["prefill_calls"] += 1
+            stats["host_syncs"] += 1
+            stats["tokens"] += 1
+            cur = cur.at[slot, 0].set(nxt)
+            outputs[rid] = [nxt]
+            active[slot] = rid if gen_tokens > 1 else -1
+            remaining[slot] = gen_tokens - 1
         return cache, cur, key
 
     for slot in range(batch):
-        if queue:
-            cache, cur, key = fill_slot(slot, cache, cur, key)
+        cache, cur, key = fill_slot(slot, cache, cur, key)
 
     while any(a >= 0 for a in active):
         key, sub = jax.random.split(key)
@@ -96,7 +102,6 @@ def serve_host_loop(model: Model, params, requests: list[jnp.ndarray], *,
             remaining[slot] -= 1
             if remaining[slot] <= 0:
                 active[slot] = -1
-                if queue:
-                    cache, cur, key = fill_slot(slot, cache, cur, key)
+                cache, cur, key = fill_slot(slot, cache, cur, key)
     outs = [outputs[i] for i in sorted(outputs)]
     return (outs, stats) if return_stats else outs

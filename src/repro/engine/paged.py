@@ -1,15 +1,15 @@
 """Paged KV-cache: global block pool, per-slot block tables, device free-list,
 and **refcounted block sharing** (prefix caching / copy-on-write).
 
-The contiguous engine cache reserves ``cache_len`` rows per slot per layer,
-so the longest admissible request dictates the memory of every slot.  The
-paged cache replaces the per-slot rows with a **global pool of fixed-size
-token blocks** shared by all slots:
+A contiguous cache reserves ``cache_len`` rows per slot per layer, so the
+longest admissible request dictates the memory of every slot.  The paged
+cache, the engine's only cache, replaces the per-slot rows with a **global
+pool of fixed-size token blocks** shared by all slots:
 
 * per attention layer: ``pk`` / ``pv`` pools of shape
   ``[num_blocks + 1, block_size, Kv, hd]`` — the extra last block is a
   **trash sink**: writes for inactive slots (the dispatch keeps decoding
-  finished slots, same as the contiguous engine) and capacity overflows are
+  finished slots) and capacity overflows are
   routed there instead of corrupting live blocks;
 * one **block table** ``tbl [slots, max_blocks]`` shared by every attention
   layer (all layers advance in lockstep, so one table serves the stack);
@@ -193,37 +193,6 @@ def release_refs(bstate: dict, ids: jnp.ndarray) -> dict:
 # ---------------------------------------------------------------------------
 # Admission-time allocation (jit-safe, called from the engine)
 # ---------------------------------------------------------------------------
-
-def alloc_admit(bstate: dict, slots: jnp.ndarray, counts: jnp.ndarray,
-                nbl: int):
-    """Allocate ``counts[i]`` blocks for each admitted slot ``slots[i]``.
-
-    Returns ``(bstate, wids [g, nbl])`` — per-slot write-block ids padded
-    with the trash index beyond ``counts[i]`` (the prefill scatter writes
-    ``nbl`` block rows per slot; rows past the slot's true need are pad
-    garbage and belong in the trash).  The caller (engine) reserves
-    capacity on the host, so the stack cannot underflow here.
-    """
-    tbl, free, n_free = bstate["tbl"], bstate["free"], bstate["n_free"]
-    ref = bstate["ref"]
-    g = slots.shape[0]
-    trash = free.shape[0]
-    offs = jnp.cumsum(counts)                   # [g] blocks consumed so far
-    jj = jnp.arange(nbl)[None, :]               # [1, nbl]
-    pos = n_free - offs[:, None] + jj           # stack index per (slot, j)
-    take = jj < counts[:, None]
-    ids = free[jnp.clip(pos, 0, trash - 1)]
-    wids = jnp.where(take, ids, trash)
-    new_rows = jnp.where(take, ids, NEG)
-    tbl = tbl.at[slots].set(
-        jnp.pad(new_rows, ((0, 0), (0, tbl.shape[1] - nbl)),
-                constant_values=NEG))
-    ref = ref.at[jnp.where(take, ids, trash).reshape(-1)].set(1, mode="drop")
-    n_free = n_free - jnp.sum(counts)
-    active = bstate["slot_active"].at[slots].set(True)
-    return {**bstate, "tbl": tbl, "ref": ref, "n_free": n_free,
-            "slot_active": active}, wids
-
 
 def admit_slot(bstate: dict, slot, shared_ids: jnp.ndarray, n_shared,
                n_new, n_retained, nbl: int):

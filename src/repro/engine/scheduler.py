@@ -1,32 +1,33 @@
 """Device-resident slot scheduler: state layout + multi-token decode dispatch.
 
 The continuous batcher's per-slot state (``cur`` token, ``active`` flag,
-``remaining`` budget) lives in jnp arrays and is updated *inside* the jitted
-decode dispatch, so the host never round-trips per token.  One dispatch runs
-``k_steps`` decode steps under ``lax.scan`` and returns the emitted token
-grid ``[B, K]`` plus the emission mask — the host syncs once per K steps
-instead of once per slot per token.
+``remaining`` budget, the prompt buffer and its prefill cursor) lives in jnp
+arrays and is updated *inside* the jitted dispatch, so the host never
+round-trips per token.  One dispatch runs ``k_steps`` steps under
+``lax.scan`` over the paged cache and returns the emitted token grid
+``[B, K]`` plus the emission mask — the host syncs once per K steps instead
+of once per slot per token.
 
-Semantics match the pre-engine host loop exactly: every slot decodes every
-step (finished slots produce masked garbage that is overwritten at the next
-prefill, just as the old loop kept feeding finished slots), ``remaining`` is
-decremented only while a slot is active, and a slot deactivates when its
-budget reaches zero.  Under greedy sampling the emitted tokens are therefore
-token-identical to the old loop.
+Every slot decodes every step (inactive slots' writes route to the pool's
+trash block), ``remaining`` is decremented only while a slot is active, and
+a slot deactivates — and pushes its blocks back on the free stack, inside
+the scan — when its budget reaches zero.  Under greedy sampling the emitted
+tokens are token-identical to the host loop (engine/legacy.py).
 
-**Chunked prefill** (``chunk > 0``, paged caches only): prompt prefill rides
-*inside* the same ``lax.scan`` — each scan step runs one decode step for the
-decoding slots AND one ``chunk``-token prefill piece for the slots still in
-prefill phase (state fields ``prompt`` / ``pf_pos`` / ``pf_len``, armed by
-the engine's admission).  A long prompt therefore no longer stalls in-flight
-decode: it streams through K*chunk prompt tokens per dispatch while other
-slots keep emitting.  The step a slot's last chunk lands, its first token is
-sampled from the chunk's logits and emitted through the same token grid, and
-decoding starts the following step — exactly the contiguous engine's
-"prefill, sample first, then decode" order.  Because the two sub-steps share
-one batch, each pass restores the rows of slots in the *other* phase
-(per-slot lengths and SSM state), so a prefilling slot's accumulating state
-is never touched by the decode pass's masked garbage.
+**Chunked prefill** (``chunk > 0``): prompt prefill rides *inside* the same
+``lax.scan`` — each scan step runs one decode step for the decoding slots
+AND one ``chunk``-token prefill piece for the slots still in prefill phase
+(state fields ``prompt`` / ``pf_pos`` / ``pf_len``, armed by the engine's
+admission).  A long prompt therefore does not stall in-flight decode: it
+streams through K*chunk prompt tokens per dispatch while other slots keep
+emitting.  The step a slot's last chunk lands, its first token is sampled
+from the chunk's logits and emitted through the same token grid, and
+decoding starts the following step ("prefill, sample first, then decode").
+Because the two sub-steps share one batch, each pass restores the rows of
+slots in the *other* phase (per-slot lengths and SSM state), so a
+prefilling slot's accumulating state is never touched by the decode pass's
+masked garbage.  The engine runs the ``chunk == 0`` dispatch whenever no
+slot is prefilling.
 """
 from __future__ import annotations
 
@@ -39,29 +40,30 @@ from repro.models.lm import Model, decode_rows_read
 from repro.telemetry.counters import bump, init_counters
 
 
-def init_slot_state(n_slots: int, prompt_cap: int = 0,
+def init_slot_state(n_slots: int, prompt_cap: int,
                     moe: bool = False) -> dict:
     """Zeroed device-side slot state for a fresh pool of ``n_slots``.
 
-    ``prompt_cap > 0`` adds the chunked-prefill fields: a per-slot prompt
-    buffer plus prefill cursor/length and the post-first-token decode
-    budget (armed by the engine's admission).  ``ctr`` is the
-    device-resident telemetry counter tree (repro.telemetry.counters):
-    bumped inside the scan, read for free at the existing dispatch sync;
-    ``moe`` adds the expert-layer counters (models with MoE layers)."""
-    st = {
+    The chunked-prefill fields are a per-slot prompt buffer of
+    ``prompt_cap`` tokens plus prefill cursor/length and the
+    post-first-token decode budget (armed by the engine's admission).
+    ``ctr`` is the device-resident telemetry counter tree
+    (repro.telemetry.counters): bumped inside the scan, read for free at
+    the existing dispatch sync; ``moe`` adds the expert-layer counters
+    (models with MoE layers)."""
+    def z():   # a buffer per field: the dispatch donates the state
+        return jnp.zeros((n_slots,), jnp.int32)
+    return {
         "cur": jnp.zeros((n_slots, 1), jnp.int32),      # last sampled token
         "active": jnp.zeros((n_slots,), bool),          # slot serving a req?
-        "remaining": jnp.zeros((n_slots,), jnp.int32),  # decode budget left
+        "remaining": z(),                               # decode budget left
         "ctr": init_counters(moe),                      # device counters
+        "prompt": jnp.zeros((n_slots, prompt_cap), jnp.int32),
+        "pf_pos": z(),                                  # next prompt row
+        "pf_len": z(),                                  # prompt length
+        "budget": z(),                                  # decode budget
+        "pf_shared": z(),                               # prefix-hit mark
     }
-    if prompt_cap:
-        st["prompt"] = jnp.zeros((n_slots, prompt_cap), jnp.int32)
-        st["pf_pos"] = jnp.zeros((n_slots,), jnp.int32)   # next prompt row
-        st["pf_len"] = jnp.zeros((n_slots,), jnp.int32)   # prompt length
-        st["budget"] = jnp.zeros((n_slots,), jnp.int32)   # decode budget
-        st["pf_shared"] = jnp.zeros((n_slots,), jnp.int32)  # prefix-hit mark
-    return st
 
 
 @jax.named_scope("keep_rows")
@@ -144,8 +146,8 @@ def named_dispatch(fn, name: str):
 
 
 def make_decode_dispatch(model: Model, sp: SamplingParams, k_steps: int,
-                         *, paged: bool = False, cow: bool = False,
-                         chunk: int = 0, n_spec: int = 0):
+                         *, cow: bool = False, chunk: int = 0,
+                         n_spec: int = 0):
     """Build the jitted K-step decode dispatch.
 
     ``dispatch(params, state, cache, key)`` -> (state, cache, tokens [B, K],
@@ -160,60 +162,43 @@ def make_decode_dispatch(model: Model, sp: SamplingParams, k_steps: int,
     scalar before ``key`` (the dynamic speculation depth, 1..n_spec — a
     plain traced operand, so moving it never recompiles), and its grids
     widen to ``[B, k_steps * (n_spec + 1)]`` (acceptance telemetry rides
-    the ``state["ctr"]`` counter tree).  Speculation requires the paged
-    cache and
-    **composes** with both flags: ``cow=True`` makes the round's span
-    allocation copy-on-write (a draft/verify write into a prefix-shared
-    block pops a private copy first, exactly like a decode write), and
-    ``chunk > 0`` appends the chunked-prefill phase to every round — the
-    three are orthogonal phases of one scan step.
+    the ``state["ctr"]`` counter tree).  Speculation **composes** with both
+    flags: ``cow=True`` makes the round's span allocation copy-on-write (a
+    draft/verify write into a prefix-shared block pops a private copy
+    first, exactly like a decode write), and ``chunk > 0`` appends the
+    chunked-prefill phase to every round — the three are orthogonal phases
+    of one scan step.
 
-    With ``paged=True`` the cache is the paged block pool
-    (``model.init_paged_cache``): each step runs ``decode_step_paged`` (which
-    pops blocks from the device free-list as slots cross block boundaries)
-    and the moment a slot's budget drains its blocks are pushed back **inside
-    the scan** — capacity recycles mid-dispatch without a host round-trip.
-    ``cow=True`` enables the copy-on-write write path (refcounted prefix
-    caching).  ``chunk > 0`` piggybacks chunked prefill on the scan (see
-    module docstring); extra state fields ride through untouched either way,
-    so the same state pytree serves both dispatch flavors.
+    The cache is the paged block pool (``model.init_paged_cache``): each
+    step runs ``decode_step_paged`` (which pops blocks from the device
+    free-list as slots cross block boundaries) and the moment a slot's
+    budget drains its blocks are pushed back **inside the scan** — capacity
+    recycles mid-dispatch without a host round-trip.  ``cow=True`` enables
+    the copy-on-write write path (refcounted prefix caching).  ``chunk > 0``
+    piggybacks chunked prefill on the scan (see module docstring); the
+    prefill state fields ride through the ``chunk == 0`` dispatch
+    untouched, so one state pytree serves both.
     """
     if n_spec:
-        if not paged:
-            raise NotImplementedError(
-                "speculative dispatch needs the paged cache path")
         from repro.engine.spec import make_spec_dispatch
         return make_spec_dispatch(model, sp, k_steps, n_spec, cow=cow,
                                   chunk=chunk)
-    if not paged:
-        step_fn = model.decode_step
-    else:
-        if model.decode_step_paged is None:
-            raise NotImplementedError(
-                f"model family {model.cfg.family!r} has no paged decode path")
-        def step_fn(params, toks, cache, keep):
-            return model.decode_step_paged(params, toks, cache, cow=cow,
-                                           keep=keep)
-    if chunk:
-        if not paged or model.prefill_chunk_paged is None:
-            raise NotImplementedError(
-                "chunked prefill needs the paged cache path")
+    if model.decode_step_paged is None or model.prefill_chunk_paged is None:
+        raise NotImplementedError(
+            f"model family {model.cfg.family!r} has no paged decode path")
 
     def dispatch(params, state: dict, cache: dict, key):
         def body(carry, step_key):
             st, cache = carry
-            ctr = st["ctr"]
             # ---- decode sub-step (slots in decode phase) ----------------
-            if paged:   # allocator deltas around the step count pops/CoW
-                nf0, ref0 = cache["n_free"], cache["ref"]
-                ctr = bump(ctr, kv_rows_read=decode_rows_read(cache,
-                                                              model.cfg))
-                # idle and prefilling slots keep their per-slot state
-                logits, new_cache = step_fn(params, st["cur"], cache,
-                                            st["active"])
-                ctr = _bump_moe(ctr, new_cache)
-            else:
-                logits, new_cache = step_fn(params, st["cur"], cache)
+            # allocator deltas around the step count pops/CoW
+            nf0, ref0 = cache["n_free"], cache["ref"]
+            ctr = bump(st["ctr"], kv_rows_read=decode_rows_read(cache,
+                                                                model.cfg))
+            # idle and prefilling slots keep their per-slot state
+            logits, new_cache = model.decode_step_paged(
+                params, st["cur"], cache, cow=cow, keep=st["active"])
+            ctr = _bump_moe(ctr, new_cache)
             if chunk:  # prefilling/idle slots' rows must stay untouched
                 new_cache = _keep_rows(new_cache, cache, st["active"])
             cache = new_cache
@@ -221,16 +206,15 @@ def make_decode_dispatch(model: Model, sp: SamplingParams, k_steps: int,
             emitted = st["active"]
             remaining = st["remaining"] - emitted.astype(jnp.int32)
             active = emitted & (remaining > 0)
-            if paged:
-                # alloc_step only pops; a CoW pop is the only ref decrement
-                ctr = bump(ctr,
-                           blocks_popped=nf0 - cache["n_free"],
-                           cow_copies=jnp.sum(cache["ref"] < ref0))
-                nf1 = cache["n_free"]
-                bstate = release_slots({k: cache[k] for k in BSTATE_KEYS},
-                                       emitted & ~active)
-                cache = {**cache, **bstate}
-                ctr = bump(ctr, blocks_released=cache["n_free"] - nf1)
+            # alloc_step only pops; a CoW pop is the only ref decrement
+            ctr = bump(ctr,
+                       blocks_popped=nf0 - cache["n_free"],
+                       cow_copies=jnp.sum(cache["ref"] < ref0))
+            nf1 = cache["n_free"]
+            bstate = release_slots({k: cache[k] for k in BSTATE_KEYS},
+                                   emitted & ~active)
+            cache = {**cache, **bstate}
+            ctr = bump(ctr, blocks_released=cache["n_free"] - nf1)
             tok_out, em_out = nxt, emitted
             st = {**st, "cur": nxt[:, None], "active": active,
                   "remaining": remaining,
@@ -258,8 +242,8 @@ def make_decode_step(model: Model, sp: SamplingParams | None = None):
     """One decode step + sampling: (params, tokens, cache, key=) ->
     (next_tok [B, 1], logits [B, V], new cache).
 
-    This is the single-step form of the dispatch; ``launch.steps
-    .make_serve_step`` is a deprecated greedy alias of it.
+    The contiguous-cache step of the host loop (engine/legacy.py);
+    ``launch.steps.make_serve_step`` is a deprecated greedy alias of it.
     """
     sp = sp or SamplingParams()
 

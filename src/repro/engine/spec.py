@@ -86,8 +86,8 @@ halving it on misses — AIMD on the acceptance rate — so a garbage draft
 stops wasting n_spec draft forwards per round without a single retrace.
 
 Guarantee: greedy speculative output is **token-exact** against the
-non-speculative paged engine (and therefore the contiguous engine and the
-legacy host loop) for any draft tree and any depth trajectory whatsoever —
+non-speculative engine (and therefore the contiguous-cache host loop,
+engine/legacy.py) for any draft tree and any depth trajectory whatsoever —
 the draft only decides how many verifier-identical tokens emit per round,
 never their values.
 

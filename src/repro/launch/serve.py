@@ -1,9 +1,9 @@
 """Serving CLI: a thin driver over the device-resident engine.
 
 All serving mechanics live in :mod:`repro.engine` — slot scheduling on
-device, K-step decode dispatch (one host sync per K tokens), batched
-multi-slot prefill with a single jitted cache scatter, greedy / temperature
-/ top-k sampling, and opt-in sharded serving over a host mesh.  This module
+device, K-step decode dispatch (one host sync per K tokens) over a paged KV
+pool with prompts prefilled in chunks inside it, greedy / temperature /
+top-k sampling, and opt-in sharded serving over a host mesh.  This module
 only parses flags, builds (and optionally quantizes) the model, and calls
 ``Engine.serve``.
 
@@ -20,10 +20,10 @@ only parses flags, builds (and optionally quantizes) the model, and calls
   their ``launch/sharding`` specs (no device ever holds the whole tree) and
   places the cache the same way; quantized ``wq/data`` / ``wq/scale``
   leaves inherit the dense weight's layout.
-* **Speculative decoding** — ``--spec-draft METHOD --n-spec N`` (with
-  ``--paged``) quantizes the weights with METHOD and serves them as the
-  *draft* model: up to N drafted tokens per round, verified by one forward
-  of the full-precision weights (engine/spec.py).  Composes freely with
+* **Speculative decoding** — ``--spec-draft METHOD --n-spec N``
+  quantizes the weights with METHOD and serves them as the *draft* model:
+  up to N drafted tokens per round, verified by one forward of the
+  full-precision weights (engine/spec.py).  Composes freely with
   ``--prefix-cache`` / ``--chunk-size`` — speculative rounds, CoW prefix
   writes and chunk-prefill pieces are phases of one dispatch — so
   shared-prefix workloads measure draft fidelity too.  The round depth is
@@ -37,7 +37,7 @@ Usage (CPU-scale):
   PYTHONPATH=src python -m repro.launch.serve --arch glm4-9b --reduced \
       --requests 6 --batch 2 --prompt-len 16 --gen 8 --k-steps 8 \
       [--daq [--method daq] [--base-ckpt experiments/study/base]] \
-      [--paged --spec-draft daq --n-spec 4] \
+      [--block-size 8 --prefix-cache --spec-draft daq --n-spec 4] \
       [--temperature 0.8 --top-k 40 --top-p 0.95] [--mesh 1] \
       [--metrics-out metrics.json --trace-out trace.json]
 
@@ -130,30 +130,24 @@ def main() -> None:
     ap.add_argument("--mesh", type=int, default=0, metavar="MP",
                     help="serve sharded over a host mesh with "
                          "model-parallel size MP (0 = unsharded)")
-    ap.add_argument("--paged", action="store_true",
-                    help="paged KV cache: global block pool + per-slot "
-                         "block tables (memory proportional to live "
-                         "tokens, not slots * cache_len)")
     ap.add_argument("--block-size", type=int, default=8,
-                    help="tokens per KV block (with --paged)")
+                    help="tokens per block of the paged KV pool")
     ap.add_argument("--num-blocks", type=int, default=0,
-                    help="KV pool size in blocks; 0 = capacity parity "
-                         "with the contiguous cache (with --paged)")
+                    help="KV pool size in blocks; 0 = every slot's whole "
+                         "capacity (slots * ceil(cache_len / block_size))")
     ap.add_argument("--chunk-size", type=int, default=0,
-                    help="chunked prefill: prompts stream through the "
-                         "decode dispatch in pieces of this many tokens "
-                         "instead of stalling decode (with --paged)")
+                    help="prompt tokens prefilled per dispatch step (prompts "
+                         "stream through the decode dispatch instead of "
+                         "stalling decode); 0 = the engine's default")
     ap.add_argument("--prefix-cache", action="store_true",
                     help="refcounted prompt-prefix block sharing: matched "
                          "leading blocks are mapped instead of re-prefilled "
-                         "and stay cached (LRU) after requests finish "
-                         "(with --paged; implies chunked prefill)")
+                         "and stay cached (LRU) after requests finish")
     ap.add_argument("--spec-draft", default="", metavar="METHOD",
                     help="self-speculative decoding: quantize the weights "
                          "with METHOD (repro.quantize registry key, e.g. "
                          "daq | absmax) and use them as the draft model, "
-                         "verified by the full-precision weights (requires "
-                         "--paged)")
+                         "verified by the full-precision weights")
     ap.add_argument("--n-spec", type=int, default=4,
                     help="maximum drafted tokens per speculative round "
                          "(with --spec-draft; must be < --k-steps)")
@@ -197,9 +191,6 @@ def main() -> None:
         raise SystemExit("--spec-draft verifies quantized drafts against "
                          "the FULL-precision weights; it cannot combine "
                          "with --daq (which quantizes the served weights)")
-    if args.spec_draft and not args.paged:
-        raise SystemExit("--spec-draft requires --paged (speculative "
-                         "decoding rides the paged engine)")
 
     cfg = get_arch(args.arch)
     if args.reduced:
@@ -250,14 +241,12 @@ def main() -> None:
                             temperature=args.temperature
                             if args.temperature > 0 else 1.0,
                             top_k=args.top_k, top_p=args.top_p)
-    if (args.chunk_size or args.prefix_cache) and not args.paged:
-        raise SystemExit("--chunk-size/--prefix-cache require --paged")
     from repro.telemetry import MetricsRegistry, Tracer
     reg = MetricsRegistry()
     tracer = Tracer() if args.trace_out else None
     eng = Engine(model, params, slots=args.batch, cache_len=cache_len,
                  k_steps=args.k_steps, sampling=sp, mesh=mesh,
-                 paged=args.paged, block_size=args.block_size,
+                 block_size=args.block_size,
                  num_blocks=args.num_blocks, chunk_size=args.chunk_size,
                  prefix_cache=args.prefix_cache,
                  n_spec=args.n_spec if args.spec_draft else 0,
@@ -268,16 +257,14 @@ def main() -> None:
     outs, stats = eng.serve(prompts, gen_tokens=args.gen, return_stats=True)
     dt = time.time() - t0
     n_tok = sum(len(o) for o in outs)
-    kind = "paged" if args.paged else "contiguous"
+    kind = "paged"
     if args.prefix_cache:
         kind += "+prefix"
     if args.spec_draft:
         kind += f"+spec({args.spec_draft})"
-    extra = ""
-    if args.paged and (args.chunk_size or args.prefix_cache):
-        extra = (f", {stats['prefill_tokens']} prompt tokens prefilled"
-                 + (f" ({stats.get('prefix_hits', 0)} prefix-hit)"
-                    if args.prefix_cache else ""))
+    extra = (f", {stats['prefill_tokens']} prompt tokens prefilled"
+             + (f" ({stats['prefix_hits']} prefix-hit)"
+                if args.prefix_cache else ""))
     snap = reg.snapshot()
     # acceptance / depth come from the metrics snapshot (the device
     # counter tree feeds the spec.* gauges); non-spec runs report n/a
@@ -303,10 +290,9 @@ def main() -> None:
         tracer.save(args.trace_out)
         print(f"[serve] perfetto trace ({len(tracer.events)} events) -> "
               f"{args.trace_out}")
-    # jit cache size per entry point: dispatch/scatter entries hold at 1 in
-    # steady state; the prefill entries compile once per distinct prompt-
-    # length bucket.  Anything above that is an avoidable recompile — the
-    # signature contracts live in `python -m repro.staticcheck`.
+    # jit cache size per entry point: each holds at 1 in steady state.
+    # Anything above that is an avoidable recompile — the signature
+    # contracts live in `python -m repro.staticcheck`.
     counts = eng.compile_counts()
     total = sum(counts.values())
     print(f"compiles: {total} total ("

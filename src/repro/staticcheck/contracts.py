@@ -1,6 +1,6 @@
 """Pass 1: compile contracts for every jitted engine dispatch.
 
-For each configuration in the matrix (model family x cache mode x mesh)
+For each configuration in the matrix (model family x serving mode x mesh)
 this pass builds a real :class:`~repro.engine.Engine`, takes its
 entry-point registry (``Engine.entry_points()``), lowers **and compiles**
 each entry on canonical example inputs, and checks declarative contracts
@@ -21,9 +21,7 @@ signatures:
   and pinned in a checked-in manifest.  Drift means the entry's jit cache
   key changed (a new state field, a dtype change, a weak-type literal) —
   exactly the edits that cause silent per-call recompiles at runtime.
-  Entries whose runtime signatures legitimately vary (length-bucketed
-  prefill) still pin their canonical shape; runtime recompile *counts*
-  are watched by the serve CLI telemetry instead.
+  Runtime recompile *counts* are watched by the serve CLI telemetry.
 * **weak-type-signature** — no example-input leaf may carry a weak type:
   a weak-typed scalar in the argument tree retraces against every strong
   dtype it meets.
@@ -56,70 +54,51 @@ _F64_RE = re.compile(r"\bf64\[")
 
 @dataclass(frozen=True)
 class Case:
-    """One point of the config matrix."""
+    """One point of the config matrix.  Every engine serves through the
+    paged pool with chunked prefill at the engine's default chunk."""
     name: str
     arch: str = "glm4-9b"          # dense; mixtral = SWA+MoE,
                                    # mamba2 = SSM, jamba = hybrid
-    paged: bool = False
-    chunked: bool = False          # chunked prefill (implies paged)
-    prefix: bool = False           # prefix cache (implies chunked)
-    spec: bool = False             # speculative decoding (implies paged)
+    prefix: bool = False           # prefix cache
+    spec: bool = False             # speculative decoding
     mesh: bool = False             # sharded over a 2-device host mesh
     cache_len: int = 32
-    chunk_size: int = 0            # 0 -> engine default when chunked
 
     def engine_kwargs(self) -> dict:
-        paged = self.paged or self.chunked or self.prefix or self.spec
-        return dict(slots=2, cache_len=self.cache_len,
-                    k_steps=2, paged=paged, block_size=8,
-                    chunk_size=(self.chunk_size or (32 if self.chunked
-                                                    else 0)),
-                    prefix_cache=self.prefix,
+        return dict(slots=2, cache_len=self.cache_len, k_steps=2,
+                    block_size=8, prefix_cache=self.prefix,
                     n_spec=1 if self.spec else 0)
 
 
 # The reduced matrix CI runs on every push: the dense stack through every
-# cache mode, plus one mesh point.  The full matrix adds the other model
+# serving mode, plus one mesh point.  The full matrix adds the other model
 # families (SWA ring, MoE, SSM, hybrid) whose cache trees have different
-# leaf sets and therefore different donation/dtype surfaces.
+# leaf sets and therefore different donation/dtype surfaces.  The
+# speculative cases run both speculative entries (the pure rounds and the
+# rounds + in-scan prefill phase) under the same donation / fingerprint /
+# dtype contracts.
 QUICK_MATRIX = (
-    Case("dense-contig"),
-    Case("dense-paged", paged=True),
+    Case("dense-chunked"),
     Case("dense-prefix", prefix=True),
-    Case("dense-spec", spec=True),
-    Case("dense-paged-mesh", paged=True, mesh=True),
+    Case("dense-spec-chunked", spec=True),
+    Case("dense-chunked-mesh", mesh=True),
 )
 FULL_MATRIX = QUICK_MATRIX + (
-    Case("swa-moe-paged", arch="mixtral-8x22b", paged=True),
-    Case("ssm-paged", arch="mamba2-780m", paged=True),
-    Case("ssm-spec", arch="mamba2-780m", spec=True),
-    Case("hybrid-chunked", arch="jamba-v0.1-52b", chunked=True,
-         cache_len=64, chunk_size=32),
-    # the composed dispatch: speculation x chunked prefill and
-    # speculation x prefix cache run both speculative entries (the pure
-    # rounds and the rounds + in-scan prefill phase) under the same
-    # donation / fingerprint / dtype contracts
-    Case("dense-spec-chunked", spec=True, chunked=True),
+    Case("swa-moe-chunked", arch="mixtral-8x22b"),
+    Case("ssm-chunked", arch="mamba2-780m"),
+    Case("ssm-spec-chunked", arch="mamba2-780m", spec=True),
+    Case("hybrid-chunked", arch="jamba-v0.1-52b", cache_len=64),
     Case("dense-spec-prefix", spec=True, prefix=True),
-    Case("dense-contig-mesh", mesh=True),
 )
 MATRICES = {"quick": QUICK_MATRIX, "full": FULL_MATRIX}
 
 
 def case_entry_names(case: Case) -> tuple[str, ...]:
     """The entries this configuration actually exercises at runtime."""
-    if case.spec and (case.chunked or case.prefix):
+    if case.spec:
         return ("_dispatch_spec", "_dispatch_spec_chunk", "_admit_chunk",
                 "_evict")
-    if case.chunked or case.prefix:
-        return ("_dispatch", "_dispatch_chunk", "_admit_chunk", "_evict")
-    if case.spec:
-        return ("_dispatch_spec", "_scatter_paged", "_prefill_full",
-                "_prefill_padded")
-    if case.paged:
-        return ("_dispatch", "_scatter_paged", "_prefill_full",
-                "_prefill_padded")
-    return ("_dispatch", "_scatter", "_prefill_full", "_prefill_padded")
+    return ("_dispatch", "_dispatch_chunk", "_admit_chunk", "_evict")
 
 
 # -- engine + example-input construction ------------------------------------
@@ -155,28 +134,21 @@ def entry_args(eng, case: Case, name: str) -> tuple:
     import jax
     import jax.numpy as jnp
 
-    from repro.engine import paged as P
     from repro.engine.scheduler import init_slot_state
 
     cfg, model = eng.cfg, eng.model
     B = cfg.slots
     L = 8                       # canonical example prompt length
     key = jax.random.PRNGKey(0)
-    if cfg.paged:
-        cache = model.init_paged_cache(B, cfg.cache_len,
-                                       block_size=cfg.block_size,
-                                       num_blocks=eng._num_blocks)
-    else:
-        cache = model.init_cache(B, cfg.cache_len)
+    cache = model.init_paged_cache(B, cfg.cache_len,
+                                   block_size=cfg.block_size,
+                                   num_blocks=eng._num_blocks)
     if eng.mesh is not None:
         cache = eng._place_cache(cache)
     pcap = cfg.cache_len
-    state = init_slot_state(B, prompt_cap=pcap if cfg.chunk_size else 0,
-                            moe=eng._moe)
+    state = init_slot_state(B, prompt_cap=pcap, moe=eng._moe)
 
-    if name == "_dispatch":
-        return (eng.params, state, cache, key)
-    if name == "_dispatch_chunk":
+    if name in ("_dispatch", "_dispatch_chunk"):
         return (eng.params, state, cache, key)
     if name in ("_dispatch_spec", "_dispatch_spec_chunk"):
         # depth is the runtime dynamic-speculation-depth operand: a strong
@@ -193,42 +165,10 @@ def entry_args(eng, case: Case, name: str) -> tuple:
     if name == "_evict":
         return (cache, state,
                 jnp.full((eng._num_blocks,), -1, jnp.int32))
-
-    # admission entries: the part cache comes from an abstract prefill so
-    # no real forward runs during checking
-    toks1 = jnp.zeros((1, L), jnp.int32)
-    cl = eng._group_cache_len(L)
-    if name == "_prefill_full":
-        return (eng.params, toks1, cl)
-    if name == "_prefill_padded":
-        toks2 = jnp.zeros((2, L), jnp.int32)
-        lens2 = jnp.asarray([L, L - 3], jnp.int32)
-        return (eng.params, toks2, lens2, cl)
-    # abstract prefill (static cache_len closed over: eval_shape would
-    # otherwise trace it) -> part-cache ShapeDtypeStructs, no forward run
-    pf = eng.entry_points()["_prefill_full"]["fun"]
-    _, part = jax.eval_shape(lambda p, t: pf(p, t, cl), eng.params, toks1)
-    slots = jnp.zeros((1,), jnp.int32)
-    first = jax.ShapeDtypeStruct((1,), jnp.int32)
-    rem0 = jnp.int32(7)
-    if name == "_scatter":
-        return (cache, state, part, slots, first, rem0)
-    if name == "_scatter_paged":
-        lens = jnp.asarray([L], jnp.int32)
-        if model.cfg.sliding_window:
-            counts = jnp.full((1,), eng._mb, jnp.int32)
-        else:
-            counts = jnp.asarray(
-                [min(P.blocks_for(L, cfg.block_size), eng._mb)], jnp.int32)
-        return (cache, state, part, slots, lens, first, rem0, counts)
     raise KeyError(f"no example inputs for entry {name!r}")
 
 
 # -- contract checks --------------------------------------------------------
-
-def _dynamic_args(args: tuple, static_argnums: tuple) -> list:
-    return [a for i, a in enumerate(args) if i not in static_argnums]
-
 
 def _abstractify(leaf):
     """Aval of a leaf, or None for non-array statics riding in a tree."""

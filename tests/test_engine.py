@@ -1,5 +1,5 @@
-"""Serving-engine tests: greedy parity with the old host loop, slot reuse,
-sampler behavior, sharded smoke, quantized embedding gather."""
+"""Serving-engine tests: greedy parity with the contiguous-cache host loop,
+slot reuse, sampler behavior, sharded smoke, quantized embedding gather."""
 
 import jax
 import jax.numpy as jnp
@@ -28,7 +28,7 @@ def _prompts(spec, lens):
 
 
 def test_engine_greedy_token_exact_vs_host_loop():
-    """Device-resident K-step decode == old per-token host loop, token for
+    """Device-resident K-step dispatch == per-token host loop, token for
     token, including slot refills mid-stream."""
     cfg, model, params, spec = _setup()
     prompts = _prompts(spec, [10, 10, 10, 10, 10])
@@ -37,33 +37,34 @@ def test_engine_greedy_token_exact_vs_host_loop():
     eng = Engine(model, params, slots=2, cache_len=30, k_steps=3)
     outs, stats = eng.serve(prompts, gen_tokens=6, return_stats=True)
     assert outs == legacy
-    # at most one host sync per K decode steps (plus one per prefill group)
+    # one host sync per K-step dispatch: admission without a prefix cache
+    # reads nothing back
     assert stats["dispatches"] * eng.cfg.k_steps == stats["decode_steps"]
-    assert stats["host_syncs"] == stats["dispatches"] + stats["prefill_calls"]
+    assert stats["host_syncs"] == stats["dispatches"]
+    assert stats["prefill_calls"] == len(prompts)   # one admission each
 
 
 def test_engine_greedy_parity_unequal_lengths_padded_prefill():
-    """The single right-padded multi-slot prefill call stays token-exact
-    against the legacy batch-1-per-slot prefill."""
+    """Prompts of unequal lengths, prefilled side by side in one chunk
+    dispatch (each slot's piece padded to the chunk), stay token-exact
+    against the host loop's batch-1-per-slot prefill."""
     cfg, model, params, spec = _setup()
     prompts = _prompts(spec, [8, 13, 8, 11])
     eng = Engine(model, params, slots=3, cache_len=36, k_steps=2)
-    assert eng._can_pad  # dense causal stack -> padded path is in play
     legacy = serve_host_loop(model, params, prompts, batch=3, gen_tokens=5,
                              cache_len=36)
     assert eng.serve(prompts, gen_tokens=5) == legacy
 
 
 def test_engine_bucketed_prefill_for_ring_ssm_and_moe():
-    """SWA-ring, Mamba-state and MoE configs refuse padding (pad tokens
-    would corrupt ring slots / SSM state) and group prompts by exact
-    length — outputs still match the legacy loop."""
+    """SWA-ring, Mamba-state and MoE configs, prompts of unequal lengths
+    prefilled side by side in chunks (pad rows may touch no ring slot and
+    no SSM state) — outputs match the host loop."""
     for arch in ("mixtral-8x22b", "mamba2-780m",
                  "deepseek-v3"):               # moe, no sliding window
         cfg, model, params, spec = _setup(arch)
         prompts = _prompts(spec, [9, 12, 9])
         eng = Engine(model, params, slots=2, cache_len=34, k_steps=2)
-        assert not eng._can_pad
         legacy = serve_host_loop(model, params, prompts, batch=2,
                                  gen_tokens=4, cache_len=34)
         assert eng.serve(prompts, gen_tokens=4) == legacy
@@ -76,8 +77,8 @@ def test_engine_slot_reuse_more_requests_than_slots():
     outs, stats = eng.serve(prompts, gen_tokens=5, return_stats=True)
     assert len(outs) == 7
     assert all(len(o) == 5 for o in outs)
-    # 7 requests through 2 slots forces at least ceil(7/2) admission rounds
-    assert stats["prefill_calls"] >= 4
+    # 7 requests through 2 slots: one admission each
+    assert stats["prefill_calls"] == 7
     assert stats["tokens"] == 35
 
 
@@ -115,17 +116,18 @@ def test_engine_sampling_deterministic_under_fixed_seed():
 
 
 def test_engine_sharded_smoke_host_mesh():
-    """Sharded serving on a host mesh reproduces unsharded outputs, and
-    quantized storage/scale leaves inherit the dense weight's layout."""
+    """Sharded serving on a host mesh (the paged pool and chunked prefill
+    partitioned) reproduces the host loop's outputs, and quantized
+    storage/scale leaves inherit the dense weight's layout."""
     from repro.launch.mesh import make_host_mesh
     from repro.launch.sharding import params_shardings
     from repro.quantize import quantize
 
     cfg, model, params, spec = _setup()
-    prompts = _prompts(spec, [10, 10, 10])
+    prompts = _prompts(spec, [10, 13, 10])
     mesh = make_host_mesh()
-    ref = Engine(model, params, slots=2, cache_len=26,
-                 k_steps=2).serve(prompts, gen_tokens=4)
+    ref = serve_host_loop(model, params, prompts, batch=2, gen_tokens=4,
+                          cache_len=26)
     eng = Engine(model, params, slots=2, cache_len=26, k_steps=2, mesh=mesh)
     assert eng.serve(prompts, gen_tokens=4) == ref
 
@@ -141,8 +143,8 @@ def test_engine_sharded_smoke_host_mesh():
     assert tuple(q) == tuple(d)
     # quantized params also serve sharded
     qeng = Engine(model, qparams, slots=2, cache_len=26, k_steps=2, mesh=mesh)
-    qref = Engine(model, qparams, slots=2, cache_len=26,
-                  k_steps=2).serve(prompts, gen_tokens=4)
+    qref = serve_host_loop(model, qparams, prompts, batch=2, gen_tokens=4,
+                           cache_len=26)
     assert qeng.serve(prompts, gen_tokens=4) == qref
 
 

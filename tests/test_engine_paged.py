@@ -1,7 +1,7 @@
-"""Paged KV-cache tests: greedy token-exactness against the contiguous
-engine AND the legacy host loop under adversarial workloads (mixed prompt
-lengths, interleaved arrivals, slot churn, tight pools), allocator unit
-invariants, and config validation."""
+"""Paged KV-cache tests: greedy token-exactness against the contiguous-cache
+host loop under adversarial workloads (mixed prompt lengths, interleaved
+arrivals, slot churn, tight pools), allocator unit invariants, and config
+validation."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,7 +13,8 @@ from repro.configs import get_arch, reduced
 from repro.data import LanguageSpec, sample_batch
 from repro.engine import (Engine, blocks_for, init_block_state,
                           release_slots, serve_host_loop)
-from repro.engine.paged import NEG, alloc_admit, alloc_step, gather_blocks
+from repro.engine.engine import default_chunk_size
+from repro.engine.paged import NEG, alloc_step, gather_blocks
 from repro.models import build_model
 
 KEY = jax.random.PRNGKey(0)
@@ -39,7 +40,7 @@ def _prompts(spec, lens, seed=0):
 
 
 # ---------------------------------------------------------------------------
-# Token-exactness: paged == contiguous == legacy host loop
+# Token-exactness: paged engine == contiguous-cache host loop
 # ---------------------------------------------------------------------------
 
 def test_paged_token_exact_dense_mixed_lengths():
@@ -49,15 +50,11 @@ def test_paged_token_exact_dense_mixed_lengths():
     prompts = _prompts(spec, [10, 25, 6, 17, 9, 12])
     legacy = serve_host_loop(model, params, prompts, batch=2, gen_tokens=6,
                              cache_len=40)
-    contig = Engine(model, params, slots=2, cache_len=40,
-                    k_steps=3).serve(prompts, gen_tokens=6)
     peng = Engine(model, params, slots=2, cache_len=40, k_steps=3,
-                  paged=True, block_size=8)
+                  block_size=8)
     pout, stats = peng.serve(prompts, gen_tokens=6, return_stats=True)
-    assert contig == legacy
-    assert pout == contig
-    # the paged pool at capacity parity is the same order of bytes as the
-    # contiguous cache (block-rounding + one trash block of overhead)
+    assert pout == legacy
+    # the pool holds every slot's capacity in blocks plus one trash block
     assert stats["cache_bytes"] > 0
 
 
@@ -71,7 +68,7 @@ def test_paged_token_exact_swa_ring():
     legacy = serve_host_loop(model, params, prompts, batch=2, gen_tokens=5,
                              cache_len=34)
     peng = Engine(model, params, slots=2, cache_len=34, k_steps=2,
-                  paged=True, block_size=8)
+                  block_size=8)
     assert peng.serve(prompts, gen_tokens=5) == legacy
 
 
@@ -83,11 +80,11 @@ def test_paged_routes_around_contiguous_state(arch):
     per-slot dense; outputs still match."""
     cfg, model, params, spec = _setup(arch)
     prompts = _prompts(spec, [9, 12, 9])
-    contig = Engine(model, params, slots=2, cache_len=34,
-                    k_steps=2).serve(prompts, gen_tokens=4)
+    legacy = serve_host_loop(model, params, prompts, batch=2, gen_tokens=4,
+                             cache_len=34)
     pout = Engine(model, params, slots=2, cache_len=34, k_steps=2,
-                  paged=True, block_size=8).serve(prompts, gen_tokens=4)
-    assert pout == contig, arch
+                  block_size=8).serve(prompts, gen_tokens=4)
+    assert pout == legacy, arch
 
 
 def test_paged_tight_pool_serializes_but_stays_exact():
@@ -95,44 +92,29 @@ def test_paged_tight_pool_serializes_but_stays_exact():
     admission; outputs stay token-exact and every request completes."""
     cfg, model, params, spec = _setup()
     prompts = _prompts(spec, [20, 20, 20, 20])
-    contig = Engine(model, params, slots=2, cache_len=32,
-                    k_steps=2).serve(prompts, gen_tokens=5)
+    legacy = serve_host_loop(model, params, prompts, batch=2, gen_tokens=5,
+                             cache_len=32)
     tight = Engine(model, params, slots=2, cache_len=32, k_steps=2,
-                   paged=True, block_size=8, num_blocks=4)
+                   block_size=8, num_blocks=4)
     assert blocks_for(20 + 5 - 1, 8) == 3        # 3 of 4 blocks per request
     outs, stats = tight.serve(prompts, gen_tokens=5, return_stats=True)
-    assert outs == contig
+    assert outs == legacy
     # one admission round per request: the pool can never hold two
     assert stats["prefill_calls"] == 4
 
 
-def test_paged_overlong_prompt_does_not_leak_blocks():
-    """A prompt longer than the per-slot capacity only keeps its first
-    ``cache_len`` rows (the contiguous cache drops the overflow the same
-    way); the allocator must debit exactly the blocks the scatter places —
-    an unclamped count would leak pool blocks and later hand out
-    duplicates.  Serving many such prompts through a capacity-parity pool
-    still terminates with every request answered."""
-    cfg, model, params, spec = _setup()
-    prompts = _prompts(spec, [24, 24, 24, 24])       # cap is 16 rows
-    contig = Engine(model, params, slots=2, cache_len=16,
-                    k_steps=2).serve(prompts, gen_tokens=3)
-    pout = Engine(model, params, slots=2, cache_len=16, k_steps=2,
-                  paged=True, block_size=8).serve(prompts, gen_tokens=3)
-    assert [len(o) for o in pout] == [3] * 4
-    assert pout == contig
-
-
 def test_paged_gen_tokens_one_releases_blocks_at_admission():
-    """gen_tokens=1 finishes a slot inside the admission scatter; its
-    blocks must come back so follow-up requests are not starved."""
+    """gen_tokens=1 finishes a slot in the scan step its last prompt chunk
+    lands; its blocks must come back so follow-up requests are not
+    starved."""
     cfg, model, params, spec = _setup()
     prompts = _prompts(spec, [16] * 6)
-    contig = Engine(model, params, slots=2, cache_len=24,
-                    k_steps=2).serve(prompts, gen_tokens=1)
+    legacy = serve_host_loop(model, params, prompts, batch=2, gen_tokens=1,
+                             cache_len=24)
+    assert [len(o) for o in legacy] == [1] * 6
     tight = Engine(model, params, slots=2, cache_len=24, k_steps=2,
-                   paged=True, block_size=8, num_blocks=4)
-    assert tight.serve(prompts, gen_tokens=1) == contig
+                   block_size=8, num_blocks=4)
+    assert tight.serve(prompts, gen_tokens=1) == legacy
 
 
 # ---------------------------------------------------------------------------
@@ -151,10 +133,10 @@ def test_paged_gen_tokens_one_releases_blocks_at_admission():
 def test_chunked_prefill_token_exact_matrix(arch, moe, chunk, lens,
                                             cache_len):
     """Chunked prefill (prompts streaming through the decode dispatch in
-    chunk_size pieces) must be token-exact vs the one-shot-prefill
-    contiguous engine on every family: dense, SWA-ring (chunks wrap the
-    paged ring), MoE (dropless on every path: the expert layer counts its
-    assignments), pure SSM and hybrid (state threaded chunk-to-chunk on the SSD grid).
+    chunk_size pieces) must be token-exact vs the one-shot-prefill host
+    loop on every family: dense, SWA-ring (chunks wrap the paged ring),
+    MoE (dropless on every path: the expert layer counts its assignments),
+    pure SSM and hybrid (state threaded chunk-to-chunk on the SSD grid).
     Prompts cross chunk AND block boundaries and mix with in-flight
     decode (slot churn: more requests than slots).
 
@@ -166,41 +148,74 @@ def test_chunked_prefill_token_exact_matrix(arch, moe, chunk, lens,
     the persistent cache stays exact with sharing inert."""
     cfg, model, params, spec = _setup(arch)
     prompts = _prompts(spec, lens)
-    contig = Engine(model, params, slots=2, cache_len=cache_len,
-                    k_steps=2).serve(prompts, gen_tokens=4)
+    legacy = serve_host_loop(model, params, prompts, batch=2, gen_tokens=4,
+                             cache_len=cache_len)
     peng = Engine(model, params, slots=2, cache_len=cache_len,
-                  k_steps=2, paged=True, block_size=8, chunk_size=chunk,
+                  k_steps=2, block_size=8, chunk_size=chunk,
                   prefix_cache=True, check_invariants=True)
     outs, stats = peng.serve(prompts, gen_tokens=4, return_stats=True)
-    assert outs == contig, arch                                  # cold
+    assert outs == legacy, arch                                  # cold
     assert (stats["counters"].get("moe_assigned", 0) > 0) == moe, arch
-    assert peng.serve(prompts, gen_tokens=4) == contig, arch   # warm
+    assert peng.serve(prompts, gen_tokens=4) == legacy, arch   # warm
 
 
 def test_chunked_prefill_without_prefix_cache_exact():
     """Plain chunked prefill (no sharing, cow=False dispatch) on dense."""
     cfg, model, params, spec = _setup()
     prompts = _prompts(spec, [10, 25, 6, 17])
-    contig = Engine(model, params, slots=2, cache_len=40,
-                    k_steps=2).serve(prompts, gen_tokens=4)
+    legacy = serve_host_loop(model, params, prompts, batch=2, gen_tokens=4,
+                             cache_len=40)
     cout = Engine(model, params, slots=2, cache_len=40, k_steps=2,
-                  paged=True, block_size=8, chunk_size=8,
+                  block_size=8, chunk_size=8,
                   check_invariants=True).serve(prompts, gen_tokens=4)
-    assert cout == contig
+    assert cout == legacy
 
 
 def test_chunked_validation_errors():
+    """The contiguous cache is refused by name; a prompt longer than the
+    per-slot capacity is refused at serve() before any block moves (the
+    engine serves it whole or not at all), and the engine stays usable;
+    a chunk off the SSD grid and a chunk wider than the ring are
+    refused."""
     cfg, model, params, spec = _setup()
-    with pytest.raises(ValueError, match="need paged"):
-        Engine(model, params, slots=2, cache_len=32, chunk_size=8)
+    with pytest.raises(ValueError, match="contiguous cache was removed"):
+        Engine(model, params, slots=2, cache_len=32, paged=False)
+    eng = Engine(model, params, slots=2, cache_len=16, k_steps=2,
+                 block_size=8, check_invariants=True)
+    ok = _prompts(spec, [12, 9])
     with pytest.raises(ValueError, match="exceeds cache_len"):
-        Engine(model, params, slots=2, cache_len=16, paged=True,
-               block_size=8, chunk_size=8).serve(
-                   _prompts(spec, [24]), gen_tokens=2)
+        eng.serve(_prompts(spec, [24]) + ok, gen_tokens=3)
+    assert eng.serve(ok, gen_tokens=3) == serve_host_loop(
+        model, params, ok, batch=2, gen_tokens=3, cache_len=16)
     cfg, model, params, spec = _setup("jamba-v0.1-52b")
     with pytest.raises(ValueError, match="multiple of ssm_chunk"):
-        Engine(model, params, slots=2, cache_len=64, paged=True,
+        Engine(model, params, slots=2, cache_len=64,
                block_size=8, chunk_size=8)
+    cfg, model, params, spec = _setup("mixtral-8x22b")
+    with pytest.raises(ValueError, match="exceeds the sliding window"):
+        Engine(model, params, slots=2, cache_len=34, block_size=8,
+               chunk_size=32)
+
+
+@pytest.mark.parametrize("arch, chunk", [
+    ("glm4-9b", 4 * 8),            # dense: four blocks
+    ("mixtral-8x22b", 16),         # SWA: four blocks, capped at the ring
+    ("mamba2-780m", 32),           # SSM state: the SSD chunk
+], ids=["dense", "swa", "ssm"])
+def test_default_chunk_size_is_derived_in_one_place(arch, chunk):
+    """``chunk_size`` 0 derives the piece from the model and the block
+    size; an explicit value equal to it configures the same engine."""
+    cfg, model, params, spec = _setup(arch)
+    assert default_chunk_size(cfg, 8) == chunk
+    eng = Engine(model, params, slots=2, cache_len=34, k_steps=2,
+                 block_size=8)
+    assert eng.cfg.chunk_size == chunk
+    explicit = Engine(model, params, slots=2, cache_len=34, k_steps=2,
+                      block_size=8, chunk_size=chunk)
+    assert explicit.cfg == eng.cfg
+    prompts = _prompts(spec, [9, 30, 12])
+    assert eng.serve(prompts, gen_tokens=3) == serve_host_loop(
+        model, params, prompts, batch=2, gen_tokens=3, cache_len=34)
 
 
 # ---------------------------------------------------------------------------
@@ -213,8 +228,8 @@ def test_paged_stress_randomized(seed):
     """Adversarial workload sweep: random prompt lengths (some crossing
     block boundaries, some below one block), random request count vs slot
     count (interleaved arrivals + slot churn), random k_steps/gen and a
-    randomly tightened pool.  Paged output must be token-exact against
-    BOTH the contiguous engine and the legacy host loop."""
+    randomly tightened pool.  Paged output must be token-exact against the
+    contiguous-cache host loop."""
     rng = np.random.RandomState(seed)
     cfg, model, params, spec = _setup()
     slots = int(rng.randint(2, 4))
@@ -228,17 +243,14 @@ def test_paged_stress_randomized(seed):
 
     legacy = serve_host_loop(model, params, prompts, batch=slots,
                              gen_tokens=gen, cache_len=cache_len)
-    contig = Engine(model, params, slots=slots, cache_len=cache_len,
-                    k_steps=k_steps).serve(prompts, gen_tokens=gen)
     mb = blocks_for(cache_len, 8)
     full = slots * mb
     lo = max(blocks_for(L + gen - 1, 8) for L in lens)
     num_blocks = int(rng.randint(lo, full + 1))  # sometimes starved pool
     pout = Engine(model, params, slots=slots, cache_len=cache_len,
-                  k_steps=k_steps, paged=True, block_size=8,
+                  k_steps=k_steps, block_size=8,
                   num_blocks=num_blocks).serve(prompts, gen_tokens=gen)
-    assert contig == legacy
-    assert pout == contig
+    assert pout == legacy
 
 
 # ---------------------------------------------------------------------------
@@ -286,24 +298,6 @@ def test_block_allocator_invariants():
     assert not np.any(np.asarray(b3["slot_active"]))
 
 
-def test_alloc_admit_counts_and_trash_padding():
-    B, MB, NB = 4, 6, 12
-    bstate = init_block_state(B, MB, NB)
-    slots = jnp.asarray([1, 3], jnp.int32)
-    counts = jnp.asarray([2, 5], jnp.int32)
-    bstate, wids = alloc_admit(bstate, slots, counts, nbl=5)
-    assert wids.shape == (2, 5)
-    w = np.asarray(wids)
-    assert np.all(w[0, 2:] == NB)                 # padded with trash
-    assert np.all(w[1] < NB)
-    ids = np.concatenate([w[0, :2], w[1]])
-    assert len(set(ids.tolist())) == 7            # all distinct
-    assert int(bstate["n_free"]) == NB - 7
-    tbl = np.asarray(bstate["tbl"])
-    assert np.all(tbl[0] == NEG) and np.all(tbl[2] == NEG)
-    assert set(tbl[1][tbl[1] >= 0].tolist()) == set(w[0, :2].tolist())
-
-
 @pytest.mark.parametrize("layer", [0, 2])
 def test_gather_blocks_reproduces_linear_layout(layer):
     """Blocks of the stacked pool's ``layer`` come out in table order."""
@@ -329,14 +323,14 @@ def test_gather_blocks_reproduces_linear_layout(layer):
 def test_paged_validation_errors():
     cfg, model, params, spec = _setup("mixtral-8x22b")
     with pytest.raises(ValueError, match="cache_len >= sliding_window"):
-        Engine(model, params, slots=2, cache_len=8, k_steps=2, paged=True,
+        Engine(model, params, slots=2, cache_len=8, k_steps=2,
                block_size=8).serve(_prompts(spec, [4]), gen_tokens=2)
     with pytest.raises(ValueError, match="must divide the sliding window"):
-        Engine(model, params, slots=2, cache_len=34, k_steps=2, paged=True,
+        Engine(model, params, slots=2, cache_len=34, k_steps=2,
                block_size=6).serve(_prompts(spec, [4]), gen_tokens=2)
 
     cfg, model, params, spec = _setup()
     eng = Engine(model, params, slots=2, cache_len=64, k_steps=2,
-                 paged=True, block_size=8, num_blocks=2)
+                 block_size=8, num_blocks=2)
     with pytest.raises(ValueError, match="blocks but the pool"):
         eng.serve(_prompts(spec, [40]), gen_tokens=4)

@@ -19,14 +19,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypothesis import given, settings
+from hypothesis import example, given, settings
 from hypothesis import strategies as st
 
 from repro.configs import get_arch, reduced
 from repro.data import LanguageSpec, sample_batch
 from repro.engine import (Engine, PrefixIndex, admit_slot, alloc_step,
                           blocks_for, chain_hashes, init_block_state,
-                          release_refs, release_slots, span_targets)
+                          release_refs, release_slots, serve_host_loop,
+                          span_targets)
 from repro.engine.paged import NEG
 from repro.models import build_model
 
@@ -261,17 +262,17 @@ def test_admission_evicts_cached_blocks_instead_of_deadlocking():
     a = jnp.concatenate([common, _tokens(spec, 6, seed=6)])
     b = jnp.concatenate([common, _tokens(spec, 6, seed=7)])
     c = _tokens(spec, 22, seed=8)                 # unrelated content
-    contig = Engine(model, params, slots=1, cache_len=32,
-                    k_steps=2).serve([a, b, c], gen_tokens=4)
+    legacy = serve_host_loop(model, params, [a, b, c], batch=1,
+                             gen_tokens=4, cache_len=32)
     # pool of 5 blocks: one 22-token request demands 4 (2 cached-prefix
     # holds + tail block + decode growth); after a+b the index still holds
     # a's 2-block prefix, so admitting c (2 new holds + 2 slot blocks on
     # top of the 2 cached) exceeds the pool and must evict LRU entries
     eng = Engine(model, params, slots=1, cache_len=32, k_steps=2,
-                 paged=True, block_size=8, num_blocks=5, prefix_cache=True,
+                 block_size=8, num_blocks=5, prefix_cache=True,
                  chunk_size=8, check_invariants=True)
     outs, stats = eng.serve([a, b, c], gen_tokens=4, return_stats=True)
-    assert outs == contig
+    assert outs == legacy
     assert stats["prefix_evictions"] > 0
     assert stats["prefix_hits"] > 0               # b still hit a's prefix
 
@@ -285,18 +286,18 @@ def test_warm_partial_hit_on_saturated_pool_degrades_not_crashes():
     cfg, model, params, spec = _setup()
     long = _tokens(spec, 24, seed=51)[:24]        # exactly 3 full blocks
     short = long[:20]                             # partial hit in block 2
-    contig_l = Engine(model, params, slots=1, cache_len=24,
-                      k_steps=2).serve([long], gen_tokens=8)
-    contig_s = Engine(model, params, slots=1, cache_len=24,
-                      k_steps=2).serve([short], gen_tokens=8)
+    legacy_l = serve_host_loop(model, params, [long], batch=1,
+                               gen_tokens=8, cache_len=24)
+    legacy_s = serve_host_loop(model, params, [short], batch=1,
+                               gen_tokens=8, cache_len=24)
     eng = Engine(model, params, slots=1, cache_len=24, k_steps=2,
-                 paged=True, block_size=8, num_blocks=3, prefix_cache=True,
+                 block_size=8, num_blocks=3, prefix_cache=True,
                  chunk_size=8, check_invariants=True)
-    assert eng.serve([long], gen_tokens=8) == contig_l
+    assert eng.serve([long], gen_tokens=8) == legacy_l
     # all 3 pool blocks are now index-held; the partial hit would pin all
     # of them and still need a CoW spare — must evict its own LRU match
     outs, stats = eng.serve([short], gen_tokens=8, return_stats=True)
-    assert outs == contig_s
+    assert outs == legacy_s
     assert stats["prefix_evictions"] > 0
 
 
@@ -307,7 +308,7 @@ def test_serve_after_the_first_compiles_nothing_when_it_evicts():
     slot)."""
     cfg, model, params, spec = _setup()
     eng = Engine(model, params, slots=1, cache_len=32, k_steps=2,
-                 paged=True, block_size=8, num_blocks=5, prefix_cache=True,
+                 block_size=8, num_blocks=5, prefix_cache=True,
                  chunk_size=8)
     _, first = eng.serve([_tokens(spec, 22, seed=11)], gen_tokens=4,
                          return_stats=True)
@@ -329,16 +330,16 @@ def test_prefix_warm_hits_are_token_exact_and_cheaper():
     sysp = _tokens(spec, 20, seed=11)             # 2.5 blocks of 8
     prompts = [jnp.concatenate([sysp, _tokens(spec, 6, seed=20 + i)])
                for i in range(3)]
-    contig = Engine(model, params, slots=2, cache_len=48,
-                    k_steps=3).serve(prompts, gen_tokens=6)
+    legacy = serve_host_loop(model, params, prompts, batch=2, gen_tokens=6,
+                             cache_len=48)
 
     eng = Engine(model, params, slots=2, cache_len=48, k_steps=3,
-                 paged=True, block_size=8, num_blocks=24, prefix_cache=True,
+                 block_size=8, num_blocks=24, prefix_cache=True,
                  chunk_size=8, check_invariants=True)
     cold, cs = eng.serve(prompts, gen_tokens=6, return_stats=True)
     warm, ws = eng.serve(prompts, gen_tokens=6, return_stats=True)
-    assert cold == contig                         # in-run sharing is exact
-    assert warm == contig                         # cross-run hits are exact
+    assert cold == legacy                         # in-run sharing is exact
+    assert warm == legacy                         # cross-run hits are exact
     assert ws["prefill_tokens"] < cs["prefill_tokens"]
     assert ws["prefix_hits"] > cs["prefix_hits"]
 
@@ -351,17 +352,17 @@ def test_partial_block_hit_copy_on_write_exact():
     cfg, model, params, spec = _setup()
     long = _tokens(spec, 24, seed=31)             # 3 full blocks
     short = long[:20]                             # partial hit in block 2
-    contig_l = Engine(model, params, slots=1, cache_len=40,
-                      k_steps=2).serve([long], gen_tokens=5)
-    contig_s = Engine(model, params, slots=1, cache_len=40,
-                      k_steps=2).serve([short], gen_tokens=5)
+    legacy_l = serve_host_loop(model, params, [long], batch=1,
+                               gen_tokens=5, cache_len=40)
+    legacy_s = serve_host_loop(model, params, [short], batch=1,
+                               gen_tokens=5, cache_len=40)
     eng = Engine(model, params, slots=1, cache_len=40, k_steps=2,
-                 paged=True, block_size=8, num_blocks=12, prefix_cache=True,
+                 block_size=8, num_blocks=12, prefix_cache=True,
                  chunk_size=8, check_invariants=True)
-    assert eng.serve([long], gen_tokens=5) == contig_l
-    assert eng.serve([short], gen_tokens=5) == contig_s   # CoW path
+    assert eng.serve([long], gen_tokens=5) == legacy_l
+    assert eng.serve([short], gen_tokens=5) == legacy_s   # CoW path
     assert eng._index.partial_hits > 0
-    assert eng.serve([long], gen_tokens=5) == contig_l    # rows uncorrupted
+    assert eng.serve([long], gen_tokens=5) == legacy_l    # rows uncorrupted
 
 
 def test_prefix_gen_tokens_one_releases_and_still_caches():
@@ -370,13 +371,13 @@ def test_prefix_gen_tokens_one_releases_and_still_caches():
     release and serve the next request's hits."""
     cfg, model, params, spec = _setup()
     prompts = [_tokens(spec, 16, seed=41)] * 3
-    contig = Engine(model, params, slots=2, cache_len=24,
-                    k_steps=2).serve(prompts, gen_tokens=1)
+    legacy = serve_host_loop(model, params, prompts, batch=2, gen_tokens=1,
+                             cache_len=24)
     eng = Engine(model, params, slots=2, cache_len=24, k_steps=2,
-                 paged=True, block_size=8, num_blocks=8, prefix_cache=True,
+                 block_size=8, num_blocks=8, prefix_cache=True,
                  chunk_size=8, check_invariants=True)
     outs, stats = eng.serve(prompts, gen_tokens=1, return_stats=True)
-    assert outs == contig
+    assert outs == legacy
     assert stats["prefix_hits"] > 0
 
 
@@ -386,13 +387,15 @@ def test_prefix_gen_tokens_one_releases_and_still_caches():
 
 @settings(max_examples=3, deadline=None)
 @given(st.integers(min_value=0, max_value=10**6))
+@example(378889)    # holds dropped in the last dispatch's host work free
+@example(961799)    # blocks after the counters were fetched
 def test_prefix_stress_randomized(seed):
     """Random prompt families (shared prefixes of random depth), more
     requests than slots (interleaved arrivals + slot churn), random
     chunk/k_steps/gen and a randomly tightened pool — with the allocator
     conservation invariants asserted after every admission and dispatch
-    (check_invariants), outputs token-exact vs the contiguous engine, both
-    cold and warm."""
+    (check_invariants), outputs token-exact vs the contiguous-cache host
+    loop, both cold and warm."""
     rng = np.random.RandomState(seed)
     cfg, model, params, spec = _setup()
     slots = int(rng.randint(2, 4))
@@ -412,17 +415,17 @@ def test_prefix_stress_randomized(seed):
     k_steps = int(rng.randint(1, 4))
     chunk = int(rng.choice([4, 8, 16]))
     cache_len = max(lens) + gen + int(rng.randint(0, 6))
-    contig = Engine(model, params, slots=slots, cache_len=cache_len,
-                    k_steps=k_steps).serve(prompts, gen_tokens=gen)
+    legacy = serve_host_loop(model, params, prompts, batch=slots,
+                             gen_tokens=gen, cache_len=cache_len)
     lo = max(blocks_for(min(L + gen - 1, cache_len), 8) + 1 for L in lens)
     full = slots * blocks_for(cache_len, 8) + 4
     num_blocks = int(rng.randint(lo, full + 1))   # sometimes starved pool
     eng = Engine(model, params, slots=slots, cache_len=cache_len,
-                 k_steps=k_steps, paged=True, block_size=8,
+                 k_steps=k_steps, block_size=8,
                  num_blocks=num_blocks, prefix_cache=True, chunk_size=chunk,
                  check_invariants=True)
     outs, stats = eng.serve(prompts, gen_tokens=gen, return_stats=True)
-    assert outs == contig
+    assert outs == legacy
     # device-counter conservation: after the drain the only blocks out of
     # the pool are the prefix index's holds ("popped == released + live")
     c = stats["counters"]
@@ -432,7 +435,7 @@ def test_prefix_stress_randomized(seed):
     assert c["tokens"] == stats["tokens"]
     held0 = len(eng._hold_blocks)
     outs, stats = eng.serve(prompts, gen_tokens=gen, return_stats=True)
-    assert outs == contig                                 # warm pass
+    assert outs == legacy                                 # warm pass
     c = stats["counters"]
     # warm counters re-zero and re-baseline on the blocks held at start
     assert (held0 + c["blocks_popped"] - c["blocks_released"]

@@ -84,10 +84,10 @@ def test_spec_token_exact_matrix():
         cfg, model, params, draft, _, spec = _setup(arch)
         prompts = _prompts(spec, lens)
         base = Engine(model, params, slots=2, cache_len=cache_len,
-                      k_steps=3, paged=True, block_size=8
+                      k_steps=3, block_size=8
                       ).serve(prompts, gen_tokens=5)
         seng = Engine(model, params, slots=2, cache_len=cache_len,
-                      k_steps=3, paged=True, block_size=8, n_spec=2,
+                      k_steps=3, block_size=8, n_spec=2,
                       draft_params=draft, check_invariants=True)
         outs, stats = seng.serve(prompts, gen_tokens=5, return_stats=True)
         assert outs == base, arch
@@ -117,10 +117,10 @@ def test_spec_composed_token_exact_matrix():
         cfg, model, params, draft, _, spec = _setup(arch)
         prompts = _shared_prompts(spec, lens, share=16)
         base = Engine(model, params, slots=2, cache_len=cache_len,
-                      k_steps=3, paged=True, block_size=8, chunk_size=chunk,
+                      k_steps=3, block_size=8, chunk_size=chunk,
                       prefix_cache=True).serve(prompts, gen_tokens=5)
         seng = Engine(model, params, slots=2, cache_len=cache_len,
-                      k_steps=3, paged=True, block_size=8, chunk_size=chunk,
+                      k_steps=3, block_size=8, chunk_size=chunk,
                       prefix_cache=True, n_spec=2, draft_params=draft,
                       check_invariants=True)
         outs, stats = seng.serve(prompts, gen_tokens=5, return_stats=True)
@@ -142,11 +142,11 @@ def test_spec_composed_warm_prefix_hit_mid_speculation():
     cfg, model, params, draft, _, spec = _setup()
     prompts = _shared_prompts(spec, [18, 25, 21], share=16)
     base = Engine(model, params, slots=2, cache_len=40, k_steps=3,
-                  paged=True, block_size=8, chunk_size=8, prefix_cache=True)
+                  block_size=8, chunk_size=8, prefix_cache=True)
     b1 = base.serve(prompts, gen_tokens=5)
     b2 = base.serve(prompts, gen_tokens=5)
     seng = Engine(model, params, slots=2, cache_len=40, k_steps=3,
-                  paged=True, block_size=8, chunk_size=8, prefix_cache=True,
+                  block_size=8, chunk_size=8, prefix_cache=True,
                   n_spec=2, draft_params=draft, check_invariants=True)
     o1, s1 = seng.serve(prompts, gen_tokens=5, return_stats=True)
     o2, s2 = seng.serve(prompts, gen_tokens=5, return_stats=True)
@@ -163,10 +163,10 @@ def test_spec_composed_exact_for_garbage_draft():
     cfg, model, params, _, bad, spec = _setup()
     prompts = _shared_prompts(spec, [18, 21, 18], share=16)
     base = Engine(model, params, slots=2, cache_len=40, k_steps=4,
-                  paged=True, block_size=8, chunk_size=8, prefix_cache=True
+                  block_size=8, chunk_size=8, prefix_cache=True
                   ).serve(prompts, gen_tokens=6)
     outs, stats = Engine(model, params, slots=2, cache_len=40, k_steps=4,
-                         paged=True, block_size=8, chunk_size=8,
+                         block_size=8, chunk_size=8,
                          prefix_cache=True, n_spec=2, draft_params=bad,
                          check_invariants=True
                          ).serve(prompts, gen_tokens=6, return_stats=True)
@@ -183,9 +183,9 @@ def test_spec_exact_for_any_draft_even_garbage():
     cfg, model, params, _, bad, spec = _setup()
     prompts = _prompts(spec, [10, 13, 6, 9])
     base = Engine(model, params, slots=2, cache_len=32, k_steps=4,
-                  paged=True, block_size=8).serve(prompts, gen_tokens=6)
+                  block_size=8).serve(prompts, gen_tokens=6)
     outs, stats = Engine(model, params, slots=2, cache_len=32, k_steps=4,
-                         paged=True, block_size=8, n_spec=2,
+                         block_size=8, n_spec=2,
                          draft_params=bad, check_invariants=True
                          ).serve(prompts, gen_tokens=6, return_stats=True)
     assert outs == base
@@ -201,10 +201,10 @@ def test_spec_budget_clamp_edges():
     prompts = _prompts(spec, [10, 13, 6, 9])
     for gen in (1, 2, 4):
         base = Engine(model, params, slots=2, cache_len=32, k_steps=5,
-                      paged=True, block_size=8).serve(prompts,
+                      block_size=8).serve(prompts,
                                                       gen_tokens=gen)
         outs = Engine(model, params, slots=2, cache_len=32, k_steps=5,
-                      paged=True, block_size=8, n_spec=3,
+                      block_size=8, n_spec=3,
                       draft_params=draft, check_invariants=True
                       ).serve(prompts, gen_tokens=gen)
         assert outs == base, gen
@@ -218,10 +218,10 @@ def test_spec_tight_pool_with_reservation_slack():
     cfg, model, params, draft, _, spec = _setup()
     prompts = _prompts(spec, [20, 20, 20, 20])
     base = Engine(model, params, slots=2, cache_len=32, k_steps=4,
-                  paged=True, block_size=8).serve(prompts, gen_tokens=5)
+                  block_size=8).serve(prompts, gen_tokens=5)
     need = blocks_for(20 + 5 - 1 + 2, 8)          # lifetime + n_spec slack
     tight = Engine(model, params, slots=2, cache_len=32, k_steps=4,
-                   paged=True, block_size=8, num_blocks=need, n_spec=2,
+                   block_size=8, num_blocks=need, n_spec=2,
                    draft_params=draft, check_invariants=True)
     outs, stats = tight.serve(prompts, gen_tokens=5, return_stats=True)
     assert outs == base
@@ -236,7 +236,7 @@ def test_spec_sampled_mode_deterministic_and_complete():
     prompts = _prompts(spec, [10, 13, 6])
     sp = SamplingParams(greedy=False, temperature=0.9, top_k=40, top_p=0.9)
     eng = Engine(model, params, slots=2, cache_len=32, k_steps=4,
-                 paged=True, block_size=8, n_spec=2, draft_params=draft,
+                 block_size=8, n_spec=2, draft_params=draft,
                  sampling=sp, check_invariants=True)
     o1 = eng.serve(prompts, gen_tokens=6, seed=7)
     o2 = eng.serve(prompts, gen_tokens=6, seed=7)
@@ -273,13 +273,13 @@ def test_spec_stress_randomized(seed):
     lens = [int(p.shape[0]) for p in prompts]   # sample_batch adds a token
 
     base = Engine(model, params, slots=slots, cache_len=cache_len,
-                  k_steps=k_steps, paged=True, block_size=8
+                  k_steps=k_steps, block_size=8
                   ).serve(prompts, gen_tokens=gen)
     full = slots * blocks_for(cache_len, 8)
     lo = max(blocks_for(L + gen - 1 + n_spec, 8) for L in lens)
     num_blocks = int(rng.randint(lo, full + 1))    # sometimes starved pool
     outs, stats = Engine(model, params, slots=slots, cache_len=cache_len,
-                         k_steps=k_steps, paged=True, block_size=8,
+                         k_steps=k_steps, block_size=8,
                          num_blocks=num_blocks, n_spec=n_spec,
                          draft_params=dtree, check_invariants=True
                          ).serve(prompts, gen_tokens=gen, return_stats=True)
@@ -319,7 +319,7 @@ def test_spec_composed_stress_randomized(seed):
     lens = [int(p.shape[0]) for p in prompts]   # sample_batch adds a token
 
     base = Engine(model, params, slots=slots, cache_len=cache_len,
-                  k_steps=k_steps, paged=True, block_size=8,
+                  k_steps=k_steps, block_size=8,
                   chunk_size=chunk, prefix_cache=True
                   ).serve(prompts, gen_tokens=gen)
     mb = blocks_for(cache_len, 8)
@@ -327,7 +327,7 @@ def test_spec_composed_stress_randomized(seed):
              for L in lens) + 1                    # + the CoW spare
     num_blocks = int(rng.randint(lo, slots * mb + 1))
     eng = Engine(model, params, slots=slots, cache_len=cache_len,
-                 k_steps=k_steps, paged=True, block_size=8,
+                 k_steps=k_steps, block_size=8,
                  chunk_size=chunk, prefix_cache=True,
                  num_blocks=num_blocks, n_spec=n_spec, draft_params=dtree,
                  check_invariants=True)
@@ -392,7 +392,7 @@ def test_spec_depth_swing_zero_recompiles():
     cfg, model, params, draft, bad, spec = _setup()
     prompts = _shared_prompts(spec, [18, 21, 18], share=16)
     eng = Engine(model, params, slots=2, cache_len=40, k_steps=4,
-                 paged=True, block_size=8, chunk_size=8, prefix_cache=True,
+                 block_size=8, chunk_size=8, prefix_cache=True,
                  n_spec=3, draft_params=bad, check_invariants=True)
     _, stats = eng.serve(prompts, gen_tokens=6, return_stats=True)
     assert stats["spec_depth"] == 1        # ~0% acceptance collapsed it
@@ -610,17 +610,17 @@ def test_sampling_params_validation():
 
 def test_spec_config_validation():
     cfg, model, params, draft, _, spec = _setup()
-    with pytest.raises(ValueError, match="paged"):
-        Engine(model, params, slots=2, cache_len=32, n_spec=2,
+    with pytest.raises(ValueError, match="contiguous cache was removed"):
+        Engine(model, params, slots=2, cache_len=32, paged=False, n_spec=2,
                draft_params=draft)
     with pytest.raises(ValueError, match="n_spec must be < k_steps"):
-        Engine(model, params, slots=2, cache_len=32, paged=True,
+        Engine(model, params, slots=2, cache_len=32,
                block_size=8, k_steps=2, n_spec=2, draft_params=draft)
     with pytest.raises(ValueError, match="draft_params"):
-        Engine(model, params, slots=2, cache_len=32, paged=True,
+        Engine(model, params, slots=2, cache_len=32,
                block_size=8, n_spec=2)
     with pytest.raises(ValueError, match="draft_params without n_spec"):
-        Engine(model, params, slots=2, cache_len=32, paged=True,
+        Engine(model, params, slots=2, cache_len=32,
                block_size=8, draft_params=draft)
 
 
@@ -631,10 +631,10 @@ def test_spec_composes_with_prefix_and_chunking():
     cfg, model, params, draft, _, spec = _setup()
     prompts = _prompts(spec, [10, 13])
     base = Engine(model, params, slots=2, cache_len=48, k_steps=5,
-                  paged=True, block_size=8, chunk_size=8, prefix_cache=True
+                  block_size=8, chunk_size=8, prefix_cache=True
                   ).serve(prompts, gen_tokens=4)
     eng = Engine(model, params, slots=2, cache_len=48, k_steps=5,
-                 paged=True, block_size=8, chunk_size=8, prefix_cache=True,
+                 block_size=8, chunk_size=8, prefix_cache=True,
                  n_spec=4, draft_params=draft, check_invariants=True)
     assert eng.serve(prompts, gen_tokens=4) == base
 
@@ -644,7 +644,7 @@ def test_swa_block_size_validation_is_early():
     with a friendly message, not as a deep shape error at first serve."""
     cfg, model, params, _, _, spec = _setup("mixtral-8x22b")
     with pytest.raises(ValueError, match="must divide the sliding window"):
-        Engine(model, params, slots=2, cache_len=34, paged=True,
+        Engine(model, params, slots=2, cache_len=34,
                block_size=6)
 
 

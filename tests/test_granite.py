@@ -89,9 +89,12 @@ def paged_logits(model, params, prompt, gen, chunk, bs=8):
     cache = model.init_paged_cache(2, cap, block_size=bs,
                                    num_blocks=2 * cap // bs)
     nb = -(-T0 // bs)
-    bstate, _ = P.alloc_admit({k: cache[k] for k in P.BSTATE_KEYS},
-                              jnp.array([0]), jnp.array([nb]), nb)
-    cache = {**cache, **bstate}
+    bstate, _ = P.admit_slot({k: cache[k] for k in P.BSTATE_KEYS}, 0,
+                             jnp.full((nb,), P.NEG, jnp.int32), 0, nb, 0, nb)
+    # decoding from the start: the slot writes (admit_slot leaves it in
+    # prefill phase, where decode writes route to the trash block)
+    cache = {**cache, **bstate,
+             "slot_active": bstate["slot_active"].at[0].set(True)}
     prefill = jax.jit(model.prefill_chunk_paged)
     decode = jax.jit(model.decode_step_paged, static_argnames="cow")
     for s in range(0, T0, chunk):
@@ -121,7 +124,7 @@ def test_granite_engine_matches_reference():
     prompt, gen = _prompt(cfg, 70), 6
     toks, lg, _ = paged_logits(model, params, prompt, gen, chunk=32)
     eng = Engine(model, params, slots=2, cache_len=128, k_steps=2,
-                 paged=True, block_size=8, chunk_size=32)
+                 block_size=8, chunk_size=32)
     assert eng.serve([prompt], gen_tokens=gen) == [toks]
     seq = np.concatenate([prompt, np.asarray(toks[:-1], np.int32)])
     ref = np.asarray(R.forward(params, seq, cfg)[0])[len(prompt) - 1:]

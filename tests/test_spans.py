@@ -83,7 +83,7 @@ def served(tiny, tmp_path_factory):
     profiler: (engine, trace, its hlo_texts, compile counts before)."""
     cfg, model, params, prompts = tiny
     eng = Engine(model, params, slots=2, cache_len=48, k_steps=4,
-                 paged=True, block_size=8, prefix_cache=True, chunk_size=8)
+                 block_size=8, prefix_cache=True, chunk_size=8)
     eng.serve(prompts, gen_tokens=6)
     _, pd = _profile(tmp_path_factory.mktemp("trace"),
                      lambda: eng.serve(prompts, gen_tokens=6))
@@ -151,31 +151,43 @@ def test_hlo_texts_needs_a_served_prefix_engine(tiny):
     """Only dispatches that have run have a text: none before a serve."""
     cfg, model, params, _ = tiny
     eng = Engine(model, params, slots=2, cache_len=48, k_steps=4,
-                 paged=True, block_size=8, prefix_cache=True, chunk_size=8)
+                 block_size=8, prefix_cache=True, chunk_size=8)
     assert eng.hlo_texts() == {}
 
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_hlo_texts_of_an_engine_without_prefix_cache(tiny, tmp_path, paged):
-    """An engine that keeps no cache between serves gives the executable
-    its decode dispatch ran, as the profiler names its ops."""
+@pytest.mark.parametrize("loop", ["paged", "spec"])
+def test_hlo_texts_of_an_engine_without_prefix_cache(tiny, tmp_path, loop):
+    """An engine that keeps no cache between serves gives the executables
+    its dispatches ran — the chunk dispatch that prefilled the prompts and
+    the dispatch that decoded after them, plain or speculative — as the
+    profiler names their ops."""
     cfg, model, params, prompts = tiny
-    kw = dict(paged=True, block_size=8) if paged else {}
-    eng = Engine(model, params, slots=2, cache_len=48, k_steps=4, **kw)
-    outs, pd = _profile(tmp_path, lambda: eng.serve(prompts, gen_tokens=6))
+    kw, mods = {}, ("jit_chunk_dispatch", "jit_decode_dispatch")
+    if loop == "spec":
+        draft, _ = quantize(params, None,
+                            QuantConfig(method="absmax",
+                                        granularity="channel"),
+                            mode="storage", out_dtype="bfloat16")
+        kw, mods = (dict(n_spec=2, draft_params=draft),
+                    ("jit_spec_chunk_dispatch", "jit_spec_dispatch"))
+    eng = Engine(model, params, slots=2, cache_len=48, k_steps=4,
+                 block_size=8, **kw)
+    # 12 tokens outlast the first dispatch: at most 1 + 3 rounds of 3
+    outs, pd = _profile(tmp_path, lambda: eng.serve(prompts, gen_tokens=12))
     counts = eng.compile_counts()
     texts = eng.hlo_texts()
-    assert set(texts) == {"jit_decode_dispatch"}
+    assert set(texts) == set(mods)
     assert eng.compile_counts() == counts
+    decode = mods[1]
     ran = {s["hlo_op"] for s in (dict(e.stats) for e in _events(pd))
-           if s.get("hlo_module") == "jit_decode_dispatch" and "hlo_op" in s}
+           if s.get("hlo_module") == decode and "hlo_op" in s}
     from repro.analysis.hlo import HloModule
-    instrs = {i.name for c in HloModule(texts["jit_decode_dispatch"])
+    instrs = {i.name for c in HloModule(texts[decode])
               .computations.values() for i in c}
     assert ran and ran <= instrs
     assert any("qlinear" in p.split("/") for p in
-               op_scopes(texts["jit_decode_dispatch"]).values())
-    assert eng.serve(prompts, gen_tokens=6) == outs
+               op_scopes(texts[decode]).values())
+    assert eng.serve(prompts, gen_tokens=12) == outs
 
 
 def _pair():
@@ -227,7 +239,7 @@ def test_serve_parity_with_tracing_on_and_off(tiny, tmp_path, loop):
     profiler on as with all three off; the tracer holds the engine spans
     and counter tracks."""
     cfg, model, params, prompts = tiny
-    kw = dict(slots=2, cache_len=48, k_steps=4, paged=True, block_size=8)
+    kw = dict(slots=2, cache_len=48, k_steps=4, block_size=8)
     if loop == "spec":
         draft, _ = quantize(params, None,
                             QuantConfig(method="absmax",

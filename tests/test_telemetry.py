@@ -212,7 +212,7 @@ def test_serve_metrics_free_on_hot_path(tmp_path):
 
     def mk(**kw):
         return Engine(model, params, slots=2, cache_len=48, k_steps=4,
-                      paged=True, block_size=8, prefix_cache=True,
+                      block_size=8, prefix_cache=True,
                       check_invariants=True, **kw)
 
     reg, tr = MetricsRegistry(), Tracer()
@@ -279,9 +279,11 @@ def test_serve_metrics_free_on_hot_path(tmp_path):
 def test_kv_rows_read_counts_what_decode_attention_reads(monkeypatch):
     """``kv_rows_read``: the whole table (``MB * bs`` a slot) every decode
     step on the gather path; on the kernel path (run here in interpret
-    mode) each slot's blocks up to its length — for one slot decoding
-    from a prompt of ``P`` rows, ``ceil((P + i) / bs) * bs`` at step
-    ``i`` — in every attention layer.  Both paths serve the same tokens."""
+    mode) each slot's blocks up to its length — for one slot whose prompt
+    of ``L`` rows lands in the chunk of scan step 0 (after that step's
+    decode sub-step, which reads nothing), ``ceil((L + i - 1) / bs) * bs``
+    at step ``i >= 1`` — in every attention layer.  Both paths serve the
+    same tokens."""
     import dataclasses
     import jax.numpy as jnp
     from repro.kernels.paged_attention import ops
@@ -296,7 +298,7 @@ def test_kv_rows_read_counts_what_decode_attention_reads(monkeypatch):
 
     def serve():
         eng = Engine(model, params, slots=1, cache_len=64, k_steps=K,
-                     paged=True, block_size=bs)
+                     block_size=bs)
         return eng.serve([prompt], gen_tokens=gen, return_stats=True)
 
     outs, st = serve()
@@ -306,7 +308,9 @@ def test_kv_rows_read_counts_what_decode_attention_reads(monkeypatch):
     monkeypatch.setattr(ops, "KERNEL_PLATFORMS", ("tpu", "cpu"))
     outs_k, st_k = serve()
     assert outs_k == outs
-    want = sum(-(-(P + i) // bs) * bs for i in range(steps)) * cfg.n_layers
+    L = int(prompt.shape[0])
+    want = sum(-(-(L + i - 1) // bs) * bs
+               for i in range(1, steps)) * cfg.n_layers
     assert st_k["decode_steps"] == steps
     assert st_k["counters"]["kv_rows_read"] == want
 

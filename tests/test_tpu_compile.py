@@ -165,7 +165,7 @@ def _paged_dispatch_compiled(sharding, entry, n_layers):
     params = jax.tree_util.tree_map_with_path(
         fp8, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
     eng = Engine(model, params, slots=64, cache_len=768, num_blocks=3072,
-                 paged=True, prefix_cache=True, chunk_size=64, block_size=16,
+                 prefix_cache=True, chunk_size=64, block_size=16,
                  k_steps=8)
     cache = jax.tree.map(sds, jax.eval_shape(
         lambda: model.init_paged_cache(64, 768, block_size=16,
@@ -259,7 +259,7 @@ def _hybrid_dispatch_compiled(sharding, entry, n_layers):
     params = jax.tree_util.tree_map_with_path(
         fp8, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
     eng = Engine(model, params, slots=24, cache_len=512, num_blocks=768,
-                 paged=True, chunk_size=256, block_size=16, k_steps=2)
+                 chunk_size=256, block_size=16, k_steps=2)
     cache = jax.tree.map(sds, jax.eval_shape(
         lambda: model.init_paged_cache(24, 512, block_size=16,
                                        num_blocks=768)))
