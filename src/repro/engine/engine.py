@@ -69,7 +69,7 @@ from repro.engine import paged as P
 from repro.engine.prefix import PrefixIndex
 from repro.engine.sampler import SamplingParams
 from repro.engine.scheduler import init_slot_state, make_decode_dispatch
-from repro.models.lm import Model, has_moe
+from repro.models.lm import Model, has_moe, has_ssm
 from repro.telemetry.counters import (COUNTER_KEYS, bump, counter_totals,
                                       init_counters)
 from repro.telemetry.trace import clock_us, span
@@ -156,6 +156,7 @@ class Engine:
         self.cfg = cfg
         self.mesh = mesh
         self._moe = has_moe(mcfg)   # expert-layer device counters
+        self._ssm = has_ssm(mcfg)   # Mamba-2 decode-kernel counter
         self.params = self._place_params(params) if mesh is not None else params
 
         sp, K = cfg.sampling, cfg.k_steps
@@ -562,7 +563,8 @@ class Engine:
             cache = model.init_paged_cache(B, cfg.cache_len,
                                            block_size=bs,
                                            num_blocks=self._num_blocks)
-            state = init_slot_state(B, prompt_cap=pcap, moe=self._moe)
+            state = init_slot_state(B, prompt_cap=pcap, moe=self._moe,
+                                    ssm=self._ssm)
             if self.mesh is not None:
                 cache = self._place_cache(cache)
             # eviction first runs once the index has filled the pool, a
@@ -577,7 +579,7 @@ class Engine:
         # zero the device counters for this serve() (host-side tree
         # rebuild — covers a reused persistent state).  Index-held blocks
         # survive across serves, so conservation baselines on them.
-        state = {**state, "ctr": init_counters(self._moe)}
+        state = {**state, "ctr": init_counters(self._moe, self._ssm)}
         self._ctr_live0 = len(self._hold_blocks)
         tr = self.tracer
         t_enq = time.perf_counter()

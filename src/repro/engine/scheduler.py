@@ -36,12 +36,12 @@ import jax.numpy as jnp
 
 from repro.engine.paged import BSTATE_KEYS, release_slots
 from repro.engine.sampler import SamplingParams, sample
-from repro.models.lm import Model, decode_rows_read
+from repro.models.lm import Model, decode_rows_read, decode_ssm_states
 from repro.telemetry.counters import bump, init_counters
 
 
 def init_slot_state(n_slots: int, prompt_cap: int,
-                    moe: bool = False) -> dict:
+                    moe: bool = False, ssm: bool = False) -> dict:
     """Zeroed device-side slot state for a fresh pool of ``n_slots``.
 
     The chunked-prefill fields are a per-slot prompt buffer of
@@ -50,14 +50,15 @@ def init_slot_state(n_slots: int, prompt_cap: int,
     ``ctr`` is the device-resident telemetry counter tree
     (repro.telemetry.counters): bumped inside the scan, read for free at
     the existing dispatch sync; ``moe`` adds the expert-layer counters
-    (models with MoE layers)."""
+    (models with MoE layers), ``ssm`` the Mamba-2 decode-kernel counter
+    (models with Mamba-2 layers)."""
     def z():   # a buffer per field: the dispatch donates the state
         return jnp.zeros((n_slots,), jnp.int32)
     return {
         "cur": jnp.zeros((n_slots, 1), jnp.int32),      # last sampled token
         "active": jnp.zeros((n_slots,), bool),          # slot serving a req?
         "remaining": z(),                               # decode budget left
-        "ctr": init_counters(moe),                      # device counters
+        "ctr": init_counters(moe, ssm),                 # device counters
         "prompt": jnp.zeros((n_slots, prompt_cap), jnp.int32),
         "pf_pos": z(),                                  # next prompt row
         "pf_len": z(),                                  # prompt length
@@ -195,6 +196,9 @@ def make_decode_dispatch(model: Model, sp: SamplingParams, k_steps: int,
             nf0, ref0 = cache["n_free"], cache["ref"]
             ctr = bump(st["ctr"], kv_rows_read=decode_rows_read(cache,
                                                                 model.cfg))
+            if "ssm_kernel_states" in ctr:
+                ctr = bump(ctr, ssm_kernel_states=decode_ssm_states(
+                    cache, st["active"]))
             # idle and prefilling slots keep their per-slot state
             logits, new_cache = model.decode_step_paged(
                 params, st["cur"], cache, cow=cow, keep=st["active"])

@@ -5,12 +5,17 @@
   fp8_quant       -- one-pass block absmax + E4M3 cast
   paged_attention -- attention against the paged K/V pools through the
                      block table, up to each slot's length
+  ssm_decode      -- Mamba-2's decode step on the stacked per-slot state,
+                     in place: one read and one write of a layer's state
 
-Serving runs ``paged_attention`` on a TPU: the engine's paged decode and
-speculative-verify attention (``models.attention.paged_decode_attention``,
-``paged_verify_attention``) call it where the pools' layout suits it.  It
-does not call the other three: ``quant_runtime.qlinear`` runs quantized
-weights as an XLA dequantize + matmul.  ``tests/test_tpu_compile.py``
+Serving runs ``paged_attention`` and ``ssm_decode`` on a TPU: the engine's
+paged decode and speculative-verify attention
+(``models.attention.paged_decode_attention``, ``paged_verify_attention``)
+call the first where the pools' layout suits it, and Mamba-2's paged
+decode step (``models.ssm.mamba_decode`` with a layer) the second where
+the state's does.  It does not call the other three:
+``quant_runtime.qlinear`` runs quantized weights as an XLA dequantize +
+matmul.  ``tests/test_tpu_compile.py``
 compiles each kernel for a v5e chip at glm4-9b widths.
 """
 from __future__ import annotations

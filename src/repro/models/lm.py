@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels.ssm_decode.ops import states_updated
 from repro.models import attention as A
 from repro.models import moe as MOE
 from repro.models import ssm as SSM
@@ -72,6 +73,12 @@ def has_moe(cfg: ModelConfig) -> bool:
     """Whether any layer of the model has an MoE FFN."""
     prefix_specs, _, specs, _ = layer_plan(cfg)
     return any(ffn == "moe" for _, ffn in prefix_specs + specs)
+
+
+def has_ssm(cfg: ModelConfig) -> bool:
+    """Whether any layer of the model has a Mamba-2 mixer."""
+    prefix_specs, _, specs, _ = layer_plan(cfg)
+    return any(mixer == "mamba" for mixer, _ in prefix_specs + specs)
 
 
 def _scaled(y, m: float):
@@ -174,6 +181,9 @@ def apply_layer_train(p: dict, x, cfg: ModelConfig, spec: LayerSpec, *,
 # leaves of an attention layer's paged cache that are block pools
 # ``[n_periods, num_blocks + 1, bs, Kv, hd]``; every other leaf is per slot
 POOL_KEYS = ("pk", "pv")
+# the leaf of a Mamba-2 layer's cache that decode updates at the layer in
+# place (ssm.mamba_decode with ``layer``); prefill and verify slice it out
+SSM_STATE_KEYS = ("h",)
 
 
 def _kv_eff(cfg: ModelConfig) -> int:
@@ -324,6 +334,19 @@ def decode_rows_read(pcache: dict, cfg: ModelConfig):
                 total += lc["pk"].shape[0] * rows_read(
                     pcache["lengths"], lc["pk"], pcache["tbl"],
                     window=cfg.sliding_window)
+    return total
+
+
+def decode_ssm_states(pcache: dict, keep):
+    """(slot, Mamba-2 layer) states one paged decode step updates through
+    the decode kernel (int32; ``kernels.ssm_decode.ops.states_updated``):
+    the layers times the slots in ``keep`` on the kernel path, 0 on the
+    XLA path."""
+    total = jnp.zeros((), jnp.int32)
+    for g in ("prefix", "stack"):
+        for lc in pcache.get(g, {}).values():
+            if "h" in lc:
+                total += states_updated(lc["h"], keep)
     return total
 
 
@@ -494,15 +517,17 @@ def apply_layer_verify_paged(p: dict, x, cache: dict, pctx: dict,
 def apply_layer_decode_paged(p: dict, x, cache: dict, pctx: dict,
                              cfg: ModelConfig, spec: LayerSpec):
     """Paged variant of :func:`apply_layer_decode`; Mamba/SSM layers keep
-    their contiguous per-slot state and are routed around the pool.  Only
-    the slots in ``pctx["keep"]`` (all when absent) are routed to experts.
-    Returns (x, cache, MoE counts or None)."""
+    their contiguous per-slot state and are routed around the pool: they
+    update the stacked state at ``pctx["layer"]`` in place.  Only the slots
+    in ``pctx["keep"]`` (all when absent) are routed to experts and step
+    their state.  Returns (x, cache, MoE counts or None)."""
     mixer, ffn = spec
     h = apply_norm(p["ln1"], x, cfg.norm_eps)
     if mixer in ("attn", "enc_attn"):
         y, cache = _attn_decode_paged(p["attn"], h, cache, pctx, cfg)
     elif mixer == "mamba":
-        y, cache = SSM.mamba_decode(p["mamba"], h, cache, cfg)
+        y, cache = SSM.mamba_decode(p["mamba"], h, cache, cfg,
+                                    layer=pctx["layer"], keep=pctx.get("keep"))
     else:
         raise ValueError(f"paged decode not supported for mixer {mixer!r}")
     x = x + _scaled(y, cfg.residual_multiplier)
@@ -659,7 +684,8 @@ def run_stack_decode(stack, cache, x, lengths, cfg, specs):
     return x, new_cache
 
 
-def run_stack_paged(apply_layer, stack, cache, x, pctx, cfg, specs):
+def run_stack_paged(apply_layer, stack, cache, x, pctx, cfg, specs,
+                    at_layer=POOL_KEYS):
     """Layer scan of a paged forward pass: ``apply_layer`` is
     :func:`apply_layer_decode_paged`, :func:`apply_layer_prefill_paged` or
     :func:`apply_layer_verify_paged`.  Returns (x, cache, MoE counts
@@ -669,14 +695,17 @@ def run_stack_paged(apply_layer, stack, cache, x, pctx, cfg, specs):
     The whole stacked cache rides the scan's carry and the layer index is
     scanned, so no leaf is sliced out of the scan's inputs or written back
     through its outputs, and the donated buffers alias through the
-    dispatch.  The K/V pools (``pk``/``pv``, ``[n_periods, NB+1, bs, Kv,
-    hd]``) are addressed at the layer inside attention: each layer
-    scatters its rows into ``pool[layer]`` and gathers ``pool[layer][tbl]``
-    with the index folded into the gather.  Per-slot (SSM) state
-    ``[n_periods, B, ...]`` is read at the layer and written back there in
-    place; slots outside ``pctx["keep"]`` (when given) keep their state.
-    The write targets / block table in ``pctx`` are shared by every layer
-    (all layers advance in lockstep) and ride the closure."""
+    dispatch.  The leaves named in ``at_layer`` are addressed at the layer
+    inside the mixer: the K/V pools (``pk``/``pv``, ``[n_periods, NB+1,
+    bs, Kv, hd]``) in attention, where each layer scatters its rows into
+    ``pool[layer]`` and gathers ``pool[layer][tbl]`` with the index folded
+    into the gather, and, in decode, the SSM state ``h``
+    (:data:`SSM_STATE_KEYS`), which Mamba-2 steps at the layer.  Every
+    other per-slot leaf ``[n_periods, B, ...]`` is read at the layer and
+    written back there in place; slots outside ``pctx["keep"]`` (when
+    given) keep their state.  The write targets / block table in ``pctx``
+    are shared by every layer (all layers advance in lockstep) and ride
+    the closure."""
     n = jax.tree.leaves(stack)[0].shape[0]
     keep = pctx.get("keep")
     moe = any(ffn == "moe" for _, ffn in specs)
@@ -689,7 +718,7 @@ def run_stack_paged(apply_layer, stack, cache, x, pctx, cfg, specs):
         for i, spec in enumerate(specs):
             lk = f"L{i}"
             state = {k: jax.lax.dynamic_index_in_dim(v, layer, 0, False)
-                     for k, v in cache[lk].items() if k not in POOL_KEYS}
+                     for k, v in cache[lk].items() if k not in at_layer}
             h, nc, cnt = apply_layer(lp[lk], h, {**cache[lk], **state},
                                      lctx, cfg, spec)
             new = {k: nc[k].astype(v.dtype) for k, v in state.items()}
@@ -698,7 +727,7 @@ def run_stack_paged(apply_layer, stack, cache, x, pctx, cfg, specs):
                     keep.reshape(keep.shape + (1,) * (v.ndim - 1)), v,
                     state[k]) for k, v in new.items()}
             new_cache[lk] = {
-                k: (nc[k] if k in POOL_KEYS else
+                k: (nc[k] if k in at_layer else
                     jax.lax.dynamic_update_index_in_dim(v, new[k], layer, 0))
                 for k, v in cache[lk].items()}
             if cnt is not None:
@@ -896,7 +925,8 @@ def build_model(cfg: ModelConfig) -> Model:
                                              batch, num_blocks, block_size)
         return c
 
-    def _run_stacks_paged(apply_layer, params, new_cache, x, pctx):
+    def _run_stacks_paged(apply_layer, params, new_cache, x, pctx,
+                          at_layer=POOL_KEYS):
         """The prefix group (if any), then the period stack, through
         :func:`run_stack_paged`; each group's cache is replaced in
         ``new_cache``, and so are the MoE counts.  Returns the final
@@ -905,11 +935,11 @@ def build_model(cfg: ModelConfig) -> Model:
         if n_prefix:
             x, new_cache["prefix"], c = run_stack_paged(
                 apply_layer, params["prefix"], new_cache["prefix"], x, pctx,
-                cfg, prefix_specs)
+                cfg, prefix_specs, at_layer)
             counts.append(c)
         x, new_cache["stack"], c = run_stack_paged(
             apply_layer, params["stack"], new_cache["stack"], x, pctx, cfg,
-            specs)
+            specs, at_layer)
         counts = [c for c in counts + [c] if c is not None]
         if counts:
             new_cache["moe_counts"] = sum(counts[1:], counts[0])
@@ -947,7 +977,7 @@ def build_model(cfg: ModelConfig) -> Model:
         if keep is not None:
             pctx["keep"] = keep
         x = _run_stacks_paged(apply_layer_decode_paged, params, new_cache, x,
-                              pctx)
+                              pctx, POOL_KEYS + SSM_STATE_KEYS)
         logits = lm_logits(params["embed"], x)[:, 0]
         new_cache["lengths"] = lengths + 1
         return logits, new_cache

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels import ssm_decode
 from repro.models.common import dense_init, split
 from repro.quant_runtime import qlinear
 
@@ -256,9 +257,10 @@ def mamba_verify_chunk(p: dict, x_in: jnp.ndarray, h: jnp.ndarray,
                        cfg: ModelConfig, cache: dict, valid: jnp.ndarray):
     """Speculative-verify chunk: the **exact recurrence**, stepped row by
     row — a bitwise mirror of ``valid[b]`` successive :func:`mamba_decode`
-    calls (same projections, same conv, same per-step ``h = a h + dt x B^T``
-    update and einsum shapes), unlike :func:`mamba_prefill_chunk` whose SSD
-    chunk math reorders the float ops.  The speculative engine needs its
+    calls (same projections, same conv, the same per-step update
+    ``ssm_decode.ref.step``; on a TPU decode runs it as the Pallas kernel,
+    which multiplies in another order), unlike :func:`mamba_prefill_chunk`
+    whose SSD chunk math reorders the float ops.  The speculative engine needs its
     verifier logits (and the rolled-back state on rejection) to equal what
     per-token decode would have produced, so the chunk here trades the SSD
     matmul form for per-row decode parity; chunks are ``n_spec + 1`` rows,
@@ -278,13 +280,8 @@ def mamba_verify_chunk(p: dict, x_in: jnp.ndarray, h: jnp.ndarray,
     dt_m = jnp.where(vm[:, :, None], dt, 0.0)
 
     @jax.named_scope("mamba")
-    def body(hst, xs):
-        xt, bt, ct, dtt = xs                   # [B,nh,P] [B,N] [B,N] [B,nh]
-        a = jnp.exp(A[None] * dtt)
-        dBx = jnp.einsum("bhp,bn,bh->bhpn", xt, bt.astype(jnp.float32), dtt)
-        hst = hst * a[..., None, None] + dBx
-        y = jnp.einsum("bn,bhpn->bhp", ct.astype(jnp.float32), hst)
-        return hst, y
+    def body(hst, xs):                 # xs: [B,nh,P] [B,N] [B,N] [B,nh]
+        return ssm_decode.ref.step(hst, *xs, A)
 
     xs = (xh32.transpose(1, 0, 2, 3), Bc.transpose(1, 0, 2),
           Cc.transpose(1, 0, 2), dt_m.transpose(1, 0, 2))
@@ -307,22 +304,26 @@ def init_mamba_cache(cfg: ModelConfig, batch: int) -> dict:
     }
 
 
-def mamba_decode(p: dict, x: jnp.ndarray, cache: dict, cfg: ModelConfig):
+def mamba_decode(p: dict, x: jnp.ndarray, cache: dict, cfg: ModelConfig,
+                 layer=None, keep=None):
     """One-token decode.  x [B,1,D].  Returns (y [B,1,D], new_cache).
 
     Uses the exact recurrence (no chunking) — one step of
-    ``h = a h + dt x B^T; y = C h + D x``."""
+    ``h = a h + dt x B^T; y = C h + D x``.  With ``layer``, ``cache["h"]``
+    is the whole stacked state [n, B, nh, P, N] and the step updates it at
+    ``layer`` in place (``kernels.ssm_decode``: a Pallas kernel on a TPU);
+    the slots outside ``keep`` [B] (all when None) keep their state."""
     Bsz = x.shape[0]
     di = cfg.resolved_d_inner
     z, xh, Bc, Cc, dt, conv_state = _project(p, x, cfg, cache)
     with jax.named_scope("mamba"):
         A = -jnp.exp(p["a_log"].astype(jnp.float32))
-        a = jnp.exp(A[None] * dt[:, 0])                # [B,nh]
         xh32 = xh.astype(jnp.float32)
-        dBx = jnp.einsum("bhp,bn,bh->bhpn", xh32[:, 0],
-                         Bc[:, 0].astype(jnp.float32), dt[:, 0])
-        h = cache["h"] * a[..., None, None] + dBx
-        y = jnp.einsum("bn,bhpn->bhp", Cc[:, 0].astype(jnp.float32), h)
+        args = (xh32[:, 0], Bc[:, 0], Cc[:, 0], dt[:, 0], A)
+        if layer is None:
+            h, y = ssm_decode.ref.step(cache["h"], *args)
+        else:
+            h, y = ssm_decode.ops.ssm_decode(cache["h"], layer, keep, *args)
         y = y + p["d_skip"].astype(jnp.float32)[None, :, None] * xh32[:, 0]
     y = _gated_norm(y.reshape(Bsz, 1, di), z, p["norm_scale"])
     out = qlinear.matmul(y.astype(x.dtype), p["out_proj"])

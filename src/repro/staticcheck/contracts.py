@@ -146,7 +146,8 @@ def entry_args(eng, case: Case, name: str) -> tuple:
     if eng.mesh is not None:
         cache = eng._place_cache(cache)
     pcap = cfg.cache_len
-    state = init_slot_state(B, prompt_cap=pcap, moe=eng._moe)
+    state = init_slot_state(B, prompt_cap=pcap, moe=eng._moe,
+                            ssm=eng._ssm)
 
     if name in ("_dispatch", "_dispatch_chunk"):
         return (eng.params, state, cache, key)

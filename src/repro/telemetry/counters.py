@@ -69,10 +69,20 @@ MOE_COUNTER_KEYS = (
 )
 
 
-def init_counters(moe: bool = False) -> dict:
+# Only for models with Mamba-2 layers (``init_counters(ssm=True)``): bumped
+# in the decode sub-step of ``scheduler.py`` (lm.decode_ssm_states).
+SSM_COUNTER_KEYS = (
+    "ssm_kernel_states", # (slot, Mamba-2 layer) states the decode kernel
+                         # updated (kernels.ssm_decode), summed over decode
+                         # steps; 0 on the XLA path
+)
+
+
+def init_counters(moe: bool = False, ssm: bool = False) -> dict:
     """Zeroed counter pytree — strong int32 scalars (a weak-typed literal
     here would retrace every dispatch; see staticcheck weak-type rule)."""
-    keys = COUNTER_KEYS + (MOE_COUNTER_KEYS if moe else ())
+    keys = (COUNTER_KEYS + (MOE_COUNTER_KEYS if moe else ())
+            + (SSM_COUNTER_KEYS if ssm else ()))
     return {k: jnp.zeros((), jnp.int32) for k in keys}
 
 
@@ -87,7 +97,8 @@ def bump(ctr: dict, **deltas) -> dict:
 
 def counter_totals(ctr_host: dict) -> dict:
     """Host-side view of a fetched counter tree as plain ints, in
-    COUNTER_KEYS (then MOE_COUNTER_KEYS) order (stable for snapshots and
-    stats)."""
-    return {k: int(ctr_host[k]) for k in COUNTER_KEYS + MOE_COUNTER_KEYS
+    COUNTER_KEYS (then MOE_COUNTER_KEYS, SSM_COUNTER_KEYS) order (stable
+    for snapshots and stats)."""
+    return {k: int(ctr_host[k])
+            for k in COUNTER_KEYS + MOE_COUNTER_KEYS + SSM_COUNTER_KEYS
             if k in ctr_host}

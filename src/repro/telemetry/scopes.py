@@ -19,7 +19,10 @@ The model and the engine wrap their device work in ``jax.named_scope``:
 * ``sample``        — token sampling (``engine.sampler.sample``);
 * ``paged_attn``    — the paged attention kernel
                       (``kernels.paged_attention``), nested in
-                      ``attention``: decode and verify on a TPU.
+                      ``attention``: decode and verify on a TPU;
+* ``mamba_state``   — the Mamba-2 decode-step kernel
+                      (``kernels.ssm_decode``), nested in ``mamba``:
+                      decode on a TPU.
 
 A scope is metadata: it lands in each HLO instruction's ``op_name`` and
 leaves the compiled code as it was.  A device trace carries no scope (its
@@ -33,7 +36,7 @@ from __future__ import annotations
 from repro.analysis.hlo import HloModule
 
 SCOPES = ("qlinear", "attention", "mamba", "moe", "prefill_chunk",
-          "keep_rows", "sample", "paged_attn")
+          "keep_rows", "sample", "paged_attn", "mamba_state")
 
 _OP_NAME = r'op_name="([^"]*)"'
 

@@ -143,8 +143,12 @@ def test_scope_path_reads_op_names():
     assert scope_path("jit(f)/while/body/attention/cond/branch_0_fun/"
                       "paged_attn/cond/branch_0_fun/paged_attention/"
                       "pallas_call") == "attention/paged_attn"
+    assert scope_path("jit(f)/while/body/mamba/cond/branch_0_fun/"
+                      "mamba_state/ssm_decode/pallas_call") \
+        == "mamba/mamba_state"
     assert SCOPES == ("qlinear", "attention", "mamba", "moe",
-                      "prefill_chunk", "keep_rows", "sample", "paged_attn")
+                      "prefill_chunk", "keep_rows", "sample", "paged_attn",
+                      "mamba_state")
 
 
 def test_hlo_texts_needs_a_served_prefix_engine(tiny):

@@ -264,7 +264,7 @@ def _hybrid_dispatch_compiled(sharding, entry, n_layers):
         lambda: model.init_paged_cache(24, 512, block_size=16,
                                        num_blocks=768)))
     state = jax.tree.map(sds, jax.eval_shape(
-        lambda: init_slot_state(24, prompt_cap=512, moe=True)))
+        lambda: init_slot_state(24, prompt_cap=512, moe=True, ssm=True)))
     key = sds(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
     fn = eng.entry_points()[entry]["fn"]
     return (fn.lower(params, state, cache, key).compile(),
@@ -297,6 +297,25 @@ def test_hybrid_dispatch_updates_ssm_state_in_place(one_chip, entry):
     grow = (c4.memory_analysis().temp_size_in_bytes
             - c2.memory_analysis().temp_size_in_bytes)
     assert grow < added, (grow, added)
+
+
+def test_hybrid_decode_steps_the_state_through_the_kernel(one_chip):
+    """Granite's decode dispatch steps each Mamba-2 layer's state in the
+    decode kernel (``kernels.ssm_decode``), in place on the stacked state:
+    no instruction makes a slot-wide slice of one layer's state
+    (``f32[1,B,nh,P,N]``, the carry's read of the layer), and no
+    ``dynamic-update-slice`` writes the stacked state (the write-back)."""
+    import re
+    compiled, h = _hybrid_dispatch_compiled(one_chip, "_dispatch", 8)
+    text = compiled.as_text()
+    dims = ",".join(map(str, h))
+    assert re.search(r"%ssm_decode\S* = .* custom-call\(", text)
+    layer = re.compile(rf"f32\[1,{dims}\]")
+    assert not layer.search(text), layer.search(text).group(0)
+    write = [ln.strip()[:160] for ln in text.splitlines()
+             if re.search(rf"= f32\[\d+,{dims}\]\S* dynamic-update-slice\(",
+                          ln)]
+    assert not write, write
 
 
 @pytest.mark.parametrize("tokens", [24, 24 * 256], ids=["decode", "chunk"])
